@@ -203,6 +203,9 @@ class Broker:
         from ..rules.engine import RuleEngine
 
         self.rules = RuleEngine(broker=self)
+        # `engine.warmup()` compiles the rules kernel for the
+        # registered program along with the match buckets
+        engine.rules_source = self.rules.device_program
         self.resources = ResourceManager()  # alarms wired below (init
         # order: the AlarmRegistry is constructed a few lines down)
         # Aggregators attached by rules/bridges (emqx_connector_

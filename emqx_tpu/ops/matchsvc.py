@@ -221,6 +221,12 @@ class MatchService:
         kw = dict(engine_kw or {})
         kw.setdefault("use_device", use_device)
         self.engine = MatchEngine(**kw)
+        if self.engine.use_device is not False:
+            # under --workers N this process owns the chip, so its
+            # shape-class compiles are the ones worth keeping on disk
+            from ..engine import enable_compile_cache
+
+            enable_compile_cache()
         self._workers: Dict[int, _Worker] = {}
         self._server: Optional[asyncio.AbstractServer] = None
         # real metrics registry (the reference's emqx_metrics slots),
@@ -258,21 +264,33 @@ class MatchService:
 
     async def stop(self) -> None:
         if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+            self._server.close()  # no new workers
+        # drop the attached workers BEFORE waiting: since Python 3.12
+        # wait_closed() waits for every accepted connection, and a
+        # worker's stays open until its writer is closed here
         for w in list(self._workers.values()):
             self._drop_worker(w)
+        if self._server is not None:
+            await self._server.wait_closed()
+            self._server = None
 
     def _device_on(self) -> bool:
+        """Whether an accelerator backs the engine.  A pinned device
+        (``use_device=True``) that JAX cannot initialize is an error
+        the operator must see, not "no device"; auto mode serves on
+        the host trie and says so."""
         eng = self.engine
         if eng.use_device is False:
             return False
-        try:
-            import jax
+        import jax
 
+        try:
             return jax.devices()[0].platform != "cpu"
-        except Exception:
+        except RuntimeError:
+            if eng.use_device is True:
+                raise
+            log.warning("no JAX backend: matching serves on the host "
+                        "trie", exc_info=True)
             return False
 
     # ------------------------------------------------------- routes

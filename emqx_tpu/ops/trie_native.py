@@ -39,9 +39,10 @@ _lib_failed = False
 
 def _build() -> None:
     os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    # one-time toolchain rebuild of a stale .so (dev boxes only;
-    # production loads the checked-in binary) — never on the
-    # steady-state path, so the loop stall is accepted
+    # built from the committed source on first load in a fresh
+    # checkout (native/build/ is not committed) and again when
+    # the source is newer — never on the steady-state path, so
+    # the loop stall is accepted
     # brokerlint: ignore[ASYNC101]
     subprocess.run(
         ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-Wall", "-o", _SO, _SRC],

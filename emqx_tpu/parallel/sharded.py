@@ -347,7 +347,7 @@ class ShardedMatchEngine(MatchEngine):
         dev = self._device_put(index) if device_put else None
         return index, dev, fid_arr, n_live, arenas
 
-    def _warm_built(self, index, dev) -> None:
+    def _warm_built(self, index, dev, batch: int = 16) -> None:
         # the sharded tables feed sharded_match, not the single-chip
         # kernel; its compile is warmed by the first sharded call
         return

@@ -215,6 +215,15 @@ class RuleEngine:
         self._stack_cache = (self.rules_rev, stack)
         return stack
 
+    def device_program(self) -> Optional[Tuple[StackedRules, int]]:
+        """The stacked WHERE program and its revision as the match
+        engine's device rules step would receive them, or None when
+        the matrix path is off (`MatchEngine.warmup` compiles the
+        rules kernel for it before traffic)."""
+        if not self._matrix_enabled or self.eval_force == "scalar":
+            return None
+        return self._stacked(), self.rules_rev
+
     def _select_stack(self, stack: StackedRules) -> SelectStack:
         """The enabled registry's lowered SELECT programs, sharing the
         WHERE stack's path union (SELECT-only paths are APPENDED, so

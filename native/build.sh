@@ -1,9 +1,11 @@
 #!/usr/bin/env bash
-# Rebuild every native helper .so with the exact flags the checked-in
-# binaries (and the on-demand rebuilders in emqx_tpu/ops/*_native.py /
-# dispatchasm.py) use.  Each loader also rebuilds its own lib lazily
-# when the source is newer than the binary, so running this script is
-# only needed for a clean rebuild or a toolchain bump.
+# Build every native helper .so from the committed sources with the
+# flags the on-demand builders in emqx_tpu/ops/*_native.py,
+# ops/dispatchasm.py and ds/native.py use.  native/build/ is not
+# committed: each loader builds its own lib on first load and again
+# when the source is newer than the binary, so this script is for a
+# clean rebuild, a toolchain bump, or a copied tree whose mtimes prove
+# nothing (chip_smoke.py runs it for that reason).
 #
 # A lib that fails to build is reported and SKIPPED: every native lib
 # has a pure-Python fallback, and tier-1 skips the native parity tests

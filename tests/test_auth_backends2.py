@@ -178,6 +178,10 @@ def test_mongo_commands_pipeline_on_one_connection():
         assert r1["echo"] == "alpha" and r2["echo"] == "beta"
         assert len(conns) == 1  # both rode one pipelined connection
         await conn.close()
+        # Python 3.12's wait_closed() waits for every accepted
+        # connection: close the server's half, or it never returns
+        for w in conns:
+            w.close()
         server.close()
         await server.wait_closed()
 
@@ -380,8 +384,8 @@ def test_mongo_redials_after_connection_loss():
             "mqtt_user", {"username": "alice"}
         ))["username"] == "alice"
         first_w = conn._w
+        first_w.close()  # first: 3.12's wait_closed() waits for it
         await fm.stop()
-        first_w.close()
         await asyncio.sleep(0.05)
         assert conn._w is None  # pump teardown reset the transport
         await fm.start()

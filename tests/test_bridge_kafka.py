@@ -392,6 +392,10 @@ def test_requests_pipeline_on_one_connection():
         assert struct.unpack(">i", r2)[0] == 2
         assert len(conns) == 1  # both rode one pipelined connection
         client.close()
+        # Python 3.12's wait_closed() waits for every accepted
+        # connection: close the server's half, or it never returns
+        for w in conns:
+            w.close()
         server.close()
         await server.wait_closed()
 
@@ -405,6 +409,8 @@ def test_connection_loss_fails_pending_requests():
     async def t():
         async def handler(r, w):
             await r.readexactly(4)  # swallow, never answer
+            await r.read()  # until the client is gone, then close our
+            w.close()  # half: 3.12's wait_closed() waits for it
 
         server = await asyncio.start_server(handler, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -440,8 +446,8 @@ def test_client_redials_after_connection_loss():
         # kill the live connection server-side and let the pump die
         first_w = client._w
         fk.server.close()
+        first_w.close()  # first: 3.12's wait_closed() waits for it
         await fk.server.wait_closed()
-        first_w.close()
         await asyncio.sleep(0.05)
         assert not client.connected  # pump teardown closed the writer
         await fk.start()  # server back (new port)

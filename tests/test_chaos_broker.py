@@ -106,7 +106,7 @@ def test_probe_recloses_breaker_after_fault_clears():
 
 def test_watchdog_deadline_counts_slow_windows():
     """A device window that RETURNS but blows the watchdog deadline is
-    breaker food too — a wedged tunnel degrades to host-only without a
+    breaker food too — a wedged link degrades to host-only without a
     single exception being raised."""
     eng = make_engine()
     eng.breaker_threshold = 2

@@ -264,6 +264,71 @@ def test_kernel_vs_twin_over_random_padded_columns():
             assert host[row].tolist() == want, preds[i]
 
 
+def _small_stack_and_window(n_msgs=20):
+    stack = build_stack([
+        (str(i), parse_sql(
+            f'SELECT * FROM "t" WHERE payload.a > {i} '
+            f"and payload.s = 's{i % 3}'"
+        ).where)
+        for i in range(5)
+    ])
+    msgs = [
+        Message(topic="t", qos=0,
+                payload=b'{"a": %d, "s": "s%d"}' % (k % 7, k % 3))
+        for k in range(n_msgs)
+    ]
+    return stack, msgs
+
+
+def test_register_file_past_the_budget_is_refused_by_shape(monkeypatch):
+    """A (S, R, W) the device cannot hold is a counted policy decision
+    served by the host twin: never dispatched, never breaker food."""
+    import emqx_tpu.engine as E
+
+    stack, msgs = _small_stack_and_window()
+    cols = WindowColumns(msgs, stack.paths, stack.lit_strings)
+    eng = MatchEngine(use_device=False)
+    eng.rules_force = "dev"
+    cells = E._rules_cells(stack, cols.n)
+    monkeypatch.setattr(E, "RULES_DEV_MAX_CELLS", cells - 1)
+    host, path = eng.rules_eval_window(stack, 0, cols)
+    assert path == "host"
+    st = eng.stats()
+    assert st["rules_dev_refused"] == 1 and st["rules_dev_errors"] == 0
+    assert st["breaker_device_errors"] == 0
+    monkeypatch.setattr(E, "RULES_DEV_MAX_CELLS", cells)
+    dev, path = eng.rules_eval_window(stack, 0, cols)
+    assert path == "dev" and np.array_equal(host, dev)
+    assert eng.stats()["rules_dev_refused"] == 1
+
+
+def test_warmup_compiles_the_rules_kernel_before_traffic():
+    """`warmup()` compiles the registered program at every window
+    bucket, and neither a wider SELECT path union nor another literal
+    count makes a served window compile again."""
+    from emqx_tpu.ops.match_kernel import rules_eval_batch
+
+    stack, msgs = _small_stack_and_window()
+    eng = MatchEngine(use_device=True)  # rules only: no automaton
+    eng.rules_source = lambda: (stack, 0)
+    assert eng.warmup(32) == 0
+    warmed = rules_eval_batch._cache_size()
+    for paths in (stack.paths, stack.paths + [("payload", "other")]):
+        for n in (3, 20):
+            cols = WindowColumns(msgs[:n], paths, stack.lit_strings)
+            mat, path = eng.rules_eval_window(stack, 0, cols)
+            assert path == "dev"
+            want = [
+                [eval_where(parse_sql(
+                    f'SELECT * FROM "t" WHERE payload.a > {i} '
+                    f"and payload.s = 's{i % 3}'"
+                ).where, build_env(m)) for m in msgs[:n]]
+                for i in range(5)
+            ]
+            assert mat.tolist() == want
+    assert rules_eval_batch._cache_size() == warmed
+
+
 def test_host_twin_block_chunking_and_program_dedup():
     """Registries past RULES_HOST_BLOCK evaluate in slabs (distinct
     literals defeat dedup), and identical programs share one row."""
