@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Bring-up check: the served match path on the attached TPU.
 
-    python chip_smoke.py [--subs N] [--seed S] [--windows K]
+    python chip_smoke.py [--subs N] [--seed S]
     python chip_smoke.py --chips 4 [--subs N] [--seed S]
 
 The default run needs one chip and drives, in ONE process that touches
@@ -16,11 +16,14 @@ JAX, with the device pinned and its work counted:
              the device and equal to the `HostTrie` referee;
   served     a `BrokerServer` built the way `listener.main()` builds
              it with ``engine.use_device = true``, the same background
-             table, rule-engine rules and live wildcard subscribers,
-             driven over loopback TCP by a child process that never
-             imports JAX — every PUBACK, every delivery and every rule
-             firing checked against the scalar referees, every window
-             matched, decided and rule-evaluated on the device.
+             table and the rule-engine rules in place before
+             `start()` — which builds, uploads and warms on its own —
+             then live wildcard subscribers and publishers over
+             loopback TCP from a child process that never imports
+             JAX.  Every PUBACK, every delivery and every rule firing
+             is checked against the scalar referees; every window is
+             matched, decided and rule-evaluated on the device, and no
+             rules program compiles inside the traffic.
 
 ``--chips 4`` runs only the sharded engine on a four-device mesh and
 what it is compared with (the single-device engine and the referee).
@@ -49,9 +52,11 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 
 PLATFORM = "tpu"        # the platform every phase must run on
 WINDOW_TOPICS = 4096    # topics a window (the batcher's batch_max)
+N_WINDOWS = 8           # steady windows in the engine phase
 PIPELINE = 4            # windows in flight (engine.pipeline_windows)
 N_RULES = 100           # BASELINE config 4's rule count
 N_LIVE = 300            # live wildcard subscribers over TCP
+LIVE_FILTERS = 4        # filters each of them subscribes to
 N_PUBLISH = 4000        # QoS1 publishes from the client child
 N_PUBLISHERS = 8
 FANOUT = 8              # subscribers sharing each background filter
@@ -129,31 +134,12 @@ def memory(device) -> dict:
 
 def background(n_subs: int):
     """The four fleet-telemetry filter families (bench.make_filters) as
-    ``(filter, fid)`` pairs, and their id populations."""
+    ``(filter, fid)`` pairs, and their id populations; the publish
+    streams over them are `bench.make_topics`."""
     from bench import make_filters
 
     filters, pops = make_filters(n_subs, FANOUT)
     return [("/".join(ws), fid) for fid, ws in filters], pops
-
-
-def zipf_topics(rng, n: int, pops):
-    """A publish stream over the same families, ids Zipf-distributed:
-    a few hot vehicles take most of the traffic, one topic in ten
-    matches nothing."""
-    n_vehicles, n_dev, n_site, _ = pops
-    z = rng.zipf(1.3, size=n)
-    topics = []
-    for i in range(n):
-        k, v = i % 10, int(z[i])
-        if k < 6:
-            topics.append(f"vehicles/v{v % n_vehicles}/sensors/temp")
-        elif k < 8:
-            topics.append(f"dev/g{v % n_dev}/x/d{v % 7}")
-        elif k < 9:
-            topics.append(f"site/s{i % 7}/floor/f{v % n_site}/a")
-        else:
-            topics.append(f"nomatch/q{i}")
-    return topics
 
 
 def referee(pairs):
@@ -303,6 +289,7 @@ def run_windows(eng, ref, windows, compiles: CompileLog, what: str):
 
 def engine_phase(args, dev, pairs, pops, ref, compiles: CompileLog):
     import numpy as np
+    from bench import make_topics
 
     from emqx_tpu.config import BrokerEngineConfig
     from emqx_tpu.engine import MatchEngine
@@ -362,7 +349,7 @@ def engine_phase(args, dev, pairs, pops, ref, compiles: CompileLog):
     check(idx["base"] == len(pairs), f"base holds {idx['base']} filters")
     rng = np.random.default_rng(args.seed)
     windows = [
-        zipf_topics(rng, WINDOW_TOPICS, pops) for _ in range(args.windows)
+        make_topics(rng, WINDOW_TOPICS, pops) for _ in range(N_WINDOWS)
     ]
     say("engine_windows",
         **run_windows(eng, ref, windows, compiles, "engine"),
@@ -393,17 +380,26 @@ def rule_sql(i: int) -> str:
             f"and payload.dev != 'd{i % 7}'")
 
 
-def live_filter(i: int) -> str:
-    kind, k = i % 5, i // 5 + 1
-    if kind == 0:
-        return f"vehicles/v{k}/sensors/#"
-    if kind == 1:
-        return f"dev/g{k}/+/d{k % 7}"
-    if kind == 2:
-        return f"site/+/floor/f{k}/#"
-    if kind == 3:
-        return "vehicles/+/sensors/temp" if k % 2 else "dev/+/x/+"
-    return f"vehicles/v{k}/#"
+def live_filters(j: int) -> list:
+    """Subscriber ``j``'s filters, disjoint from one another (a publish
+    reaches it through at most one).  All are distinct across the
+    subscribers but the two broad ones that a fifth of them share."""
+    kind, out = j % 5, []
+    for n in range(LIVE_FILTERS):
+        k = j // 5 + 1 + n * (N_LIVE // 5 + 1)
+        if kind == 0:
+            out.append(f"vehicles/v{k}/sensors/#")
+        elif kind == 1:
+            out.append(f"dev/g{k}/+/d{k % 7}")
+        elif kind == 2:
+            out.append(f"site/+/floor/f{k}/#")
+        elif kind == 4:
+            out.append(f"vehicles/v{k}/#")
+        elif n:
+            out.append(f"site/+/floor/f{k}/a")
+        else:
+            out.append("vehicles/+/sensors/temp" if k % 2 else "dev/+/x/+")
+    return out
 
 
 def payload_of(seq: int) -> bytes:
@@ -415,6 +411,7 @@ def payload_of(seq: int) -> bytes:
 
 async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
     import numpy as np
+    from bench import make_topics
 
     from emqx_tpu import topic as T
     from emqx_tpu.broker.listener import BrokerServer
@@ -440,7 +437,19 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
     broker = server.broker
     eng = broker.router.engine
     check(eng.use_device is True, "the broker's engine is not pinned")
+    subs = [(f"sub{j}", live_filters(j), j % 2) for j in range(N_LIVE)]
+    n_live_filters = len({f for _, flts, _ in subs for f in flts})
+    # the broker gets everything onto the device by its own means: the
+    # restored table through the background rebuild, the live filters
+    # through the delta fold — so the sizes must reach its thresholds
+    check(
+        len(pairs) >= eng.rebuild_threshold
+        and n_live_filters >= eng.delta_aut_threshold,
+        f"{len(pairs)} subs / {n_live_filters} live filters are below "
+        f"the engine's rebuild / fold thresholds",
+    )
 
+    # what a boot restores before start(): the rules and the table
     fired: list = []  # (rule_id, seq) per rule firing
     for i in range(N_RULES):
         rid = f"r{i}"
@@ -449,20 +458,36 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
                 (rid, json.loads(msg.payload)["seq"])
             )
         )])
+    mark = compiles.mark()
     t0 = time.perf_counter()
     await loop.run_in_executor(None, eng.insert_many, pairs)
     insert_s = time.perf_counter() - t0
+    # start() waits for the build that insert_many() started, then
+    # compiles every window bucket and the rules program
+    t0 = time.perf_counter()
     await server.start()
+    start_s = time.perf_counter() - t0
     t_start = time.time()
     clock = {}  # seconds after start() at which each step ended
     child = None
     try:
+        idx = eng.index_stats()
+        check(
+            idx["base"] == len(pairs) + N_RULES and idx["residual"] == 0,
+            f"start() left filters outside the base automaton: {idx}",
+        )
+        gc.collect()
+        say(
+            "served_start", subs=len(eng), rules=N_RULES,
+            insert_s=round(insert_s, 3), start_s=round(start_s, 3),
+            **{"start_" + k: v for k, v in compiles.since(mark).items()},
+            memory=memory(dev),
+        )
         port = server.listeners[0].port
-        subs = [(f"sub{i}", live_filter(i), i % 2) for i in range(N_LIVE)]
         rng = np.random.default_rng(args.seed + 1)
-        topics = zipf_topics(rng, N_PUBLISH, pops)
+        topics = make_topics(rng, N_PUBLISH, pops)
         pubs = [(t, payload_of(seq).decode()) for seq, t in enumerate(topics)]
-        # what the scalar referees say: deliveries per subscriber by
+        # what the scalar referees say: deliveries per subscription by
         # topic.match_words, rule firings by the interpreter
         words = {t: T.words(t) for t in set(topics)}
         hits: dict = {}  # filter -> the distinct topics it matches
@@ -477,10 +502,10 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
 
         want_recv = {
             cid: sorted(
-                (seq, min(1, qos)) for seq, t in enumerate(topics)
-                if t in matched_by(flt)
+                (seq, min(1, qos)) for flt in flts
+                for seq, t in enumerate(topics) if t in matched_by(flt)
             )
-            for cid, flt, qos in subs
+            for cid, flts, qos in subs
         }
         envs = [
             build_env(Message(topic=t, payload=payload_of(seq), qos=1))
@@ -497,6 +522,7 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
         check(n_expect > 0 and want_fired, "the workload exercises nothing")
 
         # the client child: codec + asyncio only, never JAX
+        mark = compiles.mark()
         child = await asyncio.create_subprocess_exec(
             sys.executable, os.path.abspath(__file__), "--client",
             stdin=asyncio.subprocess.PIPE, stdout=asyncio.subprocess.PIPE,
@@ -511,40 +537,38 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
         ))
         clock["subscribed"] = round(time.time() - t_start, 3)
         check(
-            ready["granted"] == [q for _, _, q in subs],
+            ready["granted"] == [[q] * LIVE_FILTERS for _, _, q in subs],
             f"granted QoS differ from asked: {ready['granted'][:8]}...",
         )
-        # fold the rules' and the live subscribers' filters into the
-        # device automaton: left in the host-matched residual their
-        # deliveries would prove nothing about the device
-        t0 = time.perf_counter()
-        await loop.run_in_executor(None, eng.rebuild)
-        build_s = time.perf_counter() - t0
-        clock["rebuilt"] = round(time.time() - t_start, 3)
-        mark = compiles.mark()
-        t0 = time.perf_counter()
-        buckets = await loop.run_in_executor(
-            None, eng.warmup, cfg.engine.batch_max
-        )
-        warm_s = time.perf_counter() - t0
-        clock["warmed"] = round(time.time() - t_start, 3)
-        check(buckets > 0, "warmup() found the device path off")
-        idx = eng.index_stats()
-        n_filters = len(pairs) + len({f for _, f, _ in subs}) + N_RULES
+        # the live filters crossed the fold threshold: the engine
+        # folds them into the device's delta automaton in its own
+        # thread (upload and bucket warm-up included); left in the
+        # host-matched residual their deliveries would prove nothing
+        # about the device
+        deadline = time.monotonic() + 600
+        while True:
+            idx = eng.index_stats()
+            if idx["folded"] and not idx["folding"]:
+                break
+            check(time.monotonic() < deadline, f"no delta fold: {idx}")
+            await asyncio.sleep(0.05)
+        clock["folded"] = round(time.time() - t_start, 3)
         check(
-            idx["base"] == n_filters and idx["residual"] == 0,
-            f"filters left outside the device automaton: {idx}",
+            idx["folded"] >= eng.delta_aut_threshold
+            and idx["folded"] + idx["residual"] == n_live_filters,
+            f"live filters not where the fold should leave them: {idx}",
         )
+        n_filters = len(pairs) + N_RULES + n_live_filters
         check(
             len(eng) == n_filters,
             f"{n_filters - len(eng)} subscriptions left before traffic",
         )
         gc.collect()
         say(
-            "served_load", subs=len(eng), rules=N_RULES, live_subs=N_LIVE,
-            insert_s=round(insert_s, 3), rebuild_s=round(build_s, 3),
-            warmup_s=round(warm_s, 3), warmup_buckets=buckets,
-            **{"warmup_" + k: v for k, v in compiles.since(mark).items()},
+            "served_load", subs=len(eng), live_subs=N_LIVE,
+            live_filters=n_live_filters, base=idx["base"],
+            folded=idx["folded"], residual=idx["residual"],
+            **{"fold_" + k: v for k, v in compiles.since(mark).items()},
             memory=memory(dev),
         )
         broker.profiler.reset()
@@ -604,6 +628,13 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
         and rstats["fallback_rule_evals"] == 0,
         f"rules left the matrix for the interpreter: {rstats}",
     )
+    # start() compiled the rules program at every window bucket: one
+    # compiling in a dispatch window holds ordered dispatch behind it
+    served = compiles.since(mark)
+    check(
+        "rules_eval_batch" not in served["compile_s_by_fn"],
+        f"the rules program compiled inside the traffic: {served}",
+    )
     assert_clean(eng, "served")
     say(
         "served_traffic", publishes=N_PUBLISH, pubacks=got["pubacks"],
@@ -617,7 +648,7 @@ async def served_phase(args, dev, pairs, pops, compiles: CompileLog):
         rules_host_windows=stats["rules_host_windows"],
         rules_lowered=rstats["lowered"],
         rules_program_rows=rstats["program_rows"],
-        **{"served_" + k: v for k, v in compiles.since(mark).items()},
+        **{"served_" + k: v for k, v in served.items()},
         breaker=eng.breaker_info(), memory=memory(dev),
         # anomaly dumps the broker's flight recorder took meanwhile
         # (an event-loop stall, an SLO breach): seen, not hidden
@@ -657,7 +688,7 @@ async def _client(plan: dict) -> dict:
             for pkt in parser.feed(data):
                 yield pkt
 
-    async def subscriber(cid: str, flt: str, qos: int, granted: dict,
+    async def subscriber(cid: str, flts: list, qos: int, granted: dict,
                          ready: asyncio.Event):
         nonlocal n_recv
         r, w, parser = await connect(cid)
@@ -667,9 +698,10 @@ async def _client(plan: dict) -> dict:
                 assert pkt.reason_code == 0, pkt
                 w.write(C.serialize(C.Subscribe(packet_id=1, subscriptions=[
                     C.Subscription(topic_filter=flt, qos=qos)
+                    for flt in flts
                 ]), ver))
             elif pkt.type == C.SUBACK:
-                granted[cid] = pkt.reason_codes[0]
+                granted[cid] = list(pkt.reason_codes)
                 ready.set()
             elif pkt.type == C.PUBLISH:
                 if pkt.qos == 1:
@@ -720,11 +752,11 @@ async def _client(plan: dict) -> dict:
 
     granted: dict = {}
     tasks, events = [], []
-    for cid, flt, qos in plan["subs"]:
+    for cid, flts, qos in plan["subs"]:
         ev = asyncio.Event()
         events.append(ev)
         tasks.append(asyncio.ensure_future(
-            subscriber(cid, flt, qos, granted, ev)
+            subscriber(cid, flts, qos, granted, ev)
         ))
     await asyncio.wait_for(
         asyncio.gather(*(e.wait() for e in events)), 120
@@ -781,6 +813,7 @@ def per_device(devs) -> list:
 def sharded_phase(args, devs, pairs, pops, ref, compiles: CompileLog):
     import jax
     import numpy as np
+    from bench import make_topics
 
     from emqx_tpu.config import BrokerEngineConfig
     from emqx_tpu.engine import MatchEngine
@@ -796,7 +829,7 @@ def sharded_phase(args, devs, pairs, pops, ref, compiles: CompileLog):
     check(mesh.shape["sub"] == k, f"mesh {dict(mesh.shape)}")
     rng = np.random.default_rng(args.seed)
     windows = [
-        zipf_topics(rng, WINDOW_TOPICS, pops) for _ in range(args.windows)
+        make_topics(rng, WINDOW_TOPICS, pops) for _ in range(N_WINDOWS)
     ]
 
     sh = ShardedMatchEngine(mesh, **kw)
@@ -902,8 +935,6 @@ def main(argv=None) -> int:
     ap.add_argument("--subs", type=int, default=1_000_000,
                     help="background wildcard subscriptions")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--windows", type=int, default=8,
-                    help="steady 4096-topic windows in the engine phase")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: only the sharded engine and its comparison")
     args = ap.parse_args(argv)

@@ -279,13 +279,24 @@ class BrokerServer:
         failpoints.load_env()
         self.broker._loop = asyncio.get_running_loop()
         eng_cfg = self.broker.config.engine
-        if self.broker.router.engine.use_device is not False:
+        engine = self.broker.router.engine
+        if engine.use_device is not False:
             # persistent XLA cache: automaton capacity-class compiles
             # happen once EVER, not once per process — a first-use
             # compile stalls concurrent matches for seconds
             from ..engine import enable_compile_cache
 
             enable_compile_cache()
+            # compile BEFORE a listener accepts: the match kernels for
+            # whatever table the boot restored and the rules kernel
+            # for the loaded rules, at every window bucket up to
+            # batch_max — paid inside the first dispatch windows these
+            # compiles hold ordered dispatch for tens of seconds and
+            # run queued windows past the breaker deadline.  Folds and
+            # rebuilds after this warm the same buckets themselves.
+            await self.broker._loop.run_in_executor(
+                None, engine.warmup, eng_cfg.batch_max
+            )
         if eng_cfg.batch_publish:
             from .broker import PublishBatcher
 

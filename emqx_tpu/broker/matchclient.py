@@ -553,6 +553,12 @@ class ServiceMatchEngine(MatchEngine):
         except Exception:
             with self._cond:
                 self._waiting.discard(seq)
+                if self._done.pop(seq, None) is not None:
+                    # the completion had already arrived: the service
+                    # is done writing and no later doorbell will come
+                    # to drain a quarantine, so free the slot now
+                    self._ring.release(slot)
+                    return None
                 self._abandoned[seq] = slot
                 self.svc_stats["quarantined"] += 1
             m = self.metrics

@@ -109,9 +109,9 @@ def enable_compile_cache() -> Optional[str]:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
     no directory is set in code.  Every program is kept, however short
-    its compile: the decide step compiles in under half a second and
-    would otherwise compile anew in every process.  Safe to call
-    repeatedly."""
+    its compile: at JAX's 0.5 s floor a second run on the chip compiled
+    11 of the 34 warmed match programs anew (the narrow batch buckets
+    compile faster than that).  Safe to call repeatedly."""
     import jax
 
     try:
@@ -504,6 +504,9 @@ class MatchEngine:
         self._ccap_mult = 2
         # (nodes, buckets, levels, batch) classes already shape-warmed
         self._warmed_shapes: Set[Tuple[int, int, int, int]] = set()
+        # widest batch bucket the background fold/build threads warm a
+        # new automaton for; `warmup()` raises it to the served width
+        self._warm_batch = 16
         # ---- window decide step (dispatch decision columns) --------
         # The dispatch half's per-delivery decisions compute as one
         # vectorized pass (ops.match_kernel.decide_batch + its numpy
@@ -960,7 +963,7 @@ class MatchEngine:
                             # failure is non-fatal: the uploaded
                             # tables still serve (worst case the first
                             # match pays the compile).
-                            self._warm_built(aut, dev)
+                            self._warm_buckets(aut, dev)
                         except Exception:
                             import logging
 
@@ -1084,6 +1087,16 @@ class MatchEngine:
                 batch=batch,
             )
 
+    def _warm_buckets(self, aut, dev) -> None:
+        """`_warm_built` at every batch bucket a served window can
+        take (16 up to the width `warmup()` was given), so a fold or
+        rebuild that crosses a capacity class compiles in its own
+        thread, not in the first wide window after the swap."""
+        bp = 16
+        while bp <= self._warm_batch:
+            self._warm_built(aut, dev, bp)
+            bp *= 2
+
     def _drop_delta_aut(self) -> None:
         self._daut = None
         self._ddev = None
@@ -1163,7 +1176,7 @@ class MatchEngine:
                 # pays a shape-class compile in its own latency
                 try:
                     if built[1] is not None and built[0].n_nodes > 1:
-                        self._warm_built(built[0], built[1])
+                        self._warm_buckets(built[0], built[1])
                 except Exception:
                     import logging
 
@@ -1246,10 +1259,21 @@ class MatchEngine:
         to ``max_batch`` (the `_pad_batch` shape set), and the rules
         kernel for the registered program over the same buckets, so a
         production publish flood never stalls on a first-use XLA
-        compile.  Returns the number of match buckets warmed (0 when
-        the device path is off)."""
+        compile.  A build or fold in flight is waited for and adopted
+        first (it is what will serve), and every later fold or
+        rebuild warms the same buckets in its own thread.  Returns
+        the number of match buckets warmed (0 when the device path is
+        off or no automaton is built yet)."""
+        for t in (self._build_thread, self._fold_thread):
+            if t is not None and t.is_alive():
+                t.join()
+        # raised only now: the sweep below covers whatever those
+        # threads built, and a fold the swap discards would have
+        # compiled every bucket for a table nothing serves
+        self._warm_batch = max(self._warm_batch, max_batch)
         self._warm_rules(max_batch)
         with self._mlock:
+            self._poll_swap()
             device_on = (
                 self.use_device is not False
                 and self._aut is not None
@@ -1277,6 +1301,8 @@ class MatchEngine:
                 with self._mlock:
                     snap = self._snapshot_refs()
                 self._warm_built(snap[0], snap[1], bp)
+                if snap[6][0] is not None and snap[6][1] is not None:
+                    self._warm_built(snap[6][0], snap[6][1], bp)
                 n += 1
                 bp *= 2
             # the sweep's first-use compiles polluted the device-cost

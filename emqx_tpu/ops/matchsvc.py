@@ -256,6 +256,15 @@ class MatchService:
     # ------------------------------------------------------ lifecycle
 
     async def start(self) -> None:
+        if self.engine.use_device is not False:
+            # the engine is empty until the workers replay their
+            # routes: this sets the bucket width its folds and
+            # rebuilds warm a new automaton for, before the swap —
+            # the workers' shipped window width (their batch_max is
+            # not on the wire)
+            from ..config import BrokerEngineConfig
+
+            self.engine.warmup(BrokerEngineConfig().batch_max)
         self._server = await asyncio.start_unix_server(
             self._serve, path=self.socket_path
         )

@@ -23,9 +23,13 @@ status=0
 
 for src in sortutil tokdict dslog hosttrie dispatchasm; do
     out="build/lib${src}.so"
-    if g++ $FLAGS -o "$out" "${src}.cpp"; then
+    # link under a private name and rename into place: a process that
+    # loads the library meanwhile sees the old file or the new one,
+    # never half of one
+    if g++ $FLAGS -o "$out.$$" "${src}.cpp" && mv -f "$out.$$" "$out"; then
         echo "built $out"
     else
+        rm -f "$out.$$"
         echo "SKIPPED $out (build failed; pure-Python fallback will serve)" >&2
         status=1
     fi

@@ -17,18 +17,35 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
-def test_default_phases_rehearsed_on_cpu(monkeypatch, capsys):
+@pytest.fixture
+def jax_cache_config():
+    """The script turns the persistent compile cache on for its
+    process; put this worker's JAX configuration back afterwards."""
+    import jax
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_default_phases_rehearsed_on_cpu(monkeypatch, capsys,
+                                         jax_cache_config):
     import chip_smoke
 
     monkeypatch.setattr(chip_smoke, "PLATFORM", "cpu")
     monkeypatch.setattr(chip_smoke, "WINDOW_TOPICS", 32)
+    monkeypatch.setattr(chip_smoke, "N_WINDOWS", 3)
     monkeypatch.setattr(chip_smoke, "N_RULES", 6)
-    monkeypatch.setattr(chip_smoke, "N_LIVE", 20)
     monkeypatch.setattr(chip_smoke, "N_PUBLISH", 200)
     monkeypatch.setattr(chip_smoke, "N_PUBLISHERS", 2)
     # the client child inherits the CPU pin and must not need it
     assert os.environ["JAX_PLATFORMS"] == "cpu"
-    assert chip_smoke.main(["--subs", "400", "--windows", "3"]) == 0
+    # the smallest table the broker still builds, folds and warms by
+    # itself (the engine's rebuild and fold thresholds)
+    assert chip_smoke.main(["--subs", "5000"]) == 0
     lines = [
         json.loads(ln) for ln in capsys.readouterr().out.splitlines()
         if ln.startswith("{")
@@ -50,7 +67,10 @@ def test_default_phases_rehearsed_on_cpu(monkeypatch, capsys):
     assert eng["all_windows_dev"] and eng["equal_to_referee"]
     assert eng["windows"] == 3 and eng["steady_compile_requests"] == 0
     assert eng["breaker"]["device_errors"] == 0
+    load = by_phase["served_load"]
+    assert load["base"] == 5000 + 6 and load["folded"] >= 1024
     served = by_phase["served_traffic"]
+    assert "rules_eval_batch" not in served["served_compile_s_by_fn"]
     assert served["pubacks"] == served["publishes"] == 200
     assert served["window_paths"] == {"dev": served["windows"]}
     assert served["decide_dev_windows"] > 0

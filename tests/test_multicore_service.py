@@ -378,10 +378,15 @@ def test_submit_seam_drop_falls_back_bit_identical(tmp_path):
         svc.stop()
 
 
-def test_complete_seam_error_falls_back_without_slot_leak(tmp_path):
+@pytest.mark.parametrize("completion_first", [False, True])
+def test_complete_seam_error_falls_back_without_slot_leak(
+    tmp_path, completion_first
+):
     """An injected completion fault degrades the window to the mirror
     AND quarantines-then-drains its slot: the late completion from the
-    (healthy) service returns it to the free list."""
+    (healthy) service returns it to the free list — and one that had
+    arrived before the fault frees it at once (no later doorbell would
+    come to drain a quarantine)."""
     sock = str(tmp_path / "svc.sock")
     svc = SvcThread(sock).start()
     eng = _attach_engine(sock)
@@ -392,6 +397,9 @@ def test_complete_seam_error_falls_back_without_slot_leak(tmp_path):
         info = {}
         pending = eng.match_batch_submit(_TOPICS)
         assert pending[0] == "svc"  # submit succeeded; completion fails
+        if completion_first:
+            wait_until(lambda: pending[1][1] in eng._done,
+                       what="completion before finish")
         out = eng.match_batch_finish(pending, info=info)
         assert info["path"] == "host-fallback"
         assert out == referee.match_batch(_TOPICS)

@@ -329,6 +329,60 @@ def test_warmup_compiles_the_rules_kernel_before_traffic():
     assert rules_eval_batch._cache_size() == warmed
 
 
+def test_server_start_warms_and_later_folds_follow():
+    """`BrokerServer.start()` warms before a listener accepts — the
+    rules kernel and the restored table's match kernels at every
+    bucket up to ``batch_max`` — and a fold after it warms the same
+    buckets in its own thread, so no served window compiles."""
+    import asyncio
+
+    from emqx_tpu.broker.listener import BrokerServer
+    from emqx_tpu.config import BrokerConfig, ListenerConfig
+    from emqx_tpu.ops.match_kernel import (
+        match_batch_compact, rules_eval_batch,
+    )
+
+    cfg = BrokerConfig()
+    cfg.listeners = [ListenerConfig(bind="127.0.0.1", port=0)]
+    cfg.engine.use_device = True
+    cfg.engine.batch_max = 64
+    server = BrokerServer(cfg)
+    eng = server.broker.router.engine
+    server.broker.rules.add_rule(
+        "r", 'SELECT * FROM "t/#" WHERE payload.a > 1', []
+    )
+    n_base = cfg.engine.rebuild_threshold  # starts a background build
+    eng.insert_many([(f"a/{i}/+", i) for i in range(n_base)])
+    rules_cold = rules_eval_batch._cache_size()
+
+    def buckets_of(aut):
+        sig = (aut.node_rows.shape[0], len(aut.fp_rows), aut.kernel_levels)
+        return {s[3] for s in eng._warmed_shapes if s[:3] == sig}
+
+    async def run():
+        await server.start()
+        try:
+            assert eng.index_stats()["base"] == n_base + 1
+            assert buckets_of(eng._aut) == {16, 32, 64}
+            assert rules_eval_batch._cache_size() == rules_cold + 3
+            for i in range(eng.delta_aut_threshold):
+                eng.insert(f"b/{i}/#", n_base + 1 + i)
+            while eng.index_stats()["folding"]:
+                await asyncio.sleep(0.01)
+            assert eng.index_stats()["folded"] == eng.delta_aut_threshold
+            assert buckets_of(eng._daut) == {16, 32, 64}
+            warmed = match_batch_compact._cache_size()
+            got = eng.match_batch(
+                [f"a/{i}/x" for i in range(40)] + ["b/7/y"]
+            )
+            assert got[3] == {3} and got[40] == {n_base + 8}
+            assert match_batch_compact._cache_size() == warmed
+        finally:
+            await server.stop()
+
+    asyncio.run(run())
+
+
 def test_host_twin_block_chunking_and_program_dedup():
     """Registries past RULES_HOST_BLOCK evaluate in slabs (distinct
     literals defeat dedup), and identical programs share one row."""
