@@ -1,0 +1,98 @@
+#!/usr/bin/env python3
+"""The control of `correct`: a run of `run.py` with one guarantee of the
+configuration broken underneath the timed path.  Each must read
+``"correct": false``.  The benchmark's own runs never come here.
+
+    python benchmark/control.py --fault <name> --workload <cell> --seed <n> --seconds <s>
+
+    lost_match    an answer altered where it is produced: once in 8
+                  windows the match step returns nothing, so the
+                  deliveries and rule firings that window owes never
+                  happen
+    weak_ack      a weakened acknowledgement: one publish in 500 is
+                  acknowledged and never dispatched
+    host_decide   the device guarantee broken: delivery decisions pinned
+                  to the host twin (the program's own `decide_force`)
+    host_match    the same for the match step, by the program's own
+                  failpoint ``engine.device_step=error`` (the windows are
+                  then served by the host trie: right answers, wrong path)
+
+A fault takes the `BrokerServer` before `start()` and returns what
+undoes it (tests run several in one process).
+"""
+
+import argparse
+import asyncio
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+
+def lost_match(server):
+    eng = server.broker.router.engine
+    real = eng.match_batch_finish
+    calls = [0]
+
+    def finish(pending, *a, **kw):
+        matched = real(pending, *a, **kw)
+        calls[0] += 1
+        if calls[0] % 8 == 0:
+            matched = [set() for _ in matched]
+        return matched
+
+    eng.match_batch_finish = finish
+    return lambda: None
+
+
+def weak_ack(server):
+    from emqx_tpu.broker.broker import PublishBatcher
+
+    real = PublishBatcher.publish
+    calls = [0]
+
+    def publish(self, msg, source=None):
+        calls[0] += 1
+        if calls[0] % 500 == 250:
+            fut = asyncio.get_running_loop().create_future()
+            fut.set_result(1)
+            return fut
+        return real(self, msg, source)
+
+    PublishBatcher.publish = publish
+
+    def undo():
+        PublishBatcher.publish = real
+    return undo
+
+
+def host_decide(server):
+    server.broker.router.engine.decide_force = "host"
+    return lambda: None
+
+
+def host_match(server):
+    from emqx_tpu import failpoints
+
+    failpoints.configure("engine.device_step", "error")
+    return lambda: failpoints.clear("engine.device_step")
+
+
+FAULTS = {"lost_match": lost_match, "weak_ack": weak_ack,
+          "host_decide": host_decide, "host_match": host_match}
+
+
+def main(argv=None) -> int:
+    import run
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--fault", required=True, choices=sorted(FAULTS))
+    args, rest = ap.parse_known_args(argv)
+    # an answer the fault took away never comes: no minute's wait for it
+    run.DRAIN_S = 5.0
+    return run.main(rest, fault=FAULTS[args.fault])
+
+
+if __name__ == "__main__":
+    sys.exit(main())

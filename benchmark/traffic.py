@@ -1,0 +1,185 @@
+"""The benchmark's one general generator: tables, rules, live
+subscribers, topic pools and schedules, all from parameters in the
+configuration's and the cell's data files and from ``--seed``.
+
+Imports nothing of the program (`emqx_tpu`) and no JAX.  The fleet
+generators are copies of `bench.make_filters` / `bench.make_topics`
+and of `chip_smoke.rule_sql` / `live_filters` / `payload_of` (see
+PERF.md, Open questions: the originals are a later PR's to delete).
+
+Every seed gets the SAME multiset of topics, gaps and sizes in another
+order: pools are drawn from the fixed ``pool_seed`` of the data file,
+``--seed`` only permutes them.  So two seeds do the same work.
+"""
+
+import numpy as np
+
+SEQ_AT = 7          # payload[SEQ_AT:SEQ_AT + SEQ_W] is the sequence number
+SEQ_W = 10
+
+
+# ------------------------------------------------------------- payload
+
+def payload_of(seq: int) -> bytes:
+    """The ~70 B JSON reading of `chip_smoke.payload_of`, every field at
+    a fixed width (space-padded numbers are valid JSON), so the sequence
+    number can be sliced out without parsing."""
+    return (
+        b'{"seq":%10d,"temp":%2d,"hum":%2d,"dev":"d%d","ok":%s}'
+        % (seq, seq * 7 % 50, seq * 13 % 100, seq % 7,
+           b"true " if seq % 3 == 0 else b"false")
+    )
+
+
+# -------------------------------------------------------------- tables
+
+def table_fleet_families(subscriptions: int, fanout: int):
+    """`bench.make_filters`: four fleet-telemetry wildcard families,
+    ``fanout`` subscriptions sharing each filter.  Returns
+    ``[(filter, fid)]`` and the id populations the topic pool needs."""
+    n = subscriptions
+    n_vehicles = max(n // (2 * fanout), 1)
+    n_dev = max(n // (5 * fanout), 1)
+    n_site = max(n // (5 * fanout), 1)
+    n_alert = max(n // (10 * fanout), 1)
+    pairs = []
+    for i in range(n):
+        kind = i % 10
+        if kind < 5:
+            pairs.append((f"vehicles/v{i % n_vehicles}/sensors/#", i))
+        elif kind < 7:
+            pairs.append((f"dev/g{i % n_dev}/+/d{i % 7}", i))
+        elif kind < 9:
+            pairs.append((f"site/+/floor/f{i % n_site}/#", i))
+        else:
+            pairs.append((f"alerts/z{i % n_alert}/+/+", i))
+    return pairs, (n_vehicles, n_dev, n_site, n_alert)
+
+
+def table_none(**_):
+    return [], (1, 1, 1, 1)
+
+
+TABLES = {"fleet_families": table_fleet_families, "none": table_none}
+
+
+# --------------------------------------------------------------- rules
+
+def rule_sql(i: int) -> str:
+    """`chip_smoke.rule_sql`: five arithmetic-free WHERE templates."""
+    kind = i % 5
+    if kind == 0:
+        return (f'SELECT payload.seq as seq FROM "vehicles/+/sensors/#" '
+                f"WHERE payload.temp > {i % 40}")
+    if kind == 1:
+        return (f'SELECT * FROM "dev/#" WHERE payload.dev = \'d{i % 7}\' '
+                f"and payload.hum <= {20 + i % 60}")
+    if kind == 2:
+        return (f'SELECT topic FROM "site/+/floor/#" WHERE '
+                f"payload.temp >= {i % 30} or not (payload.hum < {i % 50})")
+    if kind == 3:
+        return (f'SELECT clientid FROM "vehicles/#" WHERE '
+                f"payload.dev in ('d{i % 7}', 'd{(i + 3) % 7}') "
+                f"and is_not_null(payload.hum)")
+    return (f'SELECT payload FROM "#" WHERE payload.temp = {i % 50} '
+            f"and payload.dev != 'd{i % 7}'")
+
+
+# ---------------------------------------------------- live subscribers
+
+def live_fleet(subscribers: int, filters_each: int):
+    """`chip_smoke.live_filters`: subscriber ``j`` holds ``filters_each``
+    wildcard filters, disjoint from one another; QoS 0/1 alternating.
+    Returns ``[(clientid, [filters], qos)]``."""
+    out = []
+    stride = subscribers // 5 + 1
+    for j in range(subscribers):
+        kind, flts = j % 5, []
+        for n in range(filters_each):
+            k = j // 5 + 1 + n * stride
+            if kind == 0:
+                flts.append(f"vehicles/v{k}/sensors/#")
+            elif kind == 1:
+                flts.append(f"dev/g{k}/+/d{k % 7}")
+            elif kind == 2:
+                flts.append(f"site/+/floor/f{k}/#")
+            elif kind == 4:
+                flts.append(f"vehicles/v{k}/#")
+            elif n:
+                flts.append(f"site/+/floor/f{k}/a")
+            else:
+                flts.append(
+                    "vehicles/+/sensors/temp" if k % 2 else "dev/+/x/+"
+                )
+        out.append((f"sub{j}", flts, j % 2))
+    return out
+
+
+def live_exact_fanout(subscribers: int, topics: int, qos=None):
+    """emqtt-bench's fan-out scenario: one exact-match subscription a
+    connection, ``subscribers / topics`` on each topic; QoS 0/1
+    alternating unless ``qos`` fixes it."""
+    return [
+        (f"sub{j}", [f"fanout/t{j % topics}"], j % 2 if qos is None else qos)
+        for j in range(subscribers)
+    ]
+
+
+LIVE = {"fleet_live": live_fleet, "exact_fanout": live_exact_fanout}
+
+
+# ---------------------------------------------------------- topic pools
+
+def pool_fleet_zipf(rng, pool: int, pops, zipf: float = 1.3):
+    """`bench.make_topics`: 60% vehicle readings with Zipf ids, 20% dev,
+    10% site, 10% that match nothing."""
+    n_vehicles, n_dev, n_site, _ = pops
+    z = rng.zipf(zipf, size=pool) % max(n_vehicles, 1)
+    out = []
+    for i in range(pool):
+        k = i % 10
+        if k < 6:
+            out.append(f"vehicles/v{z[i]}/sensors/temp")
+        elif k < 8:
+            out.append(f"dev/g{i % n_dev}/x/d{i % 7}")
+        elif k < 9:
+            out.append(f"site/s{i % 7}/floor/f{i % n_site}/a")
+        else:
+            out.append(f"nomatch/q{i}")
+    return out
+
+
+def pool_exact(rng, pool: int, pops):
+    return [f"fanout/t{k}" for k in range(pool)]
+
+
+POOLS = {"fleet_zipf": pool_fleet_zipf, "exact_topics": pool_exact}
+
+
+def topic_pool(spec: dict, pops, seed: int, publishers: int):
+    """The cell's topic pool in this seed's order.  Publish ``seq`` goes
+    out on connection ``seq % publishers`` with topic
+    ``pool[seq % len(pool)]``.  A pool no longer than the publisher
+    count keeps its order (one topic a publisher, as the exact cell
+    wants); any other is permuted by the seed."""
+    spec = dict(spec)
+    gen = POOLS[spec.pop("generator")]
+    pool_seed = spec.pop("pool_seed", 1)
+    pool = gen(np.random.default_rng(pool_seed), pops=pops, **spec)
+    if len(pool) > publishers:
+        order = np.random.default_rng(seed).permutation(len(pool))
+        pool = [pool[i] for i in order]
+    return pool
+
+
+def poisson_schedule(rate: float, seconds: float, seed: int,
+                     pool_seed: int = 1):
+    """Due offsets (seconds after the window opens) of an open-loop
+    Poisson stream: the gaps are drawn once from ``pool_seed``, scaled
+    to fill the window exactly, and permuted by ``seed`` — every seed
+    offers the same number of publishes with the same gaps."""
+    n = int(round(rate * seconds))
+    gaps = np.random.default_rng(pool_seed).exponential(1.0, size=n + 1)
+    gaps *= seconds / gaps.sum()
+    gaps = gaps[np.random.default_rng(seed).permutation(n + 1)]
+    return np.cumsum(gaps)[:n]
