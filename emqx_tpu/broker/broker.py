@@ -1080,11 +1080,13 @@ class Broker:
         executor threads, but strictly one after the other."""
         if not live:
             return (None, [], rec)
+        if rec is not None:
+            entered = time.perf_counter()
+            rec.mark("match_submit")
         topics = [m.topic for m in live]
+        engine = self.router.engine
         try:
-            pending = self.router.engine.match_batch_submit(
-                topics, congested=congested
-            )
+            pending = engine.match_batch_submit(topics, congested=congested)
         except Exception:
             log.exception(
                 "match submit failed for window of %d; host fallback",
@@ -1092,7 +1094,12 @@ class Broker:
             )
             pending = None
         if rec is not None:
-            rec.lap("match_submit")
+            # the wait is the executor's queue, between the collector's
+            # call and the engine's first timed section
+            rec.lap_parts(
+                "match_submit", "submit_queue_wait", entered,
+                engine.submit_timings(pending) if pending else (),
+            )
         return (pending, topics, rec)
 
     def publish_match_finish(
@@ -1106,14 +1113,17 @@ class Broker:
         if not topics:
             return [], None
         path = "host-fallback"
+        # the engine reports the path that ACTUALLY served the window
+        # (an internal device fault degrades to host without raising —
+        # the flight record must say so) and what it timed on the way
+        info: Dict[str, object] = {}
+        if rec is not None:
+            entered = time.perf_counter()
+            info["seq"] = rec.seq
         try:
             if pending is None:
                 matched = self.router.engine.match_batch_host(topics)
             else:
-                # the engine reports the path that ACTUALLY served the
-                # window (an internal device fault degrades to host
-                # without raising — the flight record must say so)
-                info: Dict[str, str] = {}
                 matched = self.router.engine.match_batch_finish(
                     pending, info=info
                 )
@@ -1125,7 +1135,15 @@ class Broker:
             )
             matched = self.router.engine.match_batch_host(topics)
         if rec is not None:
-            rec.lap("match_wait")
+            # the wait: queued behind the predecessors' dispatch in the
+            # ordered dispatch loop, then the hop into this thread
+            timings = info.get("timings", ())
+            rec.lap_parts(
+                "match_wait", "finish_queue_wait", entered, timings
+            )
+            rec.n_clips = sum(
+                name == "dense_rematch" for name, _, _ in timings
+            )
             rec.path = path
             rec.breaker_open = self.router.engine.breaker_open
         remote: Optional[List[Set[str]]] = None
@@ -1149,8 +1167,10 @@ class Broker:
         hits over the batch in one predicate step.  Commits ``rec`` —
         the window's profiler record — whatever happens above."""
         if rec is not None:
-            # time queued behind predecessor windows in the ordered
-            # dispatch loop: its own span, not smeared into expand
+            # the hop from the finish's executor thread back to the
+            # loop (the queue behind predecessor windows is the match
+            # wait's ``finish_queue_wait``): its own span, not smeared
+            # into expand
             rec.lap("dispatch_wait")
         rule_sink: List[Tuple[Message, List[str]]] = []
         counts: List[int] = []
@@ -1187,6 +1207,8 @@ class Broker:
             # ONE registry pass for the whole window: shared column
             # extraction + the rules x window matrix (rec carries the
             # rules_extract/rules_eval sub-stage attribution)
+            if rec is not None:
+                rec.mark("rules")
             try:
                 self.rules.apply_batch(rule_sink, rec=rec)
             except Exception:
@@ -1319,6 +1341,8 @@ class Broker:
         router = self.router
         n = len(msgs)
         counts = [0] * n
+        if rec is not None:
+            rec.mark("expand")
         if preexpanded is None:
             msg_idx, rows, opts_rows, rules, shared = (
                 router.expand_window(matched)
@@ -1329,6 +1353,9 @@ class Broker:
             shared = []
         if rec is not None:
             rec.lap("expand")
+            # the socket writes from here to the flush lap are inside
+            # this window's laps as well as on the loop's clock
+            self.profiler.loop.in_window = True
         if rules and run_rules:
             # ``rules`` is already grouped per message; the sink takes
             # the RAW id lists (the rule engine's flatten cache dedups
@@ -1418,13 +1445,15 @@ class Broker:
                     ts_min, rec,
                 )
             else:
+                if rec is not None:
+                    rec.mark("deliver")
                 n_clients = self._dispatch_scalar(
                     msgs, sra, sm_a, so_a, dollar, touched, counts,
                     enc, mloc, corked, bake_cache, delivered_runs,
                     deliver_hook, asm, ts_min,
                 )
         if rec is not None:
-            rec.lap("deliver")
+            rec.lap("deliver", then="flush")
             if asm[0]:
                 # nested sub-stage: the native splice share of deliver
                 rec.sub("assemble", asm[0])
@@ -1448,6 +1477,7 @@ class Broker:
             mloc["messages.delivered"] += delivered
         if rec is not None:
             rec.lap("flush")
+            self.profiler.loop.in_window = False
             rec.n_deliveries = delivered
             rec.n_clients = n_clients
             if delivered and not replay:
@@ -1624,6 +1654,8 @@ class Broker:
         n = len(msgs)
         nd_total = len(sra)
         row_of = router.row_of_client
+        if rec is not None:
+            rec.mark("decide")
 
         def from_row(m) -> int:
             r = row_of(m.from_client) if m.from_client else None
@@ -1634,9 +1666,10 @@ class Broker:
         m_qos = np.fromiter((m.qos for m in msgs), np.int8, n)
         m_retain = np.fromiter((m.retain for m in msgs), bool, n)
         m_from = np.fromiter((from_row(m) for m in msgs), np.int32, n)
+        dec_info: Optional[Dict] = {} if rec is not None else None
         packed, _dec_path = router.engine.decide_window(
             router.opts_columns(), router.opts_rev,
-            so_a, sra, sm_a, m_qos, m_retain, m_from,
+            so_a, sra, sm_a, m_qos, m_retain, m_from, dec_info,
         )
         # unpack the compact column into the window-wide decision
         # views (numpy bit ops; one byte per delivery came back)
@@ -1652,7 +1685,10 @@ class Broker:
         kmin = base_key + qmin * 2
         kmax = base_key + qmax * 2
         if rec is not None:
-            rec.lap("decide")
+            if "device_wait" in dec_info:
+                start, dur = dec_info["device_wait"]
+                rec.sub("decide_device_wait", dur, start)
+            rec.lap("decide", then="deliver")
         # per-message tracing masks, computed ONCE per window: a run
         # materializes its deliveries for the OTel span / lifecycle
         # trace only when it actually carries a traced message

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from typing import List, Optional
 
 from ..codec import mqtt as C
@@ -20,6 +21,7 @@ from .channel import Channel, CONNECTING
 log = logging.getLogger("emqx_tpu.connection")
 
 _TIMER_TICK = 5.0  # keepalive/retry check cadence
+_ACKS = frozenset((C.PUBACK, C.PUBREC, C.PUBREL, C.PUBCOMP))
 
 
 class Connection:
@@ -70,6 +72,9 @@ class Connection:
     def _send_packets(self, packets: List[C.Packet]) -> None:
         if self.writer.is_closing():
             return
+        # the loop's own clock: two reads a socket write, none a packet
+        lc = self.broker.profiler.loop
+        t_out = time.perf_counter() if lc is not None else 0.0
         m = self.broker.metrics
         version = self.channel.version
         n = 0
@@ -83,6 +88,8 @@ class Connection:
         m.inc("packets.sent", n)
         m.inc("bytes.sent", len(data))
         self.writer.write(data)
+        if lc is not None:
+            lc.egress(t_out, len(data), n)
         # ONE accessor for the transport's write-buffer signal — the
         # same `out_buffered` the dispatch watermark reads (0 when the
         # transport can't report, which also skips the alarm below)
@@ -128,6 +135,9 @@ class Connection:
         """The connection's receive loop (emqx_connection:run_loop)."""
         timer = asyncio.get_running_loop().create_task(self._timers())
         reason = "closed"
+        # the loop's own clock: two reads a socket read, none a packet
+        lc = self.broker.profiler.loop
+        t_in = 0.0
         try:
             idle = self.broker.config.mqtt.idle_timeout
             while not self._closed.is_set():
@@ -141,9 +151,17 @@ class Connection:
                     break
                 if not data:
                     break
+                if lc is not None:
+                    t_in = time.perf_counter()
                 self.broker.metrics.inc("bytes.received", len(data))
+                n_pkts = n_pubs = n_acks = 0
                 if self.limiter is None:
                     for pkt in self.parser.feed(data):
+                        n_pkts += 1
+                        if pkt.type == C.PUBLISH:
+                            n_pubs += 1
+                        elif pkt.type in _ACKS:
+                            n_acks += 1
                         self.channel.handle_in(pkt)
                         if self._closed.is_set():
                             break
@@ -159,21 +177,29 @@ class Connection:
                     # hand out long waits under contention and cutting
                     # them short would let the aggregate rate scale
                     # with the number of connections.
+                    # (a pause is no work of the loop's: it moves the
+                    # read's start forward by what was slept)
                     delay = self.limiter.consume(len(data), 0)
                     if delay > 0:
                         self.broker.metrics.inc("connection.rate_limited")
-                        await self._pause(delay)
+                        t_in += await self._pause(delay)
                     for pkt in self.parser.feed(data):
+                        n_pkts += 1
                         if pkt.type == C.PUBLISH:
+                            n_pubs += 1
                             delay = self.limiter.consume(0, 1)
                             if delay > 0:
                                 self.broker.metrics.inc(
                                     "connection.rate_limited"
                                 )
-                                await self._pause(delay)
+                                t_in += await self._pause(delay)
+                        elif pkt.type in _ACKS:
+                            n_acks += 1
                         self.channel.handle_in(pkt)
                         if self._closed.is_set():
                             break
+                if lc is not None:
+                    lc.ingress(t_in, len(data), n_pkts, n_pubs, n_acks)
                 await self._drain()
                 batcher = self.broker.batcher
                 if batcher is not None and batcher.congested(self.channel):
@@ -205,14 +231,17 @@ class Connection:
             except (ConnectionError, asyncio.CancelledError):
                 pass
 
-    async def _pause(self, delay: float) -> None:
+    async def _pause(self, delay: float) -> float:
         """Sleep a limiter deficit in 1s slices, bailing early when
         the connection is closed (kick/stop must not wait out a long
-        shared-bucket debt)."""
+        shared-bucket debt).  Returns the seconds slept."""
+        slept = 0.0
         while delay > 0 and not self._closed.is_set():
             step = min(delay, 1.0)
             await asyncio.sleep(step)
             delay -= step
+            slept += step
+        return slept
 
     async def _drain(self) -> None:
         try:

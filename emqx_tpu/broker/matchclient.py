@@ -574,7 +574,8 @@ class ServiceMatchEngine(MatchEngine):
     ):
         handle = self._ring_submit(topics, congested)
         if handle is not None:
-            return ("svc", handle, list(topics))
+            # (no timings: a window shipped to the service is timed there)
+            return ("svc", handle, list(topics), ())
         return super().match_batch_submit(
             topics, congested, _force_device=_force_device
         )
@@ -582,7 +583,7 @@ class ServiceMatchEngine(MatchEngine):
     def match_batch_finish(self, pending, info=None):
         if pending[0] != "svc":
             return super().match_batch_finish(pending, info=info)
-        _, (epoch, seq, slot), topics = pending
+        _, (epoch, seq, slot), topics, _ = pending
         payload = self._ring_complete(epoch, seq, slot)
         if payload is None:
             with self._lk:
@@ -630,6 +631,7 @@ class ServiceMatchEngine(MatchEngine):
         m_qos: np.ndarray,
         m_retain: np.ndarray,
         m_from_row: np.ndarray,
+        info=None,
     ) -> Tuple[np.ndarray, str]:
         with self._lk:
             use_svc = (
@@ -647,7 +649,7 @@ class ServiceMatchEngine(MatchEngine):
                 self.svc_stats["fallbacks"] += 1
         return super().decide_window(
             cols, rev, opts_rows, client_rows, msg_idx, m_qos,
-            m_retain, m_from_row,
+            m_retain, m_from_row, info,
         )
 
     def _ring_decide(self, cols, rev, opts_rows, client_rows, msg_idx,

@@ -28,6 +28,7 @@ import numpy as np
 
 from . import failpoints
 from . import topic as T
+from .observability import NO_LAPS, Laps
 from .tp import tp
 from .ops.automaton import Automaton, build_automaton
 from .ops.dictionary import SENTINEL, TokenDict, encode_topics
@@ -1644,10 +1645,14 @@ class MatchEngine:
         m_qos: np.ndarray,
         m_retain: np.ndarray,
         m_from_row: np.ndarray,
+        info: Optional[Dict] = None,
     ) -> Tuple[np.ndarray, str]:
         """Compute one window's packed per-delivery decision column
         (see ops.match_kernel's bit layout) on the host or the device,
         chosen per window by the measured per-delivery cost EWMAs.
+        ``info`` (optional dict) receives ``device_wait``: ``(start,
+        dur)`` of the kernel call and the blocking copy back, on the
+        perf_counter clock, where the device served.
 
         ``cols`` are the router's SubOpts attribute columns and ``rev``
         their mutation counter (the device copies cache on it).  A
@@ -1661,7 +1666,7 @@ class MatchEngine:
                 t0 = time.perf_counter()
                 packed = self._decide_device(
                     cols, rev, opts_rows, client_rows, msg_idx,
-                    m_qos, m_retain, m_from_row,
+                    m_qos, m_retain, m_from_row, info,
                 )
                 us = (time.perf_counter() - t0) * 1e6 / n
                 if self._dec_dev_warm:
@@ -1736,7 +1741,7 @@ class MatchEngine:
 
     def _decide_device(
         self, cols, rev, opts_rows, client_rows, msg_idx,
-        m_qos, m_retain, m_from_row,
+        m_qos, m_retain, m_from_row, info=None,
     ) -> np.ndarray:
         """One device decide step: upload the attribute columns (cached
         by ``rev``), pad the delivery/message columns to power-of-two
@@ -1763,8 +1768,7 @@ class MatchEngine:
             out[: len(a)] = a
             return out
 
-        packed = decide_batch(
-            *cache[1],
+        window = (
             pad(opts_rows, npad, 0, np.int32),
             pad(client_rows, npad, -1, np.int32),
             pad(msg_idx, npad, 0, np.int32),
@@ -1772,11 +1776,16 @@ class MatchEngine:
             pad(m_retain, bpad, False, bool),
             pad(m_from_row, bpad, -1, np.int32),
         )
-        return np.asarray(packed)[:n]
+        t0 = time.perf_counter() if info is not None else 0.0
+        packed = np.asarray(decide_batch(*cache[1], *window))
+        if info is not None:
+            info["device_wait"] = (t0, time.perf_counter() - t0)
+        return packed[:n]
 
     # -------------------------------------- rules x window matrix
 
-    def rules_eval_window(self, stack, rev: int, cols, rows=None):
+    def rules_eval_window(self, stack, rev: int, cols, rows=None,
+                          info: Optional[Dict] = None):
         """Evaluate the rule registry's stacked WHERE program against
         one window's column planes: the ``[n_rules, n_msgs]`` boolean
         pass matrix, host numpy twin or the fused device kernel
@@ -1794,13 +1803,14 @@ class MatchEngine:
         bit-identical host twin and counts against the shared PR 1
         circuit breaker, so a dead device path trips matching,
         deciding and rule eval to host together; the background
-        breaker probe heals all three."""
+        breaker probe heals all three.  ``info`` receives
+        ``device_wait`` as in `decide_window`."""
         n_active = stack.n_rules if rows is None else len(rows)
         n = n_active * cols.n
         if n and self._rules_choose(stack, cols, n):
             try:
                 t0 = time.perf_counter()
-                mat = self._rules_device(stack, rev, cols)
+                mat = self._rules_device(stack, rev, cols, info)
                 us = (time.perf_counter() - t0) * 1e6 / n
                 if self._rul_dev_warm:
                     self._rul_dev_us = (
@@ -1909,7 +1919,7 @@ class MatchEngine:
         # columns stay on the float64 numpy materialization
         return cols.f32_safe(len(stack.paths))
 
-    def _rules_device(self, stack, rev: int, cols) -> np.ndarray:
+    def _rules_device(self, stack, rev: int, cols, info=None) -> np.ndarray:
         """One device rules step: upload the stacked program (cached
         by the registry's ``rev``), pad rules/window/planes/literals
         to power-of-two buckets (bounded shape classes, as
@@ -1925,11 +1935,12 @@ class MatchEngine:
         n_p = len(stack.paths)
         return self._rules_run(
             stack, rev, cols.n, cols.lit_ranks, cols.num[:n_p],
-            cols.sid[:n_p], cols.err[:n_p], cols.prs[:n_p],
+            cols.sid[:n_p], cols.err[:n_p], cols.prs[:n_p], info,
         )
 
     def _rules_run(
-        self, stack, rev: int, w_n: int, lit_ranks, num, sid, err, prs
+        self, stack, rev: int, w_n: int, lit_ranks, num, sid, err, prs,
+        info=None,
     ) -> np.ndarray:
         """The padded kernel call behind `_rules_device` and
         `_warm_rules`: ``[P, w_n]`` planes in, ``[R, w_n]`` matrix
@@ -1973,14 +1984,19 @@ class MatchEngine:
 
         lits = np.zeros(_pow2_at_least(len(lit_ranks), 1), np.int32)
         lits[: len(lit_ranks)] = lit_ranks
-        mat = rules_eval_batch(
-            code, a0, a1, a2, a3, litn, lits, last,
+        planes = (
             padw(num, np.nan, np.float32),
             padw(sid, -1, np.int32),
             padw(err, False, bool),
             padw(prs, False, bool),
         )
-        return np.asarray(mat)[:r_n, :w_n]
+        t0 = time.perf_counter() if info is not None else 0.0
+        mat = np.asarray(rules_eval_batch(
+            code, a0, a1, a2, a3, litn, lits, last, *planes
+        ))
+        if info is not None:
+            info["device_wait"] = (t0, time.perf_counter() - t0)
+        return mat[:r_n, :w_n]
 
     def _warm_rules(self, max_batch: int) -> None:
         """Compile the rules kernel for the registered program at every
@@ -2047,13 +2063,9 @@ class MatchEngine:
         thread — executor-thread concurrency does NOT overlap the
         transfer wait (the blocking conversion serializes), async
         dispatch does (the standalone bench's depth-8 scheme)."""
-        prof = self.profiler
-        if prof is not None and prof.enabled:
-            _t_tok = time.perf_counter()
-            words = [T.words(t) for t in topics]
-            prof.stage("tokenize", time.perf_counter() - _t_tok)
-        else:
-            words = [T.words(t) for t in topics]
+        tm = self._laps()
+        words = [T.words(t) for t in topics]
+        tm.lap("tokenize")
         with self._mlock:
             if self._built is not None:
                 self._poll_swap()
@@ -2115,7 +2127,10 @@ class MatchEngine:
                 # keep a fresh sample for the out-of-band device probe
                 # (small: each probe's host-side cost is paid in GIL)
                 self._probe_topics = list(topics[:256])
-            return ("host-fallback" if snap_failed else "host", out)
+            return (
+                "host-fallback" if snap_failed else "host", out,
+                tm.timings(),
+            )
         t0 = time.perf_counter()
         c0 = time.thread_time()
         try:
@@ -2124,11 +2139,11 @@ class MatchEngine:
             # transfer
             daut, ddev, _ = snap[6]
             dpend = (
-                self._flat_dispatch(daut, ddev, words)
+                self._flat_dispatch(daut, ddev, words, tm)
                 if daut is not None
                 else None
             )
-            pend_base = self._flat_submit(snap, words)
+            pend_base = self._flat_submit(snap, words, tm)
         except Exception:
             # a dispatch-side device fault (encode upload, compile,
             # injected engine.device_step error): count it toward the
@@ -2147,24 +2162,44 @@ class MatchEngine:
             for ws in words:
                 with self._mlock:
                     out.append(self.match_host(ws))
-            return ("host-fallback", out)
+            return ("host-fallback", out, tm.timings())
         if len(words) >= 64:
             # keep a fresh sample for the breaker probe: after a trip
             # the device path stops running, and probing with recent
             # REAL topics measures what production windows would see
             self._probe_topics = list(topics[:256])
         cpu0 = time.thread_time() - c0  # encode + dispatch CPU
-        return ("dev", snap, pend_base, dpend, topics, words, t0, cpu0)
+        return (
+            "dev", snap, pend_base, dpend, topics, words, t0, cpu0,
+            tm.timings(),
+        )
 
-    def _flat_submit(self, snap: Tuple, words: Sequence[T.Words]):
+    def _laps(self, seq: int = 0):
+        """A lap clock for one submit or finish while the profiler is
+        on, else the no-op: the engine times its own sections and hands
+        them back in the pending handle and in ``info``; the caller
+        lays them on its window's record."""
+        prof = self.profiler
+        return Laps(seq) if prof is not None and prof.enabled else NO_LAPS
+
+    @staticmethod
+    def submit_timings(pending) -> Sequence[Tuple[str, float, float]]:
+        """``(name, start, dur)`` of the sections `match_batch_submit`
+        timed (``tokenize``, ``encode``, ``kernel_dispatch``): the last
+        element of every pending handle, a subclass's too; ``start``
+        is on the perf_counter clock."""
+        return pending[-1]
+
+    def _flat_submit(self, snap: Tuple, words: Sequence[T.Words],
+                     tm=NO_LAPS):
         """Overridable async-dispatch hook for the base snapshot:
         subclasses whose flat path is synchronous (the sharded mesh
         engine's shard_map call) override this to compute eagerly."""
-        return ("pend", self._flat_dispatch(snap[0], snap[1], words))
+        return ("pend", self._flat_dispatch(snap[0], snap[1], words, tm))
 
-    def _flat_result(self, token):
+    def _flat_result(self, token, tm=NO_LAPS):
         kind, v = token
-        return self._flat_finish(v) if kind == "pend" else v
+        return self._flat_finish(v, tm) if kind == "pend" else v
 
     def match_batch_finish(self, pending, info=None) -> List[Set[Hashable]]:
         """Phase 2: wait for the device results (if any), overlay the
@@ -2177,20 +2212,26 @@ class MatchEngine:
         ACTUALLY served the window — ``dev``, ``host``, or
         ``host-fallback`` when a device fault degraded it here — so
         the profiler's flight record never labels a fallback window
-        as a device window."""
+        as a device window.  While the profiler is on it also receives
+        ``timings``: ``(name, start, dur)`` of the sections timed here
+        (``device_wait``, ``expand_codes``, ``dense_rematch`` once a
+        compact clip, ``overlay_lock_wait``, ``overlay``); the caller's
+        ``seq`` in it tags their trace annotations."""
         if pending[0] != "dev":
             if info is not None:
                 info["path"] = pending[0]
             return pending[1]
+        tm = NO_LAPS
         if info is not None:
             info["path"] = "dev"
-        _, snap, pend_base, dpend, topics, words, t0, cpu0 = pending
+            tm = self._laps(info.get("seq", 0))
+        _, snap, pend_base, dpend, topics, words, t0, cpu0, _ = pending
         t1w = time.perf_counter()
         c1 = time.thread_time()
         try:
-            rows, gpos, ovf = self._flat_result(pend_base)
+            rows, gpos, ovf = self._flat_result(pend_base, tm)
             dflat = (
-                self._flat_finish(dpend) if dpend is not None else None
+                self._flat_finish(dpend, tm) if dpend is not None else None
             )
         except Exception:
             # the wait/transfer side of the device step failed: breaker
@@ -2202,13 +2243,19 @@ class MatchEngine:
                 len(words),
             )
             self._device_failure()
+            tm.unmark()
             if info is not None:
                 info["path"] = "host-fallback"
+                info["timings"] = tm.timings()
             return self.match_batch_host(list(topics))
         self._device_ok(time.perf_counter() - t0)
         tp("match_overlay")
         with self._mlock:
+            tm.lap("overlay_lock_wait", then="overlay")
             out = self._overlay(topics, words, rows, gpos, ovf, snap, dflat)
+            tm.lap("overlay")
+        if info is not None:
+            info["timings"] = tm.timings()
         if self.use_device is None and len(words) >= 64:
             cpu_us = (
                 (cpu0 + time.thread_time() - c1) / len(words) * 1e6
@@ -2389,10 +2436,13 @@ class MatchEngine:
             idx = np.fromiter(js, np.int64, count=b)
             return idx, mat, lens, dol
 
-    def _flat_dispatch(self, aut, tables, words: Sequence[T.Words]):
+    def _flat_dispatch(self, aut, tables, words: Sequence[T.Words],
+                       tm=NO_LAPS):
         """Encode + launch the kernel; returns a pending handle without
         blocking (JAX async dispatch), so several automata (base +
         segments) overlap on the device and the host<->device link.
+        ``tm`` takes the ``encode`` lap (everything since the previous
+        one: path choice and snapshot too) and ``kernel_dispatch``.
 
         The batch is DEDUPLICATED first: publish windows are Zipf-heavy
         (hot topics repeat ~2x at bench scale), and matching each
@@ -2419,6 +2469,7 @@ class MatchEngine:
         # round-trip (+ possible compile) per window.  The multiplier
         # is sticky power-of-two (bounded shape-class ladder).
         c_cap = self._ccap_mult * tokens.shape[0]
+        tm.lap("encode")
         flat, counts, total = match_batch_compact(
             *tables,
             tokens,
@@ -2434,15 +2485,17 @@ class MatchEngine:
         flat.copy_to_host_async()
         counts.copy_to_host_async()
         total.copy_to_host_async()
+        tm.lap("kernel_dispatch")
         return (
             aut, tables, flat, counts, total, (tokens, lengths, dollar),
             len(uniq), inv,
         )
 
-    def _flat_finish(self, pending):
+    def _flat_finish(self, pending, tm=NO_LAPS):
         from .ops.automaton import expand_codes_dedup, expand_codes_flat
 
         (aut, tables, flat, counts, total, enc, n_uniq, inv) = pending
+        tm.mark("device_wait")
         if int(np.asarray(total)[0]) > len(flat):
             # the compact buffer clipped: re-match this window on the
             # dense kernel — correct for any fill, just more bytes on
@@ -2454,6 +2507,7 @@ class MatchEngine:
             self._ccap_mult = min(self._ccap_mult * 2, 64)
             from .ops.match_kernel import match_batch
 
+            tm.lap("device_wait", then="dense_rematch")
             codes, _, ovf = match_batch(
                 *tables, *enc, f_width=self.f_width, m_cap=self.m_cap
             )
@@ -2461,12 +2515,19 @@ class MatchEngine:
                 aut.code_off, aut.code_idx,
                 np.asarray(codes)[:n_uniq], inv,
             )
-            return rows, pos, np.asarray(ovf)[:n_uniq][inv]
+            ovf = np.asarray(ovf)[:n_uniq][inv]
+            tm.lap("dense_rematch")
+            return rows, pos, ovf
+        # block on both results before the host work on either, so the
+        # wait and the work are two spans
         counts = np.asarray(counts).astype(np.int64)
+        flat = np.asarray(flat)
+        tm.lap("device_wait", then="expand_codes")
         ovf_u = counts < 0
         counts_pos = np.where(ovf_u, -counts - 1, counts)
         rows, pos = expand_codes_flat(
-            aut.code_off, aut.code_idx, np.asarray(flat),
-            counts_pos, inv,
+            aut.code_off, aut.code_idx, flat, counts_pos, inv,
         )
-        return rows, pos, ovf_u[:n_uniq][inv]
+        ovf = ovf_u[:n_uniq][inv]
+        tm.lap("expand_codes")
+        return rows, pos, ovf

@@ -22,7 +22,11 @@ pipeline a stall lives in.  Three pieces close that gap:
     cork flush, end-to-end publish→delivery) collected by the broker
     with two ``perf_counter`` calls per stage, plus engine lifecycle
     events (XLA shape compiles, ``device_put`` transfer bytes, delta
-    folds) recorded from the builder threads.
+    folds) recorded from the builder threads.  Inside a span, named
+    sub-spans with a start (the queue, device and host parts of the two
+    match laps, the device round trips of ``decide`` and ``rules``) say
+    what the lap was made of; ``LoopClock`` counts what the event loop
+    does between the windows' stages: socket reads and writes.
 
 Flight recorder
     A fixed ring of the last N ``WindowRecord``s, always on and
@@ -181,51 +185,166 @@ class Histogram:
             self._count = 0
 
 
-class WindowRecord:
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, on first use
+
+
+def annotation(name: str, seq: int):
+    """A ``jax.profiler.TraceAnnotation`` named ``emqx/<name>`` that
+    carries the window's ``seq``: a host span on the device trace's own
+    clock.  None while no `jax.profiler` trace runs (one static call
+    says so), so an untraced window makes no annotation object.  For
+    synchronous sections only (one thread, no ``await`` inside)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    if not _TraceAnnotation.is_enabled():
+        return None
+    return _TraceAnnotation("emqx/" + name, seq=seq)
+
+
+class Laps:
+    """Contiguous named laps on ``perf_counter``: ``lap`` closes the
+    span running since the previous lap under a name, and where the
+    section is synchronous ``mark`` (or ``lap(..., then=)``) opens its
+    trace annotation first.  The engine times its own sections with
+    one and hands the spans back; a window's record is one."""
+
+    __slots__ = ("seq", "t0", "_t_last", "spans", "_ann")
+
+    def __init__(self, seq: int) -> None:
+        self.seq = seq
+        self.t0 = self._t_last = time.perf_counter()
+        self.spans: List[Tuple[str, float, float]] = []  # (name, off, dur)
+        self._ann = None  # the open trace annotation, if any
+
+    def mark(self, name: str) -> None:
+        """Open the trace annotation of the synchronous section that
+        the next ``lap`` (on this thread) closes."""
+        ann = annotation(name, self.seq)
+        if ann is not None:
+            self._ann = ann
+            ann.__enter__()
+
+    def lap(self, name: str, then: Optional[str] = None) -> None:
+        """Close the span running since the previous lap (or since
+        construction) under ``name`` — two perf_counter reads per
+        stage, nothing else on the hot path.  ``then`` marks the
+        section that starts here."""
+        now = time.perf_counter()
+        self.spans.append((name, self._t_last - self.t0, now - self._t_last))
+        self._t_last = now
+        self.unmark()
+        if then is not None:
+            self.mark(then)
+
+    def unmark(self) -> None:
+        """Close the open annotation, if any (a section that raised
+        before its lap)."""
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+
+    def timings(self) -> List[Tuple[str, float, float]]:
+        """The spans as ``(name, start, dur)`` with ``start`` on the
+        perf_counter clock, for `WindowRecord.sub`."""
+        return [(name, self.t0 + off, dur) for name, off, dur in self.spans]
+
+
+class _NoLaps:
+    """`Laps` with the profiler off: reads no clock, keeps nothing."""
+
+    __slots__ = ()
+
+    def mark(self, name: str) -> None:
+        pass
+
+    def lap(self, name: str, then: Optional[str] = None) -> None:
+        pass
+
+    def unmark(self) -> None:
+        pass
+
+    def timings(self) -> Tuple:
+        return ()
+
+
+NO_LAPS = _NoLaps()
+
+
+class WindowRecord(Laps):
     """One dispatch window's flight-record entry: stage spans plus
     sizes, the match path taken and the breaker state.  Mutated by
     exactly one window's happens-before chain (collector → executor →
     dispatch loop), so it needs no lock of its own."""
 
     __slots__ = (
-        "seq", "wall0", "t0", "_t_last", "n_msgs", "n_deliveries",
-        "n_clients", "path", "breaker_open", "source", "spans",
-        "subs", "e2e_ms",
+        "wall0", "n_msgs", "n_deliveries", "n_clients", "n_clips",
+        "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
+        "loop_cpu",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
-        now = time.perf_counter()
-        self.seq = seq
+        super().__init__(seq)
         self.wall0 = time.time()
-        self.t0 = now
-        self._t_last = now
         self.n_msgs = n_msgs
         self.n_deliveries = 0
         self.n_clients = 0
+        self.n_clips = 0  # compact clips re-matched on the dense kernel
         self.path = ""  # "host" | "dev" | "host-fallback"
         self.breaker_open = False
         self.source = source  # "publish" | "batcher" | "forwarded"
-        self.spans: List[Tuple[str, float, float]] = []  # (name, off, dur)
-        # nested sub-stages: (name, dur) accumulated inside a parent
-        # span (e.g. the native ``assemble`` share of ``deliver``) —
-        # histogrammed like spans but kept out of the trace's B/E
-        # track, whose spans must stay contiguous
-        self.subs: List[Tuple[str, float]] = []
+        # nested sub-stages inside a parent span: (name, off, dur),
+        # histogrammed like spans and exported nested inside the parent
+        # (whose own B/E chain stays contiguous).  ``off`` is None for
+        # a caller-accumulated total with no one start (the native
+        # ``assemble`` share of ``deliver``); a name may repeat (base
+        # and delta automaton) and counts as the sum
+        self.subs: List[Tuple[str, Optional[float], float]] = []
         self.e2e_ms: List[float] = []
+        # LoopClock growth since the previously committed window, and
+        # the loop thread's CPU seconds since the previous window began
+        self.loop: Optional[Tuple] = None
+        self.loop_cpu = 0.0
 
-    def lap(self, name: str) -> None:
-        """Close the span running since the previous lap (or since
-        construction) under ``name`` — two perf_counter reads per
-        stage, nothing else on the hot path."""
-        now = time.perf_counter()
-        self.spans.append((name, self._t_last - self.t0, now - self._t_last))
-        self._t_last = now
+    def sub(self, name: str, dur_s: float,
+            start: Optional[float] = None) -> None:
+        """Record a nested sub-stage: ``start`` is its perf_counter
+        start, or None for a caller-accumulated total."""
+        self.subs.append(
+            (name, None if start is None else start - self.t0, dur_s)
+        )
 
-    def sub(self, name: str, dur_s: float) -> None:
-        """Record a nested sub-stage total (caller-accumulated)."""
-        self.subs.append((name, dur_s))
+    def lap_parts(self, name: str, wait: str, entered: float,
+                  timings: Sequence[Tuple[str, float, float]]) -> None:
+        """Close the span ``name`` whose work another thread entered
+        at ``entered`` and timed as ``timings`` (``(name, start, dur)``
+        on the perf_counter clock, as `Laps.timings` gives them): the
+        time before the first of them is the sub-stage ``wait``, the
+        hop from the previous lap's thread to that work."""
+        waited_from = self._t_last
+        self.lap(name)
+        if timings:
+            entered = timings[0][1]
+        self.sub(wait, entered - waited_from, waited_from)
+        for part, start, dur in timings:
+            self.sub(part, dur, start)
+
+    def sub_totals(self) -> Dict[str, float]:
+        """Seconds by sub-stage name (a repeated name summed)."""
+        out: Dict[str, float] = {}
+        for name, _off, dur in self.subs:
+            out[name] = out.get(name, 0.0) + dur
+        return out
 
     def to_dict(self) -> Dict[str, object]:
+        loop = {}
+        if self.loop is not None:
+            for field, v in zip(LoopClock.FIELDS, self.loop):
+                if field.endswith("_s"):
+                    field, v = field[:-2] + "_us", round(v * 1e6, 1)
+                loop["loop_" + field] = v
+            loop["loop_cpu_us"] = round(self.loop_cpu * 1e6, 1)
         return {
             "seq": self.seq,
             "at": self.wall0,
@@ -233,6 +352,7 @@ class WindowRecord:
             "n_msgs": self.n_msgs,
             "n_deliveries": self.n_deliveries,
             "n_clients": self.n_clients,
+            "n_clips": self.n_clips,
             "path": self.path,
             "breaker_open": self.breaker_open,
             "stages_us": {
@@ -242,11 +362,135 @@ class WindowRecord:
                 },
                 **{
                     name: round(dur * 1e6, 1)
-                    for name, dur in self.subs
+                    for name, dur in self.sub_totals().items()
                 },
             },
             "e2e_ms": [round(v, 3) for v in self.e2e_ms[:8]],
+            **loop,
         }
+
+
+class LoopClock:
+    """What the event loop does between the windows' stages: every
+    socket read (parse + channel, `Connection.run`) and every socket
+    write (`Connection._send_packets`) adds its interval and counts
+    here, two ``perf_counter`` reads each and none a packet.  The
+    totals only grow; `take` hands the growth since the previous take
+    to the window being committed, and `stamp_cpu` the loop thread's
+    CPU since the previous window began to the one beginning.  For
+    the trace, intervals less than ``BURST_GAP_S`` apart merge into
+    one burst.  Loop thread only, so no lock."""
+
+    FIELDS = (
+        "ingress_s", "ingress_reads", "ingress_packets",
+        "ingress_publishes", "ingress_acks", "ingress_bytes",
+        "egress_s", "egress_writes", "egress_packets", "egress_bytes",
+        "egress_in_window_s", "egress_in_window_writes",
+    )
+    BURST_GAP_S = 200e-6
+    BURSTS_CAP = 65536
+
+    def __init__(self) -> None:
+        for field in self.FIELDS:
+            setattr(self, field, 0)
+        self._base = (0,) * len(self.FIELDS)
+        self.tid: Optional[int] = None  # the loop thread, once it read
+        self.cpu_s = 0.0  # that thread's CPU clock, as last read
+        # a write inside a window's deliver / flush laps is inside
+        # those laps too: the broker raises this around them
+        self.in_window = False
+        self._bursts: deque = deque(maxlen=self.BURSTS_CAP)
+        self._open: Dict[str, List[float]] = {}
+
+    def ingress(self, t0: float, n_bytes: int, packets: int,
+                publishes: int, acks: int) -> None:
+        """One socket read's parse + channel work, begun at ``t0``."""
+        now = time.perf_counter()
+        if self.tid is None:
+            self.tid = threading.get_ident()
+        self.ingress_s += now - t0
+        self.ingress_reads += 1
+        self.ingress_packets += packets
+        self.ingress_publishes += publishes
+        self.ingress_acks += acks
+        self.ingress_bytes += n_bytes
+        self._burst("loop_ingress", t0, now)
+
+    def egress(self, t0: float, n_bytes: int, packets: int) -> None:
+        """One socket write (serialize + write), begun at ``t0``."""
+        now = time.perf_counter()
+        self.egress_s += now - t0
+        self.egress_writes += 1
+        self.egress_packets += packets
+        self.egress_bytes += n_bytes
+        if self.in_window:
+            self.egress_in_window_s += now - t0
+            self.egress_in_window_writes += 1
+        self._burst("loop_egress", t0, now)
+
+    def _burst(self, name: str, t0: float, t1: float) -> None:
+        cur = self._open.get(name)
+        if cur is not None and t0 - cur[1] < self.BURST_GAP_S:
+            cur[1] = t1
+            return
+        if cur is not None:
+            self._bursts.append((name, cur[0], cur[1]))
+        self._open[name] = [t0, t1]
+
+    def stamp_cpu(self) -> float:
+        """Read the loop thread's CPU clock, once a window as it
+        begins: the seconds it grew since the previous reading (0.0
+        off the loop thread, and at the first reading)."""
+        if threading.get_ident() != self.tid:
+            return 0.0
+        cpu = time.thread_time()
+        grown = cpu - self.cpu_s if self.cpu_s else 0.0
+        self.cpu_s = cpu
+        return grown
+
+    def take(self) -> Tuple:
+        """The totals' growth since the previous take."""
+        now = tuple(getattr(self, f) for f in self.FIELDS)
+        grown = tuple(a - b for a, b in zip(now, self._base))
+        self._base = now
+        return grown
+
+    def bursts(self) -> List[Tuple[str, float, float]]:
+        """``(name, start, end)`` on the perf_counter clock, the two
+        bursts still open included."""
+        return list(self._bursts) + [
+            (name, cur[0], cur[1]) for name, cur in self._open.items()
+        ]
+
+    def reset(self) -> None:
+        # (the CPU spent since the last window began is not the next
+        # window's: read the clock afresh)
+        self.stamp_cpu()
+        self.take()
+        self._bursts.clear()
+        self._open.clear()
+
+
+def _nest(subs, b_ts: float, e_ts: float) -> List[Tuple[str, str, float]]:
+    """B/E events ``(ph, name, ts)`` for one span's sub-stages
+    ``(start, dur, name)`` sorted by start: each clamped into what
+    encloses it (the span ``[b_ts, e_ts]``, or an earlier sub-stage
+    still open), timestamps never decreasing, ends in LIFO order."""
+    out: List[Tuple[str, str, float]] = []
+    stack: List[Tuple[str, float]] = []  # (name, end) still open
+    cursor = b_ts
+    for start, dur, name in list(subs) + [(e_ts, 0.0, None)]:
+        while stack and stack[-1][1] <= start:
+            done, end = stack.pop()
+            cursor = max(cursor, end)
+            out.append(("E", done, cursor))
+        if name is None:
+            break
+        limit = stack[-1][1] if stack else e_ts
+        cursor = min(max(start, cursor), limit)
+        out.append(("B", name, cursor))
+        stack.append((name, min(cursor + dur, limit)))
+    return out
 
 
 class Profiler:
@@ -286,6 +530,12 @@ class Profiler:
         self._seq = 0
         # engine lifecycle events: (kind, wall_ts, dur_s, meta)
         self._events: deque = deque(maxlen=max(events_cap, 1))
+        # the event loop's socket reads and writes; None when disabled
+        # (every call site guards, as for ``begin``)
+        self.loop: Optional[LoopClock] = LoopClock() if enabled else None
+        # one pair of readings puts the perf_counter stamps of the
+        # loop's bursts on the wall clock of the windows' ``wall0``
+        self._wall_at = (time.time(), time.perf_counter())
         # optional flightrec.FlightRecorder: every committed window is
         # mirrored into its numeric ring (one attribute load + one O(1)
         # append — the black box sees dispatch cadence without a
@@ -301,19 +551,23 @@ class Profiler:
         with self._ring_lock:
             self._seq += 1
             seq = self._seq
-        return WindowRecord(seq, n_msgs, source)
+        rec = WindowRecord(seq, n_msgs, source)
+        rec.loop_cpu = self.loop.stamp_cpu()
+        return rec
 
     def commit(self, rec: WindowRecord) -> None:
         """Fold a finished window into the histograms (ONE lock for
         every stage sample + the e2e batch) and the ring."""
+        rec.unmark()
+        lc = self.loop
+        if lc is not None:
+            rec.loop = lc.take()
+            lc.in_window = False
         hist = self._hist
         with self._hlock:
-            for name, _off, dur in rec.spans:
-                h = hist.get(name)
-                if h is None:
-                    h = hist[name] = Histogram(lock=self._hlock)
-                h._record_locked(dur * 1e6)
-            for name, dur in rec.subs:
+            samples = [(name, dur) for name, _off, dur in rec.spans]
+            samples += rec.sub_totals().items()
+            for name, dur in samples:
                 h = hist.get(name)
                 if h is None:
                     h = hist[name] = Histogram(lock=self._hlock)
@@ -331,9 +585,8 @@ class Profiler:
     # -------------------------------------------------- stages/events
 
     def stage(self, name: str, dur_s: float) -> None:
-        """One standalone stage sample (engine-internal stages like
-        tokenize that cannot ride a WindowRecord across the engine
-        API boundary)."""
+        """One standalone stage sample, of no window (``ds_sync``,
+        the engine's lifecycle events)."""
         if not self.enabled:
             return
         with self._hlock:
@@ -400,27 +653,48 @@ class Profiler:
         with self._ring_lock:
             self._ring = [None] * len(self._ring)
         self._events.clear()
+        if self.loop is not None:
+            self.loop.reset()
 
     # -------------------------------------------------- chrome trace
+
+    # the loop bursts' track: window tracks take their seq, the engine's
+    # lifecycle events tid 0
+    LOOP_TID = (1 << 31) - 1
 
     def chrome_trace(self, limit: Optional[int] = None) -> Dict[str, object]:
         """The flight recorder as Chrome trace-event JSON (the format
         Perfetto and chrome://tracing load natively): every window is
         its own thread track with paired B/E events per stage (windows
         pipeline, so tracks may overlap in time — per-track events
-        stay strictly nested), engine lifecycle events ride tid 0 as
-        complete ("X") events."""
+        stay strictly nested) and each sub-stage that has a start
+        nested inside its parent; engine lifecycle events ride tid 0
+        and the event loop's read / write bursts a track of their own,
+        both as complete ("X") events."""
         recs = self._recent(limit if limit is not None else len(self._ring))
         recs.reverse()  # oldest first: ts ordering within each track
-        engine_events = list(self._events)
+        wall_at, perf_at = self._wall_at
+        others = [
+            (kind, ts - dur, ts, 0, dict(meta))
+            for kind, ts, dur, meta in list(self._events)
+        ]
+        if self.loop is not None:
+            others += [
+                (name, wall_at + (t0 - perf_at), wall_at + (t1 - perf_at),
+                 self.LOOP_TID, {})
+                for name, t0, t1 in self.loop.bursts()
+            ]
         # export timestamps RELATIVE to the trace's own epoch: at
         # absolute epoch-µs magnitude (1.7e15) a float64 has ~0.25 µs
         # of quantization, enough to flip adjacent span edges out of
-        # order; small relative values keep full sub-µs precision
-        starts = [r.wall0 for r in recs] + [
-            ts - dur for _k, ts, dur, _m in engine_events
-        ]
-        epoch = min(starts) if starts else 0.0
+        # order; small relative values keep full sub-µs precision.
+        # The epoch is the oldest exported window's start, so a reader
+        # puts the export back on the wall clock from the ring alone:
+        # what began earlier is clipped to it and never moves it
+        if recs:
+            epoch = recs[0].wall0
+        else:
+            epoch = min((o[1] for o in others), default=0.0)
         pid = self.pid
         events: List[Dict[str, object]] = [
             {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
@@ -430,6 +704,9 @@ class Profiler:
              )}},
             {"name": "process_sort_index", "ph": "M", "pid": pid,
              "tid": 0, "args": {"sort_index": pid}},
+            {"name": "thread_name", "ph": "M", "pid": pid,
+             "tid": self.LOOP_TID,
+             "args": {"name": "event loop: socket reads and writes"}},
         ]
         for rec in recs:
             tid = rec.seq
@@ -438,13 +715,18 @@ class Profiler:
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
                 "args": {"name": f"window {rec.seq} ({rec.source})"},
             })
+            subs = sorted(
+                ((base_us + off * 1e6, max(dur, 0.0) * 1e6, name)
+                 for name, off, dur in rec.subs if off is not None),
+                key=lambda s: (s[0], -s[1]),
+            )
+            k = 0
             cursor = base_us  # monotonic clamp: contiguous span
             # offsets are measured independently, so edge timestamps
             # can disagree by an ulp — never let E(k) > B(k+1)
             for name, off, dur in rec.spans:
                 b_ts = max(base_us + off * 1e6, cursor)
                 e_ts = b_ts + max(dur, 0.0) * 1e6
-                cursor = e_ts
                 args = {
                     "n_msgs": rec.n_msgs,
                     "path": rec.path,
@@ -454,15 +736,29 @@ class Profiler:
                     "name": name, "ph": "B", "pid": pid, "tid": tid,
                     "ts": b_ts, "args": args,
                 })
+                # the sub-stages that start inside this span
+                k1 = k
+                while k1 < len(subs) and subs[k1][0] < e_ts:
+                    k1 += 1
+                for ph, sub_name, ts in _nest(subs[k:k1], b_ts, e_ts):
+                    events.append({
+                        "name": sub_name, "ph": ph, "pid": pid,
+                        "tid": tid, "ts": ts,
+                    })
+                k = k1
+                cursor = e_ts
                 events.append({
                     "name": name, "ph": "E", "pid": pid, "tid": tid,
                     "ts": e_ts,
                 })
-        for kind, ts, dur, meta in engine_events:
+        for name, start, end, tid, args in others:
+            if end < epoch:
+                continue
+            start = max(start, epoch)
             events.append({
-                "name": kind, "ph": "X", "pid": pid, "tid": 0,
-                "ts": (ts - dur - epoch) * 1e6, "dur": dur * 1e6,
-                "args": dict(meta),
+                "name": name, "ph": "X", "pid": pid, "tid": tid,
+                "ts": (start - epoch) * 1e6, "dur": (end - start) * 1e6,
+                "args": args,
             })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 

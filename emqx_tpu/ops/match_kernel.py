@@ -127,9 +127,10 @@ def _match_core(
         return (nf, nrows), (nf, h_hit, over)
 
     xs = (tokens.T, jnp.arange(levels, dtype=jnp.int32))
-    (frontier, frows), (nf_seq, h_seq, over_seq) = lax.scan(
-        step, (frontier, frows), xs
-    )
+    with jax.named_scope("level_scan"):
+        (frontier, frows), (nf_seq, h_seq, over_seq) = lax.scan(
+            step, (frontier, frows), xs
+        )
 
     # assemble (value, hit) pairs: root '#', per-level '#' hits, final
     # exact hits — then compact into the code buffer with one scatter
@@ -175,14 +176,16 @@ def match_batch(
     vals, hits, over_seq = _match_core(
         fp_rows, node_rows, salt, tokens, lengths, dollar, f_width
     )
-    prefix = jnp.cumsum(hits.astype(jnp.int32), axis=1)
-    count = prefix[:, -1]
-    pos = jnp.where(hits & (prefix <= m_cap), prefix - 1, m_cap)
-    rows = jnp.broadcast_to(
-        jnp.arange(b, dtype=jnp.int32)[:, None], pos.shape
-    )
-    buf = jnp.full((b, m_cap), -1, jnp.int32)
-    buf = buf.at[rows, pos].set(vals, mode="drop")
+    with jax.named_scope("hit_prefix_sum"):
+        prefix = jnp.cumsum(hits.astype(jnp.int32), axis=1)
+        count = prefix[:, -1]
+    with jax.named_scope("compact"):
+        pos = jnp.where(hits & (prefix <= m_cap), prefix - 1, m_cap)
+        rows = jnp.broadcast_to(
+            jnp.arange(b, dtype=jnp.int32)[:, None], pos.shape
+        )
+        buf = jnp.full((b, m_cap), -1, jnp.int32)
+        buf = buf.at[rows, pos].set(vals, mode="drop")
     ovf = jnp.any(over_seq, axis=0) | (count > m_cap)
     return buf, jnp.minimum(count, m_cap), ovf
 
@@ -220,14 +223,16 @@ def match_batch_compact(
     vals, hits, over_seq = _match_core(
         fp_rows, node_rows, salt, tokens, lengths, dollar, f_width
     )
-    prefix = jnp.cumsum(hits.astype(jnp.int32), axis=1)
-    count = prefix[:, -1]
-    count_c = jnp.minimum(count, m_cap)
-    row_start = jnp.cumsum(count_c) - count_c  # exclusive
-    valid = hits & (prefix <= m_cap)
-    tgt = jnp.where(valid, row_start[:, None] + (prefix - 1), c_cap)
-    flat = jnp.full((c_cap,), -1, jnp.int32)
-    flat = flat.at[tgt.reshape(-1)].set(vals.reshape(-1), mode="drop")
+    with jax.named_scope("hit_prefix_sum"):
+        prefix = jnp.cumsum(hits.astype(jnp.int32), axis=1)
+        count = prefix[:, -1]
+        count_c = jnp.minimum(count, m_cap)
+        row_start = jnp.cumsum(count_c) - count_c  # exclusive
+    with jax.named_scope("compact"):
+        valid = hits & (prefix <= m_cap)
+        tgt = jnp.where(valid, row_start[:, None] + (prefix - 1), c_cap)
+        flat = jnp.full((c_cap,), -1, jnp.int32)
+        flat = flat.at[tgt.reshape(-1)].set(vals.reshape(-1), mode="drop")
     ovf = jnp.any(over_seq, axis=0) | (count > m_cap)
     counts_out = jnp.where(ovf, -count_c - 1, count_c).astype(jnp.int16)
     total = (row_start[-1] + count_c[-1]).astype(jnp.int32)[None]
@@ -267,6 +272,7 @@ DEC_SUBID_BIT = 1 << 6
 
 
 @jax.jit
+@jax.named_scope("decide_columns")
 def decide_batch(
     oa_qos,       # [R] int8   per-opts-row subscription QoS
     oa_nl,        # [R] bool   no_local
@@ -499,6 +505,7 @@ def rules_eval_host(
 
 
 @jax.jit
+@jax.named_scope("predicate_planes")
 def rules_eval_batch(
     code, a0, a1, a2, a3, litn, lit_ranks, last,
     num, sid, err, prs,

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine import MatchEngine
+from ..observability import NO_LAPS
 from ..ops.automaton import Automaton, build_automaton
 from ..ops.dictionary import SENTINEL, TokenDict, encode_topics
 from ..ops.match_kernel import match_batch
@@ -363,11 +364,13 @@ class ShardedMatchEngine(MatchEngine):
             snap = self._snapshot_refs()
         return self._flat_from_snapshot(snap, words)
 
-    def _flat_submit(self, snap, words: Sequence[T.Words]):
+    def _flat_submit(self, snap, words: Sequence[T.Words], tm=NO_LAPS):
         # the shard_map call is synchronous end-to-end (collectives
         # inside); compute eagerly and hand the finished triple back
         # through the submit/finish protocol
-        return ("done", self._flat_from_snapshot(snap, words))
+        out = ("done", self._flat_from_snapshot(snap, words))
+        tm.lap("kernel_dispatch")
+        return out
 
     def _flat_from_snapshot(self, snap, words: Sequence[T.Words]):
         from ..ops.automaton import expand_codes_host

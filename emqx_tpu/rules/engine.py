@@ -340,9 +340,11 @@ class RuleEngine:
         envs.  Matched/passed/failed counters update once per rule
         and broker metrics flush in one `inc_bulk` pass.
 
-        ``rec`` (the window's profiler record) takes ``rules_extract``
-        / ``rules_eval`` sub-stages so the bench can attribute column
-        extraction vs matrix evaluation inside the ``rules`` lap."""
+        ``rec`` (the window's profiler record) takes the sub-stages
+        of the ``rules`` lap: ``rules_extract`` (column extraction),
+        ``rules_eval`` (the matrix, with the device round trip that
+        blocks the loop as ``rules_device_wait`` inside it) and
+        ``rules_actions`` (the actions' loop)."""
         if not items:
             return 0
         msgs = [m for m, _ in items]
@@ -473,11 +475,18 @@ class RuleEngine:
                     # speed for a pathological payload)
                     pass
                 elif active.size and self.broker is not None:
+                    ev_info: Optional[Dict] = (
+                        {} if rec is not None else None
+                    )
                     matrix, _path = (
                         self.broker.router.engine.rules_eval_window(
-                            stack, self.rules_rev, cols, rows=active
+                            stack, self.rules_rev, cols, rows=active,
+                            info=ev_info,
                         )
                     )
+                    if ev_info:
+                        start, dur = ev_info["device_wait"]
+                        rec.sub("rules_device_wait", dur, start)
                 elif active.size:  # standalone: the host twin directly
                     from ..ops.match_kernel import rules_eval_host
 
@@ -496,8 +505,8 @@ class RuleEngine:
                     self._stats["matrix_windows"] += 1
                     if rec is not None:
                         t2 = time.perf_counter()
-                        rec.sub("rules_extract", t1 - t0)
-                        rec.sub("rules_eval", t2 - t1)
+                        rec.sub("rules_extract", t1 - t0, t0)
+                        rec.sub("rules_eval", t2 - t1, t1)
         if matrix is None:
             self._stats["scalar_windows"] += 1
             known = np.zeros(len(ppos), bool)
@@ -576,6 +585,8 @@ class RuleEngine:
                 k = k2
             t_act1 = time.perf_counter()
             self._sel_lane_account(rows_b, rows_s, t_act1 - t_act0)
+            if rec is not None:
+                rec.sub("rules_actions", t_act1 - t_act0, t_act0)
         if hits:
             mloc["rules.matched"] += hits
         if self.broker is not None and mloc:

@@ -413,7 +413,7 @@ class LifecycleTracer:
                     "ts_ns": int(w_end * 1e9),
                     "attrs": {"dur_us": round(dur * 1e6, 1)},
                 }
-                for name, dur in rec.subs
+                for name, _off, dur in rec.subs
             ]
             path = rec.path
             breaker = rec.breaker_open

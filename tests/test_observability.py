@@ -698,3 +698,289 @@ def test_profiler_overhead_window_shape():
     # one transport write per subscriber per window (corked flush
     # unchanged by instrumentation)
     assert len(sink) >= 64
+
+
+# ------------------------- sub-spans, the loop's clock, annotations
+
+MATCH_WAIT_SUBS = ("finish_queue_wait", "device_wait", "expand_codes",
+                   "overlay_lock_wait", "overlay", "dense_rematch")
+MATCH_SUBMIT_SUBS = ("submit_queue_wait", "tokenize", "encode",
+                     "kernel_dispatch")
+
+
+async def _served_windows(enable=True, rounds=6, trace_dir=None,
+                          during=None):
+    """A device-pinned broker behind its real listener: ``rounds``
+    windows of 8 QoS1 publishes from one socket to one QoS1 subscriber
+    socket, a lowered rule firing on each.  Returns the server's
+    profiler after ``stop()`` (the ring survives it)."""
+    from emqx_tpu.rules.engine import FunctionAction
+    from mqtt_client import TestClient
+
+    cfg = BrokerConfig()
+    cfg.listeners = [ListenerConfig(bind="127.0.0.1", port=0)]
+    cfg.engine.use_device = True
+    cfg.profiler.enable = enable
+    srv = BrokerServer(cfg)
+    eng = srv.broker.router.engine
+    fired = []
+    srv.broker.rules.add_rule(
+        "r0", 'SELECT payload.v AS v FROM "f/+/x" WHERE payload.v > 1',
+        [FunctionAction(lambda sel, msg: fired.append(sel))],
+    )
+    eng.insert_many([(f"f/{i}/+", f"tab{i}") for i in range(64)])
+    eng.rebuild()  # a base automaton on the device: windows go `dev`
+    await srv.start()
+    port = srv.listeners[0].port
+    sub = TestClient(port, "sub")
+    await sub.connect()
+    await sub.subscribe("f/+/x", qos=1)
+    pub = TestClient(port, "pub")
+    await pub.connect()
+    loop = asyncio.get_running_loop()
+    if trace_dir is not None:
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        await loop.run_in_executor(None, lambda: jax.profiler.start_trace(
+            trace_dir, profiler_options=opts
+        ))
+    try:
+        for k in range(rounds):
+            for i in range(8):
+                await pub.send(C.Publish(
+                    topic=f"f/{i}/x", payload=b'{"v": 3}', qos=1,
+                    packet_id=k * 8 + i + 1,
+                ))
+            for _ in range(8):
+                await sub.recv_publish(timeout=20)
+            for _ in range(8):
+                await pub.expect(C.PUBACK, timeout=20)
+            if during is not None and k == rounds // 2:
+                during(srv.broker.profiler)
+        await asyncio.sleep(0.2)  # the subscriber's last PUBACKs land
+    finally:
+        if trace_dir is not None:
+            await loop.run_in_executor(None, jax.profiler.stop_trace)
+        await srv.stop()
+    assert len(fired) == rounds * 8
+    return srv.broker.profiler
+
+
+def test_match_laps_are_covered_by_their_sub_spans():
+    """Every `dev` window's queue, device and host parts add up to its
+    two match laps: nothing larger than a tenth of either is unnamed,
+    and no part is counted twice."""
+    prof = run(_served_windows())
+    wins = [w for w in prof.windows(100) if w["source"] == "batcher"]
+    assert len(wins) >= 6 and all(w["path"] == "dev" for w in wins)
+    for w in wins:
+        st = w["stages_us"]
+        for lap, subs in (("match_wait", MATCH_WAIT_SUBS),
+                          ("match_submit", MATCH_SUBMIT_SUBS)):
+            parts = sum(st.get(s, 0.0) for s in subs)
+            assert 0.9 * st[lap] <= parts <= st[lap] + 1.0, (lap, st)
+        # the device round trips that block the loop thread are inside
+        # their laps, and the rules lap's three parts inside it
+        assert 0 < st["decide_device_wait"] < st["decide"]
+        assert 0 < st["rules_device_wait"] < st["rules_eval"]
+        assert 0 < st["rules_actions"]
+        assert (st["rules_extract"] + st["rules_eval"]
+                + st["rules_actions"]) <= st["rules"] + 1.0
+        assert w["n_clips"] == st.get("dense_rematch", 0) == 0
+    # the engine's tokenize is a sub-span of the window now, and still
+    # a histogram of the scrape (one sample a window)
+    assert prof.summary()["tokenize"]["count"] == len(prof.windows(100))
+
+
+def test_chrome_trace_nests_sub_spans_inside_their_parents():
+    """Each sub-span with a start is a B/E pair inside its parent lap
+    on the window's track; the parents still chain contiguously."""
+    prof = run(_served_windows(rounds=3))
+    trace = prof.chrome_trace()
+    parents = set(Profiler.STAGES) - {"tokenize", "assemble"}
+    by_tid = {}
+    for ev in trace["traceEvents"]:
+        if ev["ph"] in ("B", "E"):
+            by_tid.setdefault(ev["tid"], []).append(ev)
+    nested = set()
+    for tid, evs in by_tid.items():
+        stack, last_ts, last_parent_end = [], -1.0, None
+        for ev in evs:
+            assert ev["ts"] >= last_ts, (tid, ev)
+            last_ts = ev["ts"]
+            if ev["ph"] == "B":
+                if not stack:
+                    assert ev["name"] in parents, ev
+                    if last_parent_end is not None:
+                        assert ev["ts"] == last_parent_end, ev
+                else:
+                    assert stack[0] in parents
+                    nested.add((stack[0], ev["name"]))
+                stack.append(ev["name"])
+            else:
+                assert stack.pop() == ev["name"], (tid, ev)
+                if not stack:
+                    last_parent_end = ev["ts"]
+        assert not stack
+    assert {("match_submit", "submit_queue_wait"),
+            ("match_submit", "tokenize"), ("match_submit", "encode"),
+            ("match_submit", "kernel_dispatch"),
+            ("match_wait", "finish_queue_wait"),
+            ("match_wait", "device_wait"), ("match_wait", "expand_codes"),
+            ("match_wait", "overlay_lock_wait"), ("match_wait", "overlay"),
+            ("decide", "decide_device_wait"), ("rules", "rules_eval"),
+            ("rules", "rules_device_wait"),
+            ("rules", "rules_actions")} <= nested
+    # the loop's bursts: X events on their own track, on the windows'
+    # clock, none before the export's epoch (the oldest window's start)
+    bursts = [e for e in trace["traceEvents"]
+              if e["ph"] == "X" and e["tid"] == Profiler.LOOP_TID]
+    assert {e["name"] for e in bursts} == {"loop_ingress", "loop_egress"}
+    span_end = max(e["ts"] for evs in by_tid.values() for e in evs)
+    for e in bursts:
+        assert e["ts"] >= 0 and e["dur"] >= 0
+        assert e["ts"] <= span_end + 1e6
+    oldest = min(w["at"] for w in prof.windows(100))
+    first_b = min(e["ts"] for evs in by_tid.values() for e in evs)
+    assert first_b == 0.0  # the epoch IS the oldest window's `at`
+    assert json.loads(json.dumps(trace)) and oldest > 0
+
+
+def test_chrome_trace_clips_what_began_before_the_oldest_window():
+    prof = Profiler(ring_size=4)
+    lc = prof.loop
+    t0 = time.perf_counter()
+    lc.egress(t0 - 0.5, 10, 1)          # a burst long before any window
+    prof.event("xla_compile", 0.25)     # and an engine event
+    lc.ingress(time.perf_counter(), 10, 1, 1, 0)  # open as the window starts
+    rec = prof.begin(1)
+    rec.lap("prepare")
+    lc.ingress(time.perf_counter() - 150e-6, 10, 1, 1, 0)  # merges: gap < 200 us
+    prof.commit(rec)
+    xs = [e for e in prof.chrome_trace()["traceEvents"] if e["ph"] == "X"]
+    assert [e["name"] for e in xs] == ["loop_ingress"]  # one merged burst
+    assert xs[0]["ts"] == 0.0 and xs[0]["dur"] > 0  # clipped to the epoch
+    b = [e for e in prof.chrome_trace()["traceEvents"] if e["ph"] == "B"]
+    assert b[0]["ts"] == 0.0
+
+
+def test_loop_fields_sum_to_the_clock_and_rebase_on_reset():
+    """The ring's loop_* fields are the accumulator's growth cut at
+    each commit: over the ring they sum to it, and `reset()` (the
+    benchmark calls it as its window opens) re-bases them."""
+    from emqx_tpu.observability import LoopClock
+
+    marks = []
+
+    def during(prof):
+        prof.reset()
+        marks.append({f: getattr(prof.loop, f) for f in LoopClock.FIELDS})
+        marks.append(prof.loop.cpu_s)
+
+    prof = run(_served_windows(rounds=6, during=during))
+    lc = prof.loop
+    # one more commit takes what the loop did after the last window
+    prof.commit(prof.begin(0))
+    wins = prof.windows(100)
+    assert 3 <= len(wins) <= 4  # only the windows after the reset
+    for f in LoopClock.FIELDS:
+        key = "loop_" + (f[:-2] + "_us" if f.endswith("_s") else f)
+        grown = getattr(lc, f) - marks[0][f]
+        if f.endswith("_s"):
+            grown *= 1e6
+        summed = sum(w[key] for w in wins)
+        # (a record rounds its microseconds to a tenth)
+        assert abs(summed - grown) <= 0.1 * len(wins), (f, summed, grown)
+        assert summed > 0, f
+    # the loop thread's CPU: each window carries what the thread spent
+    # since the previous window began, from the reset on
+    cpu = sum(w["loop_cpu_us"] for w in wins)
+    assert abs(cpu - (lc.cpu_s - marks[1]) * 1e6) <= 0.1 * len(wins)
+    assert 0 < cpu <= (wins[0]["at"] - wins[-1]["at"] + 1.0) * 1e6
+    # 8 PUBLISH in and 8 PUBACKs back a round, both through sockets
+    assert sum(w["loop_ingress_publishes"] for w in wins) >= 16
+    assert sum(w["loop_ingress_acks"] for w in wins) >= 8
+    assert lc.ingress_publishes == 48 and lc.ingress_acks == 48
+    assert lc.ingress_packets >= 96 + 3  # + 2 CONNECT, 1 SUBSCRIBE
+    assert lc.egress_packets >= 96 and lc.egress_writes <= lc.egress_packets
+    assert lc.ingress_reads <= lc.ingress_packets
+    assert 0 < lc.egress_in_window_writes <= lc.egress_writes
+    assert 0 < lc.egress_in_window_s <= lc.egress_s
+
+
+def test_profiler_disabled_reads_no_new_clock(monkeypatch):
+    """`profiler.enable = false`: a served window reads none of the
+    clocks this instrumentation added and writes no field."""
+    from emqx_tpu import engine as engine_mod
+    from emqx_tpu import observability as obs
+    from emqx_tpu.broker import connection as conn_mod
+
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read with the profiler off")
+
+    def no_laps(*a, **k):
+        raise AssertionError("a lap clock was made with the profiler off")
+
+    monkeypatch.setattr(conn_mod, "time", NoClock())
+    monkeypatch.setattr(engine_mod, "Laps", no_laps)
+    monkeypatch.setattr(obs, "annotation", no_laps)
+    prof = run(_served_windows(enable=False, rounds=2))
+    assert prof.loop is None and prof.begin(1) is None
+    assert prof.windows() == []
+    assert all(s.count == 0 for s in prof.snapshots().values())
+
+
+def test_jax_trace_holds_the_windows_annotations(tmp_path):
+    """The program writes its synchronous window sections into the
+    profiler's own trace (`emqx/<name>`, with the window's seq), so a
+    device trace carries the host's working spans on its own clock."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    prof = run(_served_windows(rounds=3, trace_dir=str(tmp_path)))
+    seqs = {w["seq"] for w in prof.windows(100)}
+    found, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = {}
+    for plane in ProfileData.from_file(found).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("emqx/"):
+                    seq = {k: v for k, v in ev.stats}.get("seq")
+                    seen.setdefault(ev.name, set()).add(seq)
+    assert {"emqx/match_submit", "emqx/device_wait", "emqx/expand_codes",
+            "emqx/overlay", "emqx/expand", "emqx/decide", "emqx/deliver",
+            "emqx/flush", "emqx/rules"} <= set(seen)
+    for name in ("emqx/overlay", "emqx/deliver"):
+        assert seen[name] and seen[name] <= seqs, (name, seen[name])
+
+
+def test_kernel_phases_carry_named_scopes():
+    """Metadata only: the jitted programs keep their names (the
+    benchmark reads `jit_<function>` modules) and gain scopes."""
+    import jax.numpy as jnp
+
+    from emqx_tpu.ops import match_kernel as mk
+
+    def z(n, d):
+        return jnp.zeros(n, d)
+
+    low = mk.match_batch_compact.lower(
+        z((64, 16), jnp.uint32), z((32, 8), jnp.int32), jnp.uint32(1),
+        z((16, 8), jnp.int32), z(16, jnp.int32), z(16, bool),
+        f_width=16, m_cap=128, c_cap=32,
+    )
+    text = low.as_text(debug_info=True)
+    for scope in ("level_scan", "hit_prefix_sum", "compact"):
+        assert f"jit(match_batch_compact)/{scope}" in text, scope
+    low = mk.decide_batch.lower(
+        z(8, jnp.int8), z(8, bool), z(8, bool), z(8, bool),
+        z(64, jnp.int32), z(64, jnp.int32), z(64, jnp.int32),
+        z(16, jnp.int8), z(16, bool), z(16, jnp.int32),
+    )
+    assert "jit(decide_batch)/decide_columns" in low.as_text(debug_info=True)
+    assert mk.rules_eval_batch.__name__ == "rules_eval_batch"
