@@ -77,6 +77,15 @@ class Channel:
     _recv_slots = None
     _sent_slots = None
     _auth_ok = None
+    _ack_slots = None
+
+    def _ack_run_slots(self, m):
+        slots = Channel._ack_slots
+        if slots is None:
+            rx = m.slots("packets.received", "packets.puback.received")
+            acked = m.slots("messages.acked")
+            slots = Channel._ack_slots = (rx, acked, rx + acked)
+        return slots
 
     def _auth_ok_slots(self, m):
         ok = Channel._auth_ok
@@ -317,8 +326,12 @@ class Channel:
     # ------------------------------------------------------ incoming
 
     def handle_in(self, pkt: C.Packet) -> None:
-        """One parsed packet from the wire (emqx_channel:handle_in/2)."""
+        """One parsed packet from the wire (emqx_channel:handle_in/2),
+        or one `AckRun` of them."""
         self.last_rx = time.time()
+        if pkt.type == C.ACK_RUN:
+            self._handle_ack_run(pkt)
+            return
         m = self.broker.metrics
         m.inc("packets.received")
         if self.state == CONNECTING:
@@ -389,6 +402,37 @@ class Channel:
             self._disconnect_with(RC_PROTOCOL_ERROR)  # no enhanced auth yet
         else:
             self._shutdown("protocol_error")
+
+    def _handle_ack_run(self, run: C.AckRun) -> None:
+        """A read's run of minimal PUBACKs (`StreamParser` with
+        ``ack_runs``): every counter, the session and the hook end
+        where `handle_in` a `Puback` would leave them, with one clock
+        read, one locked bump and one `send_packets` for the run."""
+        if self.state != CONNECTED:
+            # the state guards are a packet's: before CONNECT the first
+            # is the protocol error, while CONNECT resolves the run
+            # joins the backlog as packets (and may overflow it)
+            for pkt in run.packets():
+                if self._closing:
+                    break
+                self.handle_in(pkt)
+            return
+        pids = run.packet_ids
+        known, out = self.session.puback_run(pids)
+        m = self.broker.metrics
+        rx, acked, both = self._ack_run_slots(m)
+        if len(known) == len(pids):
+            m.inc_slots(both, len(pids))
+        else:
+            m.inc_slots(rx, len(pids))
+            if known:
+                m.inc_slots(acked, len(known))
+        hooks = self.broker.hooks
+        if known and hooks.has("message.acked"):
+            clientid = self.client.clientid
+            for pid in known:
+                hooks.run("message.acked", clientid, pid)
+        self.send_packets(out)
 
     # ------------------------------------------------------- connect
 

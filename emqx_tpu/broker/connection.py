@@ -56,8 +56,10 @@ class Connection:
             self.channel.transport_buffered = (
                 transport.get_write_buffer_size
             )
+        # (a read's PUBACKs come as one `AckRun`, not as k packets)
         self.parser = C.StreamParser(
-            max_packet_size=broker.config.mqtt.max_packet_size
+            max_packet_size=broker.config.mqtt.max_packet_size,
+            ack_runs=True,
         )
         self._closed = asyncio.Event()
         self._congested = False
@@ -154,14 +156,19 @@ class Connection:
                 if lc is not None:
                     t_in = time.perf_counter()
                 self.broker.metrics.inc("bytes.received", len(data))
-                n_pkts = n_pubs = n_acks = 0
+                # (an `AckRun` counts as the PUBACKs it carries)
+                n_pubs = n_acks = n_run = n_other = 0
                 if self.limiter is None:
                     for pkt in self.parser.feed(data):
-                        n_pkts += 1
-                        if pkt.type == C.PUBLISH:
+                        t = pkt.type
+                        if t == C.ACK_RUN:
+                            n_run += len(pkt.packet_ids)
+                        elif t == C.PUBLISH:
                             n_pubs += 1
-                        elif pkt.type in _ACKS:
+                        elif t in _ACKS:
                             n_acks += 1
+                        else:
+                            n_other += 1
                         self.channel.handle_in(pkt)
                         if self._closed.is_set():
                             break
@@ -184,8 +191,12 @@ class Connection:
                         self.broker.metrics.inc("connection.rate_limited")
                         t_in += await self._pause(delay)
                     for pkt in self.parser.feed(data):
-                        n_pkts += 1
-                        if pkt.type == C.PUBLISH:
+                        t = pkt.type
+                        if t == C.ACK_RUN:
+                            # (acks cost nothing here: bytes are
+                            # charged a read, messages a PUBLISH)
+                            n_run += len(pkt.packet_ids)
+                        elif t == C.PUBLISH:
                             n_pubs += 1
                             delay = self.limiter.consume(0, 1)
                             if delay > 0:
@@ -193,13 +204,17 @@ class Connection:
                                     "connection.rate_limited"
                                 )
                                 t_in += await self._pause(delay)
-                        elif pkt.type in _ACKS:
+                        elif t in _ACKS:
                             n_acks += 1
+                        else:
+                            n_other += 1
                         self.channel.handle_in(pkt)
                         if self._closed.is_set():
                             break
                 if lc is not None:
-                    lc.ingress(t_in, len(data), n_pkts, n_pubs, n_acks)
+                    n_acks += n_run
+                    lc.ingress(t_in, len(data), n_pubs + n_acks + n_other,
+                               n_pubs, n_acks, n_run)
                 await self._drain()
                 batcher = self.broker.batcher
                 if batcher is not None and batcher.congested(self.channel):

@@ -79,6 +79,21 @@ class Inflight:
     def delete(self, key: int) -> Optional[Any]:
         return self._d.pop(key, None)
 
+    def delete_run(self, keys, qos: int) -> List[int]:
+        """Delete each of ``keys`` whose entry is of this ``qos`` and
+        return those, in order: what `get` + `delete` key by key give
+        (a key unknown, repeated or of another qos is passed over),
+        with the dict's methods bound once for the run."""
+        d = self._d
+        get = d.get
+        gone: List[int] = []
+        for key in keys:
+            entry = get(key)
+            if entry is not None and entry.qos == qos:
+                del d[key]
+                gone.append(key)
+        return gone
+
     def get(self, key: int) -> Optional[Any]:
         return self._d.get(key)
 

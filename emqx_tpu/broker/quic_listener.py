@@ -57,7 +57,8 @@ class _QuicChannelBridge:
         self.rx_datagrams = 0
         self.hs_counted = True  # in the per-source handshake census
         self.parser = C.StreamParser(
-            max_packet_size=listener.broker.config.mqtt.max_packet_size
+            max_packet_size=listener.broker.config.mqtt.max_packet_size,
+            ack_runs=True,
         )
         self.channel = Channel(
             listener.broker,

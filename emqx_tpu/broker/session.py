@@ -529,6 +529,28 @@ class Session:
         self.inflight.delete(pid)
         return True, self._dequeue()
 
+    def puback_run(self, pids) -> Tuple[List[int], List[C.Packet]]:
+        """The PUBACKs of one run, in wire order: exactly what a
+        `puback` call an id gives, as (the known ids in order, every
+        follow-up in order).  With the queue empty as the run starts
+        no ack has a follow-up (nothing enters the queue meanwhile:
+        the loop thread is synchronous), so the ids leave the window
+        together and `_dequeue` runs once, for ``out_parked``.  With a
+        backlog the walk stays id by id: `_alloc_packet_id` skips the
+        ids still in flight, so which ids the follow-ups get depends
+        on which acks came before them."""
+        if not len(self.mqueue):
+            known = self.inflight.delete_run(pids, 1)
+            return known, self._dequeue() if known else []
+        known: List[int] = []
+        out: List[C.Packet] = []
+        for pid in pids:
+            ok, more = self.puback(pid)
+            if ok:
+                known.append(pid)
+                out += more
+        return known, out
+
     def pubrec(self, pid: int) -> Tuple[bool, List[C.Packet]]:
         """PUBREC for a QoS 2 delivery: advance to PUBREL phase."""
         entry = self.inflight.get(pid)

@@ -254,6 +254,27 @@ Packet = Union[
     Disconnect, Auth,
 ]
 
+# not a wire type (those have four bits): what `AckRun.type` reads
+ACK_RUN = 16
+
+
+class AckRun:
+    """A maximal run of consecutive minimal PUBACK frames (``40 02 hi
+    lo``: no reason code, no properties) of one socket read, carried
+    as one object: ``packet_ids`` in wire order.  A `StreamParser`
+    made with ``ack_runs=True`` yields it in place of as many `Puback`
+    objects; a run of one is a run."""
+
+    __slots__ = ("packet_ids",)
+    type = ACK_RUN
+
+    def __init__(self, packet_ids: Tuple[int, ...]) -> None:
+        self.packet_ids = packet_ids
+
+    def packets(self) -> List[Puback]:
+        """The run packet by packet, as `parse_frame` gives them."""
+        return [Puback(packet_id=pid) for pid in self.packet_ids]
+
 
 # ---------------------------------------------------------------------------
 # primitive readers over (buf, pos)
@@ -670,10 +691,15 @@ class StreamParser:
     buffers partial frames, decodes the varint remaining-length with the
     max-size guard, and parses each complete body.  The protocol version
     is locked in from the first CONNECT it sees (or set explicitly for
-    client-side use)."""
+    client-side use).  ``ack_runs`` is what a server's read loop asks
+    for: consecutive minimal PUBACK frames come as one `AckRun`."""
+
+    # frames of an ack run scanned a strided slice: bounds what a lone
+    # PUBACK ahead of a large buffer costs
+    _RUN_CHUNK = 256
 
     def __init__(self, max_packet_size: int = MAX_PACKET_SIZE + 5,
-                 version: int = MQTT_V5):
+                 version: int = MQTT_V5, ack_runs: bool = False):
         # max_packet_size bounds the WHOLE packet (fixed header included),
         # matching the MQTT 5 'Maximum Packet Size' property semantics;
         # default admits the largest representable frame.
@@ -681,6 +707,7 @@ class StreamParser:
         self._pos = 0
         self.max_packet_size = max_packet_size
         self.version = version
+        self.ack_runs = ack_runs
 
     def feed(self, data: bytes) -> Iterator[Packet]:
         # buffer eagerly (feed() must consume `data` even if the returned
@@ -692,8 +719,18 @@ class StreamParser:
         self._buf += data
         return self._drain()
 
-    def _drain(self) -> Iterator[Packet]:
+    def _drain(self) -> Iterator[Union[Packet, AckRun]]:
+        runs, buf = self.ack_runs, self._buf
         while True:
+            if runs:
+                pos = self._pos
+                if (
+                    buf.startswith(b"\x40\x02", pos)
+                    and len(buf) - pos >= 4
+                    and self.max_packet_size >= 4
+                ):
+                    yield self._ack_run()
+                    continue
             frame = self._try_frame()
             if frame is None:
                 return
@@ -702,6 +739,29 @@ class StreamParser:
             if isinstance(pkt, Connect):
                 self.version = pkt.proto_ver
             yield pkt
+
+    def _ack_run(self) -> AckRun:
+        """The longest run of whole ``40 02 hi lo`` frames at the
+        position (one at least, the caller saw): its length from
+        strided slices, its ids from one unpack, no loop a frame."""
+        buf, pos = self._buf, self._pos
+        if not buf.startswith(b"\x40\x02", pos + 4):
+            # a lone ack (most clients' reads) pays for no slice
+            self._pos = pos + 4
+            return AckRun(((buf[pos + 2] << 8) | buf[pos + 3],))
+        whole = (len(buf) - pos) // 4
+        k = 1
+        while k < whole:
+            n = min(whole - k, self._RUN_CHUNK)
+            lo, hi = pos + 4 * k, pos + 4 * (k + n)
+            heads, lens = bytes(buf[lo:hi:4]), bytes(buf[lo + 1 : hi : 4])
+            got = n - max(len(heads.lstrip(b"\x40")),
+                          len(lens.lstrip(b"\x02")))
+            k += got
+            if got < n:
+                break
+        self._pos = pos + 4 * k
+        return AckRun(struct.unpack_from(">%dH" % (2 * k), buf, pos)[1::2])
 
     def _try_frame(self) -> Optional[Tuple[int, int, bytes]]:
         buf, pos = self._buf, self._pos

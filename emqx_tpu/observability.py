@@ -383,7 +383,8 @@ class LoopClock:
 
     FIELDS = (
         "ingress_s", "ingress_reads", "ingress_packets",
-        "ingress_publishes", "ingress_acks", "ingress_bytes",
+        "ingress_publishes", "ingress_acks", "ingress_acks_run",
+        "ingress_bytes",
         "egress_s", "egress_writes", "egress_packets", "egress_bytes",
         "egress_in_window_s", "egress_in_window_writes",
     )
@@ -403,8 +404,10 @@ class LoopClock:
         self._open: Dict[str, List[float]] = {}
 
     def ingress(self, t0: float, n_bytes: int, packets: int,
-                publishes: int, acks: int) -> None:
-        """One socket read's parse + channel work, begun at ``t0``."""
+                publishes: int, acks: int, acks_run: int = 0) -> None:
+        """One socket read's parse + channel work, begun at ``t0``:
+        ``acks_run`` of its ``acks`` crossed as `AckRun`s (a run
+        counts as the packets it carries, everywhere here)."""
         now = time.perf_counter()
         if self.tid is None:
             self.tid = threading.get_ident()
@@ -413,6 +416,7 @@ class LoopClock:
         self.ingress_packets += packets
         self.ingress_publishes += publishes
         self.ingress_acks += acks
+        self.ingress_acks_run += acks_run
         self.ingress_bytes += n_bytes
         self._burst("loop_ingress", t0, now)
 

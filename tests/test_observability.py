@@ -904,11 +904,72 @@ def test_loop_fields_sum_to_the_clock_and_rebase_on_reset():
     assert sum(w["loop_ingress_publishes"] for w in wins) >= 16
     assert sum(w["loop_ingress_acks"] for w in wins) >= 8
     assert lc.ingress_publishes == 48 and lc.ingress_acks == 48
+    # the subscriber's PUBACKs are the four-byte form: each crossed in
+    # a run, and the field is cut into the records like its neighbours
+    assert lc.ingress_acks_run == 48
+    assert 8 <= sum(w["loop_ingress_acks_run"] for w in wins) <= sum(
+        w["loop_ingress_acks"] for w in wins)
     assert lc.ingress_packets >= 96 + 3  # + 2 CONNECT, 1 SUBSCRIBE
     assert lc.egress_packets >= 96 and lc.egress_writes <= lc.egress_packets
     assert lc.ingress_reads <= lc.ingress_packets
     assert 0 < lc.egress_in_window_writes <= lc.egress_writes
     assert 0 < lc.egress_in_window_s <= lc.egress_s
+
+
+def test_a_read_counts_an_ack_run_as_the_packets_it_carries():
+    """One socket write of five minimal PUBACKs, a v5 PUBACK with a
+    reason code and a PINGREQ: seven packets and six acks to the
+    loop's clock, five of them in runs, however the reads fell."""
+    from mqtt_client import TestClient
+
+    async def main():
+        cfg = BrokerConfig()
+        cfg.listeners = [ListenerConfig(bind="127.0.0.1", port=0)]
+        srv = BrokerServer(cfg)
+        await srv.start()
+        lc = srv.broker.profiler.loop
+        try:
+            sub = TestClient(srv.listeners[0].port, "sub")
+            await sub.connect()
+            await sub.subscribe("a/#", qos=1)
+            for i in range(6):
+                srv.broker.publish(Message(topic="a/b", payload=b"x", qos=1))
+            pids = [(await sub.expect(C.PUBLISH)).packet_id for _ in range(6)]
+            before = (lc.ingress_packets, lc.ingress_acks, lc.ingress_acks_run)
+            sub.writer.write(
+                b"".join(bytes((0x40, 2, p >> 8, p & 255)) for p in pids[:5])
+                + bytes((0x40, 3, pids[5] >> 8, pids[5] & 255, 0))
+                + C.serialize(C.Pingreq(), C.MQTT_V5)
+            )
+            await sub.expect(C.PINGRESP)
+            after = (lc.ingress_packets, lc.ingress_acks, lc.ingress_acks_run)
+            assert [a - b for a, b in zip(after, before)] == [7, 6, 5]
+            m = srv.broker.metrics
+            assert m.val("packets.puback.received") == 6
+            assert m.val("messages.acked") == 6
+            assert len(srv.broker.cm.lookup("sub").inflight) == 0
+        finally:
+            await srv.stop()
+
+    run(main())
+
+
+def test_loop_clock_takes_and_resets_the_run_count():
+    prof = Profiler(ring_size=4)
+    lc = prof.loop
+    lc.ingress(time.perf_counter(), 80, 19, 1, 18, 17)
+    lc.ingress(time.perf_counter(), 8, 2, 0, 2)  # scalar acks: none in runs
+    rec = prof.begin(1)
+    prof.commit(rec)
+    w, = prof.windows(10)
+    assert (w["loop_ingress_packets"], w["loop_ingress_acks"],
+            w["loop_ingress_acks_run"]) == (21, 20, 17)
+    lc.ingress(time.perf_counter(), 16, 4, 0, 4, 4)
+    prof.reset()  # re-bases: what came before is no later window's
+    lc.ingress(time.perf_counter(), 12, 3, 0, 3, 3)
+    prof.commit(prof.begin(1))
+    assert prof.windows(1)[0]["loop_ingress_acks_run"] == 3
+    assert lc.ingress_acks_run == 24  # the accumulator only grows
 
 
 def test_profiler_disabled_reads_no_new_clock(monkeypatch):
