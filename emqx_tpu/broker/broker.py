@@ -1686,8 +1686,11 @@ class Broker:
         kmax = base_key + qmax * 2
         if rec is not None:
             if "device_wait" in dec_info:
+                start, dur = dec_info["upload"]
+                rec.sub("decide_upload", dur, start)
                 start, dur = dec_info["device_wait"]
                 rec.sub("decide_device_wait", dur, start)
+                rec.decide_rows, rec.decide_rows_padded = dec_info["rows"]
             rec.lap("decide", then="deliver")
         # per-message tracing masks, computed ONCE per window: a run
         # materializes its deliveries for the OTel span / lifecycle
@@ -2868,6 +2871,23 @@ class PublishBatcher:
             del self._queues[src]
         return entry
 
+    async def _landed(self) -> bool:
+        """Give the loop the turns a readable socket needs to land its
+        publishes in the lanes (`data_received`, the connection's read
+        task, `handle_in`); True if any did.  For a window whose
+        deadline ran out while the loop was elsewhere (a predecessor's
+        dispatch holds it for a whole window's writes): no socket was
+        read meanwhile, so what the publishers sent since is still
+        unread.  Closing at once splits one burst over two windows,
+        and a closed loop then repeats those sizes forever (each
+        window's acks refill as one burst of its size).  Bounded, so
+        a lone publish on a busy loop still closes its window."""
+        for _ in range(3):
+            await asyncio.sleep(0)
+            if self._total:
+                return True
+        return False
+
     def _window_limit(self) -> int:
         """Max messages collected into one window: the pipeline-depth
         bound, capped by the olp ladder's L1 window shrink (smaller
@@ -2930,16 +2950,22 @@ class PublishBatcher:
                         if self._total:
                             batch.append(self._rr_pop())
                             continue
-                        timeout = deadline - loop.time()
-                        if timeout <= 0:
+                        late = loop.time() - deadline
+                        if late >= 0:
+                            # a deadline the loop answered more than
+                            # a window LATE has not been waited out:
+                            # see `_landed`
+                            if late > self.window and await self._landed():
+                                deadline = loop.time() + self.window
+                                continue
                             break
                         self._arrival.clear()
                         try:
                             await asyncio.wait_for(
-                                self._arrival.wait(), timeout
+                                self._arrival.wait(), -late
                             )
                         except asyncio.TimeoutError:
-                            break
+                            pass
                 msgs = [m for m, _fut, _src in batch]
                 if rec is not None:
                     rec.n_msgs = len(batch)

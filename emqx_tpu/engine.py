@@ -43,6 +43,36 @@ def _pow2_at_least(n: int, minimum: int) -> int:
     return cap
 
 
+# `decide_batch` compiles one program a shape triple (attribute-column
+# length, delivery rows, window messages).  The served path keeps that
+# set small enough to compile before traffic: the message columns are
+# padded to the one width `warmup()` was given, the delivery rows to a
+# power of two from `_DEC_ROWS_MIN` (12 KB of indices: padding a small
+# window up to it costs nothing against the round trip), the router's
+# attribute columns to a rung of `_DEC_COLS_MIN` x 4^k.
+_DEC_ROWS_MIN = 1024
+_DEC_COLS_MIN = 4096
+# rows warmed per message of the `warmup()` width: a full window at 32
+# deliveries a publish; a wider window warms its successor as it runs
+_DEC_ROWS_PER_MSG = 32
+# triples this process has compiled (the jit cache is the process's,
+# not an engine's)
+_DEC_WARMED: Set[Tuple[int, int, int]] = set()
+
+
+def _dec_cols_rung(n: int) -> int:
+    cap = _DEC_COLS_MIN
+    while cap < n:
+        cap *= 4
+    return cap
+
+
+def _pad_to(a, cap: int, fill, dtype) -> np.ndarray:
+    out = np.full(cap, fill, dtype=dtype)
+    out[: len(a)] = a
+    return out
+
+
 def _pad_batch(tokens, lengths, dollar):
     """Pad the batch to a power-of-two bucket so XLA sees a bounded set
     of shapes (no recompile storm on ragged publish batches)."""
@@ -520,7 +550,13 @@ class MatchEngine:
         self._dec_dev_us: Optional[float] = None
         self._dec_stats = {"host_windows": 0, "dev_windows": 0,
                            "dev_errors": 0}
-        self._dec_cols_cache: Optional[Tuple] = None  # (rev, dev arrays)
+        # (rev, dev arrays padded to a `_dec_cols_rung`, the rung)
+        self._dec_cols_cache: Optional[Tuple] = None
+        # what `_warm_decide` keeps compiled: the attribute-column
+        # rungs seen so far, and delivery-row buckets up to this many
+        self._dec_rungs: Set[int] = {_DEC_COLS_MIN}
+        self._dec_rows_warm = _DEC_ROWS_MIN
+        self._dec_warm_thread: Optional[threading.Thread] = None
         # EWMA hygiene: the FIRST device decide window pays the JIT
         # compile and must not poison the cost estimate, and a rare
         # in-band re-probe keeps it fresh while host is winning (the
@@ -1273,6 +1309,13 @@ class MatchEngine:
         # compiled every bucket for a table nothing serves
         self._warm_batch = max(self._warm_batch, max_batch)
         self._warm_rules(max_batch)
+        self._dec_rows_warm = max(
+            self._dec_rows_warm, _DEC_ROWS_PER_MSG * max_batch
+        )
+        t = self._dec_warm_thread
+        if t is not None and t.is_alive():
+            t.join()
+        self._warm_decide()
         with self._mlock:
             self._poll_swap()
             device_on = (
@@ -1650,9 +1693,10 @@ class MatchEngine:
         """Compute one window's packed per-delivery decision column
         (see ops.match_kernel's bit layout) on the host or the device,
         chosen per window by the measured per-delivery cost EWMAs.
-        ``info`` (optional dict) receives ``device_wait``: ``(start,
-        dur)`` of the kernel call and the blocking copy back, on the
-        perf_counter clock, where the device served.
+        ``info`` (optional dict) receives, where the device served,
+        ``upload`` and ``device_wait`` (the kernel call and the
+        blocking copy back) as ``(start, dur)`` on the perf_counter
+        clock, and ``rows``: ``(rows, the bucket they were padded to)``.
 
         ``cols`` are the router's SubOpts attribute columns and ``rev``
         their mutation counter (the device copies cache on it).  A
@@ -1744,11 +1788,9 @@ class MatchEngine:
         m_qos, m_retain, m_from_row, info=None,
     ) -> np.ndarray:
         """One device decide step: upload the attribute columns (cached
-        by ``rev``), pad the delivery/message columns to power-of-two
-        buckets (bounded shape classes, as `_pad_batch` does for the
-        match kernel), run the fused kernel, slice the padding off."""
-        from .ops.match_kernel import decide_batch
-
+        by ``rev``), pad them and the delivery/message columns to the
+        bounded shape classes `_warm_decide` compiles (the note at
+        `_DEC_ROWS_MIN`), run the fused kernel, slice the padding off."""
         if failpoints.enabled:
             # chaos seam: an injected error degrades this window to the
             # host columns and feeds the shared device breaker
@@ -1757,30 +1799,137 @@ class MatchEngine:
         if cache is None or cache[0] != rev:
             import jax
 
-            cache = (rev, tuple(jax.device_put(c) for c in cols))
+            rung = _dec_cols_rung(len(cols[0]))
+            cache = (rev, tuple(jax.device_put(
+                _pad_to(c, rung, 0, c.dtype)) for c in cols), rung)
             self._dec_cols_cache = cache
+            want = {rung}
+            if len(cols[0]) >= rung:
+                # the router has filled this rung: its successor
+                # compiles before the next doubling needs it
+                want.add(_dec_cols_rung(rung + 1))
+            if not want <= self._dec_rungs:
+                self._dec_rungs = self._dec_rungs | want
+                self._kick_decide_warm()
         n = len(opts_rows)
-        npad = _pow2_at_least(n, 64)
-        bpad = _pow2_at_least(len(m_qos), 16)
-
-        def pad(a, cap, fill, dtype):
-            out = np.full(cap, fill, dtype=dtype)
-            out[: len(a)] = a
-            return out
-
-        window = (
-            pad(opts_rows, npad, 0, np.int32),
-            pad(client_rows, npad, -1, np.int32),
-            pad(msg_idx, npad, 0, np.int32),
-            pad(m_qos, bpad, 0, np.int8),
-            pad(m_retain, bpad, False, bool),
-            pad(m_from_row, bpad, -1, np.int32),
-        )
-        t0 = time.perf_counter() if info is not None else 0.0
-        packed = np.asarray(decide_batch(*cache[1], *window))
+        npad = _pow2_at_least(n, _DEC_ROWS_MIN)
+        bpad = max(_pow2_at_least(len(m_qos), 16), self._warm_batch)
+        packed = self._decide_run(cache[1], (
+            _pad_to(opts_rows, npad, 0, np.int32),
+            _pad_to(client_rows, npad, -1, np.int32),
+            _pad_to(msg_idx, npad, 0, np.int32),
+            _pad_to(m_qos, bpad, 0, np.int8),
+            _pad_to(m_retain, bpad, False, bool),
+            _pad_to(m_from_row, bpad, -1, np.int32),
+        ), info)
         if info is not None:
-            info["device_wait"] = (t0, time.perf_counter() - t0)
+            info["rows"] = (n, npad)
+        if 2 * npad > self._dec_rows_warm:
+            # the widest window so far: its successor bucket compiles
+            # in the warm thread, ahead of the window that needs it
+            self._dec_rows_warm = 2 * npad
+            self._kick_decide_warm()
         return packed[:n]
+
+    @staticmethod
+    def _decide_run(cols_dev, window, info=None) -> np.ndarray:
+        """The padded kernel call behind `_decide_device` and
+        `_warm_decide`: upload the window's six columns, run, copy the
+        packed column back.  ``info`` receives ``upload`` and
+        ``device_wait`` (kernel and copy back) as ``(start, dur)``."""
+        import jax
+
+        from .ops.match_kernel import decide_batch
+
+        t0 = time.perf_counter()
+        window = jax.block_until_ready(jax.device_put(window))
+        t1 = time.perf_counter()
+        packed = np.asarray(decide_batch(*cols_dev, *window))
+        if info is not None:
+            info["upload"] = (t0, t1 - t0)
+            info["device_wait"] = (t1, time.perf_counter() - t1)
+        return packed
+
+    def _decide_shapes(self) -> List[Tuple[int, int, int]]:
+        """The ``(rung, rows, messages)`` triples a served window can
+        take that this process has not compiled yet."""
+        out = []
+        for rung in sorted(self._dec_rungs):
+            npad = _DEC_ROWS_MIN
+            while npad <= self._dec_rows_warm:
+                sig = (rung, npad, self._warm_batch)
+                if sig not in _DEC_WARMED:
+                    out.append(sig)
+                npad *= 2
+        return out
+
+    def _warm_decide(self) -> bool:
+        """Compile `decide_batch` for every shape triple a served
+        window can take (`_decide_shapes`), so that no window pays a
+        first-use compile on the loop thread.  It needs no automaton:
+        a broker of exact subscriptions alone decides on the device
+        too.  Best effort: False where it compiled nothing more (the
+        device path is off or its breaker open, or a device fault,
+        which is the served path's to count against the breaker)."""
+        if (
+            self.use_device is False or self._brk_open
+            or self.decide_force == "host"
+        ):
+            return False
+        import jax
+
+        t0 = time.perf_counter()
+        todo = self._decide_shapes()
+        cols_dev: Dict[int, Tuple] = {}
+        try:
+            for rung, npad, bpad in todo:
+                if rung not in cols_dev:
+                    cols_dev[rung] = jax.device_put((
+                        np.zeros(rung, np.int8), np.zeros(rung, bool),
+                        np.zeros(rung, bool), np.zeros(rung, bool),
+                    ))
+                self._decide_run(cols_dev[rung], (
+                    np.zeros(npad, np.int32), np.full(npad, -1, np.int32),
+                    np.zeros(npad, np.int32),
+                    np.zeros(bpad, np.int8), np.zeros(bpad, bool),
+                    np.full(bpad, -1, np.int32),
+                ))
+                _DEC_WARMED.add((rung, npad, bpad))
+        except Exception:
+            import logging
+
+            logging.getLogger("emqx_tpu.engine").debug(
+                "decide shape warm failed", exc_info=True
+            )
+            return False
+        self._dec_dev_warm = True
+        prof = self.profiler
+        if todo and prof is not None:
+            prof.event(
+                "xla_compile", time.perf_counter() - t0,
+                decide_shapes=len(todo),
+            )
+        return True
+
+    def _kick_decide_warm(self) -> None:
+        """`_warm_decide` in a thread of its own, as folds warm their
+        buckets: the loop thread never waits for it."""
+
+        def work() -> None:
+            while True:
+                ok = self._warm_decide()
+                with self._lock:
+                    if not ok or not self._decide_shapes():
+                        self._dec_warm_thread = None
+                        return
+
+        with self._lock:
+            if self._dec_warm_thread is not None or not self._decide_shapes():
+                return
+            t = self._dec_warm_thread = threading.Thread(
+                target=work, name="matchengine-decide-warm", daemon=True
+            )
+        t.start()
 
     # -------------------------------------- rules x window matrix
 

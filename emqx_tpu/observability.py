@@ -281,7 +281,7 @@ class WindowRecord(Laps):
     __slots__ = (
         "wall0", "n_msgs", "n_deliveries", "n_clients", "n_clips",
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
-        "loop_cpu",
+        "loop_cpu", "decide_rows", "decide_rows_padded",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -291,6 +291,10 @@ class WindowRecord(Laps):
         self.n_deliveries = 0
         self.n_clients = 0
         self.n_clips = 0  # compact clips re-matched on the dense kernel
+        # delivery rows the device decide step was given, and the
+        # bucket it ran them in (both 0 where the host decided)
+        self.decide_rows = 0
+        self.decide_rows_padded = 0
         self.path = ""  # "host" | "dev" | "host-fallback"
         self.breaker_open = False
         self.source = source  # "publish" | "batcher" | "forwarded"
@@ -353,6 +357,8 @@ class WindowRecord(Laps):
             "n_deliveries": self.n_deliveries,
             "n_clients": self.n_clients,
             "n_clips": self.n_clips,
+            "decide_rows": self.decide_rows,
+            "decide_rows_padded": self.decide_rows_padded,
             "path": self.path,
             "breaker_open": self.breaker_open,
             "stages_us": {
