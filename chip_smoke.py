@@ -7,7 +7,7 @@
 The default run needs one chip and drives, in ONE process that touches
 JAX, with the device pinned and its work counted:
 
-  preflight  the platform must be ``tpu``; the five native libraries
+  preflight  the platform must be ``tpu``; the six native libraries
              are built from ``native/*.cpp`` and must all load;
   engine     `MatchEngine(use_device=True)` at the shipped kernel
              widths, loaded with ``--subs`` wildcard subscriptions
@@ -179,13 +179,13 @@ def preflight(need_devices: int):
         f"native/build.sh failed: {built.stderr[-2000:]}",
     )
     from emqx_tpu.ds import native as dslog
-    from emqx_tpu.ops import dispatchasm, sortutil_native
+    from emqx_tpu.ops import dispatchasm, sockwriter, sortutil_native
     from emqx_tpu.ops import tokdict_native, trie_native
 
     seams = {
         "hosttrie": trie_native, "sortutil": sortutil_native,
         "tokdict": tokdict_native, "dispatchasm": dispatchasm,
-        "dslog": dslog,
+        "dslog": dslog, "sockwriter": sockwriter,
     }
     loaded = {
         name: "native" if mod.load() is not None else "python"

@@ -245,6 +245,9 @@ class Broker:
         # whichever thread ran the match (batcher executor, probe
         # thread), so the alarm publish hops to the event loop.
         self._loop = None  # captured by BrokerServer.start
+        # the native sender thread (ops/sockwriter.SockSender) while a
+        # BrokerServer runs and the library is there; None otherwise
+        self.sender = None
         self.router.engine.on_breaker_trip = self._engine_breaker_trip
         self.router.engine.on_breaker_clear = self._engine_breaker_clear
         self.banned = BannedList()
@@ -1457,13 +1460,21 @@ class Broker:
             if asm[0]:
                 # nested sub-stage: the native splice share of deliver
                 rec.sub("assemble", asm[0])
-        # flush: ONE concatenated transport.write per connection for
-        # the whole window (each channel was corked on first touch)
-        for ch in corked:
-            try:
-                ch.uncork()
-            except Exception:
-                log.exception("window uncork failed")
+        # flush: ONE concatenated write per connection for the whole
+        # window (each channel was corked on first touch), and ONE
+        # hand-over of them all to the sender thread where one runs
+        snd = self.sender
+        if snd is not None:
+            snd.begin()
+        try:
+            for ch in corked:
+                try:
+                    ch.uncork()
+                except Exception:
+                    log.exception("window uncork failed")
+        finally:
+            if snd is not None:
+                snd.end()
         if delivered_runs:
             # ONE bridge call per window per sink (exhook coalescing);
             # fired after the flush so the wire never waits on it
@@ -3138,7 +3149,7 @@ class PublishBatcher:
                 finally:
                     if corked:
                         asyncio.get_running_loop().call_soon(
-                            self._uncork_all, corked
+                            self._uncork_all, corked, self.broker.sender
                         )
                 self._maybe_release()
             except asyncio.CancelledError:
@@ -3147,9 +3158,17 @@ class PublishBatcher:
                 log.exception("publish window post-dispatch failed")
 
     @staticmethod
-    def _uncork_all(channels: List) -> None:
-        for ch in channels:
-            try:
-                ch.uncork()
-            except Exception:
-                log.exception("ack uncork failed")
+    def _uncork_all(channels: List, sender=None) -> None:
+        # (one flush scope: the acks of every publisher of the window
+        # reach the sender thread in one hand-over)
+        if sender is not None:
+            sender.begin()
+        try:
+            for ch in channels:
+                try:
+                    ch.uncork()
+                except Exception:
+                    log.exception("ack uncork failed")
+        finally:
+            if sender is not None:
+                sender.end()

@@ -135,16 +135,18 @@ class Channel:
         # concatenated transport.write on uncork
         self._cork_depth = 0
         self._cork_buf: List[C.Packet] = []
-        # wired by the owning Connection: () -> bytes buffered in the
-        # transport toward this client (the outbound high-watermark
-        # signal; None = transport can't report, watermark inactive)
+        # wired by the owning Connection: () -> bytes buffered toward
+        # this client, in the transport and with the sender thread
+        # (the outbound high-watermark signal; None = transport can't
+        # report, watermark inactive)
         self.transport_buffered = None
 
     def out_buffered(self) -> int:
-        """Bytes buffered toward this client in the transport (the
-        per-connection outbound high-watermark input; cork buffers
-        flush within the same window, so the transport buffer is the
-        unbounded part a stalled subscriber grows)."""
+        """Bytes buffered toward this client in the transport and
+        with the native sender thread (the per-connection outbound
+        high-watermark input; cork buffers flush within the same
+        window, so these are the unbounded part a stalled subscriber
+        grows)."""
         fn = self.transport_buffered
         if fn is None:
             return 0

@@ -11,9 +11,11 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import socket
 import time
 from typing import List, Optional
 
+from .. import failpoints
 from ..codec import mqtt as C
 from .broker import Broker
 from .channel import Channel, CONNECTING
@@ -53,9 +55,33 @@ class Connection:
         if transport is not None and hasattr(
             transport, "get_write_buffer_size"
         ):
-            self.channel.transport_buffered = (
-                transport.get_write_buffer_size
-            )
+            self._tbuf = transport.get_write_buffer_size
+            self.channel.transport_buffered = self._out_buffered
+        else:
+            self._tbuf = None
+        # where a flush scope's writes go, decided once from what the
+        # socket is: plain TCP under a transport that writes straight
+        # to it -> the native sender thread (ops/sockwriter.py); TLS,
+        # WebSocket (no transport), anything else -> the transport
+        self._sender = None
+        self._slot = -1
+        self._handed = False  # the sender may still hold bytes of ours
+        self._parked = False  # the transport took parked bytes back
+        snd = broker.sender
+        if snd is not None and self._tbuf is not None and (
+            transport.get_extra_info("ssl_object") is None
+            and transport.get_extra_info("sslcontext") is None
+        ):
+            sock = transport.get_extra_info("socket")
+            if (
+                sock is not None
+                and sock.family in (socket.AF_INET, socket.AF_INET6)
+                and sock.type == socket.SOCK_STREAM
+                and sock.fileno() >= 0
+            ):
+                self._slot = snd.open(sock.fileno(), self)
+                if self._slot >= 0:
+                    self._sender = snd
         # (a read's PUBACKs come as one `AckRun`, not as k packets)
         self.parser = C.StreamParser(
             max_packet_size=broker.config.mqtt.max_packet_size,
@@ -63,6 +89,7 @@ class Connection:
         )
         self._closed = asyncio.Event()
         self._congested = False
+        self._failed = False  # a send failed on the sender thread
 
     # -------------------------------------------------------- output
 
@@ -89,11 +116,80 @@ class Connection:
         data = b"".join(parts)
         m.inc("packets.sent", n)
         m.inc("bytes.sent", len(data))
-        self.writer.write(data)
+        handed = self._sender is not None and self._hand_over(data)
+        if not handed:
+            self.writer.write(data)
         if lc is not None:
-            lc.egress(t_out, len(data), n)
-        # ONE accessor for the transport's write-buffer signal — the
-        # same `out_buffered` the dispatch watermark reads (0 when the
+            lc.egress(t_out, len(data), n, handed)
+        self._note_buffered()
+
+    def _hand_over(self, data: bytes) -> bool:
+        """The order rule of the two sinks.  The wire carries a
+        connection's bytes in the order `_send_packets` was called:
+        a write goes to the sender thread inside a flush scope while
+        the transport's buffer is empty, and ANY write follows bytes
+        the thread still holds; it goes to the transport (False) only
+        while the thread holds nothing.  After a hand-back the
+        transport owns the connection until its buffer is empty."""
+        snd, slot = self._sender, self._slot
+        if self._handed and snd.pending(slot) == 0:
+            self._handed = False
+        if not self._handed:
+            if self._parked:
+                if self._tbuf():
+                    return False
+                self._parked = False
+                snd.unpark(slot)
+            if not snd.in_scope or self._tbuf():
+                return False
+        if failpoints.enabled:
+            try:
+                act = failpoints.evaluate(
+                    "conn.sender.send", key=self.channel.peer
+                )
+            except ConnectionError:
+                # as a send(2) that failed on the thread
+                self.on_sender_failed(0)
+                return True
+            if act == "drop":
+                return True  # the bytes the network ate
+            if act == "duplicate":
+                snd.add(slot, data)
+        snd.add(slot, data)
+        self._handed = True
+        return True
+
+    def on_sender_parked(self, data: bytes) -> None:
+        """The socket would not take a write on the sender thread:
+        here is the remainder and what was queued behind it, in
+        order, for the transport (which tells `drain` and watches
+        the socket for room)."""
+        if self.writer.is_closing():
+            return
+        self._parked = True
+        self.writer.write(data)
+        self._note_buffered()
+
+    def on_sender_failed(self, err: int) -> None:
+        """A ``send`` on the sender thread failed (EPIPE, ECONNRESET):
+        the connection closes as on the transport's
+        ``connection_lost``."""
+        log.debug("sender write to %s failed: errno %d",
+                  self.channel.peer, err)
+        self._failed = True
+        self._close("peer_reset")
+
+    def _out_buffered(self) -> int:
+        """Bytes buffered toward this client: the transport's write
+        buffer plus what the sender thread still holds."""
+        n = self._tbuf()
+        if self._handed:
+            n += self._sender.pending(self._slot)
+        return n
+
+    def _note_buffered(self) -> None:
+        # ONE accessor for the write-buffer signal — the same
+        # `out_buffered` the dispatch watermark reads (0 when the
         # transport can't report, which also skips the alarm below)
         buffered = self.channel.out_buffered()
         if buffered == 0 and not self._congested:
@@ -127,9 +223,19 @@ class Connection:
                 else self.channel.peer
             )
             self.broker.alarms.deactivate(f"conn_congestion/{cid}")
+        self._release_slot()
         if not self.writer.is_closing():
             self.writer.close()
         self._closed.set()
+
+    def _release_slot(self) -> None:
+        """Nothing more goes to the sender thread: it sends what it
+        was handed, then closes its own descriptor (queue order)."""
+        snd, self._sender = self._sender, None
+        if snd is not None:
+            snd.close(self._slot)
+            self._slot = -1
+            self._handed = False
 
     # --------------------------------------------------------- input
 
@@ -238,7 +344,10 @@ class Connection:
             reason = "server_stopped"
         finally:
             timer.cancel()
+            if self._failed:
+                reason = "peer_reset"
             self.channel.connection_lost(reason)
+            self._release_slot()
             if not self.writer.is_closing():
                 self.writer.close()
             try:

@@ -297,6 +297,13 @@ class BrokerServer:
             await self.broker._loop.run_in_executor(
                 None, engine.warmup, eng_cfg.batch_max
             )
+        # the native sender thread, before a listener accepts: every
+        # plain-TCP connection takes its slot as it is made
+        from ..ops import sockwriter
+
+        self.broker.sender = sockwriter.start(
+            self.broker._loop, self.broker.profiler.loop
+        )
         if eng_cfg.batch_publish:
             from .broker import PublishBatcher
 
@@ -668,6 +675,11 @@ class BrokerServer:
         if self.broker.batcher is not None:
             await self.broker.batcher.stop()
             self.broker.batcher = None
+        if self.broker.sender is not None:
+            # after the listeners and the batcher: no connection and
+            # no flush scope is left; the thread drains and is joined
+            self.broker.sender.stop()
+            self.broker.sender = None
         if self.telemetry is not None:
             await self.telemetry.stop()
             self.telemetry = None

@@ -84,6 +84,7 @@ SEAMS = (
     "multicore.service.restart",
     "resource.batch.flush",
     "bridge.mqtt.send",
+    "conn.sender.send",
 )
 
 enabled = False  # fast-path gate: disabled brokers pay one bool check

@@ -281,7 +281,7 @@ class WindowRecord(Laps):
     __slots__ = (
         "wall0", "n_msgs", "n_deliveries", "n_clients", "n_clips",
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
-        "loop_cpu", "decide_rows", "decide_rows_padded",
+        "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -310,6 +310,9 @@ class WindowRecord(Laps):
         # the loop thread's CPU seconds since the previous window began
         self.loop: Optional[Tuple] = None
         self.loop_cpu = 0.0
+        # the native sender thread's own clock over the same stretch:
+        # (seconds inside send(2), send calls), None without a sender
+        self.sender: Optional[Tuple[float, int]] = None
 
     def sub(self, name: str, dur_s: float,
             start: Optional[float] = None) -> None:
@@ -349,6 +352,9 @@ class WindowRecord(Laps):
                     field, v = field[:-2] + "_us", round(v * 1e6, 1)
                 loop["loop_" + field] = v
             loop["loop_cpu_us"] = round(self.loop_cpu * 1e6, 1)
+        if self.sender is not None:
+            loop["sender_send_us"] = round(self.sender[0] * 1e6, 1)
+            loop["sender_writes"] = self.sender[1]
         return {
             "seq": self.seq,
             "at": self.wall0,
@@ -380,12 +386,18 @@ class LoopClock:
     """What the event loop does between the windows' stages: every
     socket read (parse + channel, `Connection.run`) and every socket
     write (`Connection._send_packets`) adds its interval and counts
-    here, two ``perf_counter`` reads each and none a packet.  The
-    totals only grow; `take` hands the growth since the previous take
-    to the window being committed, and `stamp_cpu` the loop thread's
-    CPU since the previous window began to the one beginning.  For
-    the trace, intervals less than ``BURST_GAP_S`` apart merge into
-    one burst.  Loop thread only, so no lock."""
+    here, two ``perf_counter`` reads each and none a packet.  A write
+    handed to the native sender thread (``egress_writes_sender``,
+    ``egress_bytes_sender``) costs the loop its serialize and one
+    list append, and each flush scope one hand-over (`egress_submit`):
+    the ``send`` itself is on the thread's clock, `take_sender`.
+    ``egress_parked`` counts the hand-backs after a socket would not
+    take a write.  The totals only grow; `take` hands the growth
+    since the previous take to the window being committed, and
+    `stamp_cpu` the loop thread's CPU since the previous window began
+    to the one beginning.  For the trace, intervals less than
+    ``BURST_GAP_S`` apart merge into one burst.  Loop thread only, so
+    no lock."""
 
     FIELDS = (
         "ingress_s", "ingress_reads", "ingress_packets",
@@ -393,6 +405,7 @@ class LoopClock:
         "ingress_bytes",
         "egress_s", "egress_writes", "egress_packets", "egress_bytes",
         "egress_in_window_s", "egress_in_window_writes",
+        "egress_writes_sender", "egress_bytes_sender", "egress_parked",
     )
     BURST_GAP_S = 200e-6
     BURSTS_CAP = 65536
@@ -406,6 +419,10 @@ class LoopClock:
         # a write inside a window's deliver / flush laps is inside
         # those laps too: the broker raises this around them
         self.in_window = False
+        # the native sender's clock, while one runs: () -> (seconds
+        # inside send(2), send calls), both only growing
+        self.sender_clock = None
+        self._sender_base = (0.0, 0)
         self._bursts: deque = deque(maxlen=self.BURSTS_CAP)
         self._open: Dict[str, List[float]] = {}
 
@@ -426,17 +443,47 @@ class LoopClock:
         self.ingress_bytes += n_bytes
         self._burst("loop_ingress", t0, now)
 
-    def egress(self, t0: float, n_bytes: int, packets: int) -> None:
-        """One socket write (serialize + write), begun at ``t0``."""
+    def egress(self, t0: float, n_bytes: int, packets: int,
+               sender: bool = False) -> None:
+        """One socket write, begun at ``t0``: serialize + write, or
+        (``sender``) serialize + the append to the scope's batch."""
         now = time.perf_counter()
         self.egress_s += now - t0
         self.egress_writes += 1
         self.egress_packets += packets
         self.egress_bytes += n_bytes
+        if sender:
+            self.egress_writes_sender += 1
+            self.egress_bytes_sender += n_bytes
         if self.in_window:
             self.egress_in_window_s += now - t0
             self.egress_in_window_writes += 1
         self._burst("loop_egress", t0, now)
+
+    def egress_submit(self, t0: float) -> None:
+        """One hand-over of a scope's batch to the sender thread,
+        begun at ``t0``: the loop's time, no write of its own."""
+        now = time.perf_counter()
+        self.egress_s += now - t0
+        if self.in_window:
+            self.egress_in_window_s += now - t0
+        self._burst("loop_egress", t0, now)
+
+    def take_sender(self) -> Optional[Tuple[float, int]]:
+        """The sender thread's clock, its growth since the previous
+        take; None while no sender runs."""
+        clock = self.sender_clock
+        if clock is None:
+            return None
+        now = clock()
+        base, self._sender_base = self._sender_base, now
+        return (now[0] - base[0], now[1] - base[1])
+
+    def attach_sender(self, clock) -> None:
+        """A sender thread started (its clock begins at zero), or
+        (None) stopped."""
+        self.sender_clock = clock
+        self._sender_base = (0.0, 0)
 
     def _burst(self, name: str, t0: float, t1: float) -> None:
         cur = self._open.get(name)
@@ -477,6 +524,7 @@ class LoopClock:
         # window's: read the clock afresh)
         self.stamp_cpu()
         self.take()
+        self.take_sender()
         self._bursts.clear()
         self._open.clear()
 
@@ -572,6 +620,7 @@ class Profiler:
         lc = self.loop
         if lc is not None:
             rec.loop = lc.take()
+            rec.sender = lc.take_sender()
             lc.in_window = False
         hist = self._hist
         with self._hlock:

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Build every native helper .so from the committed sources with the
 # flags the on-demand builders in emqx_tpu/ops/*_native.py,
-# ops/dispatchasm.py and ds/native.py use.  native/build/ is not
+# ops/dispatchasm.py, ops/sockwriter.py and ds/native.py use.  native/build/ is not
 # committed: each loader builds its own lib on first load and again
 # when the source is newer than the binary, so this script is for a
 # clean rebuild, a toolchain bump, or a copied tree whose mtimes prove
@@ -18,10 +18,10 @@ set -u
 cd "$(dirname "$0")"
 mkdir -p build
 
-FLAGS="-O3 -fPIC -shared -std=c++17 -Wall"
+FLAGS="-O3 -fPIC -shared -std=c++17 -Wall -pthread"
 status=0
 
-for src in sortutil tokdict dslog hosttrie dispatchasm; do
+for src in sortutil tokdict dslog hosttrie dispatchasm sockwriter; do
     out="build/lib${src}.so"
     # link under a private name and rename into place: a process that
     # loads the library meanwhile sees the old file or the new one,

@@ -82,7 +82,9 @@ def test_ocpp_call_result_and_downlink():
         # -------- upstream CALL -> ocpp/cp/CP001
         cp.send([2, "m1", "BootNotification",
                  {"chargePointModel": "X1", "chargePointVendor": "emq"}])
-        pub = await csms.recv_publish()
+        # (the broker's first window: seconds, with six test workers
+        # on the cores; it timed out at the default 2 s now and then)
+        pub = await csms.recv_publish(timeout=30)
         assert pub.topic == "ocpp/cp/CP001"
         body = json.loads(pub.payload)
         assert body["type"] == 2 and body["action"] == "BootNotification"

@@ -41,11 +41,11 @@ from .engine import call_tail, dotted_name
 
 # known GIL-released native entry points: the C ABI symbol prefixes of
 # native/*.cpp (da_=dispatchasm, ht_=hosttrie, td_=tokdict,
-# su_=sortutil, dslog_=dslog).  A call whose tail matches is a "native
+# su_=sortutil, dslog_=dslog, sw_=sockwriter).  A call whose tail matches is a "native
 # call" base fact; wrappers (ops.dispatchasm.assemble_run, ...) pick
 # it up transitively through their summaries.
 NATIVE_ENTRY_PREFIXES: Tuple[str, ...] = (
-    "da_", "ht_", "td_", "su_", "dslog_",
+    "da_", "ht_", "td_", "su_", "dslog_", "sw_",
 )
 
 

@@ -98,6 +98,8 @@ SEAM_FUNCS: Tuple[Seam, ...] = (
     Seam("emqx_tpu/broker/matchclient.py",
          "ServiceMatchEngine._reconnect_once",
          "multicore.service.restart"),
+    Seam("emqx_tpu/broker/connection.py", "Connection._hand_over",
+         "conn.sender.send"),
 )
 
 
