@@ -42,7 +42,6 @@ import asyncio
 import gc
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -170,14 +169,10 @@ def preflight(need_devices: int):
     _DEVICE.update(platform=devs[0].platform, kind=devs[0].device_kind)
     # a copied tree's mtimes prove nothing: build the libraries anew
     # from the committed sources rather than trust what lies there
-    built = subprocess.run(
-        ["bash", os.path.join(REPO, "native", "build.sh")],
-        capture_output=True, text=True,
-    )
-    check(
-        built.returncode == 0,
-        f"native/build.sh failed: {built.stderr[-2000:]}",
-    )
+    from emqx_tpu.ops import nativelib
+
+    failed = {n: why for n, why in nativelib.rebuild().items() if why}
+    check(not failed, f"native rebuild failed: {failed}")
     from emqx_tpu.ds import native as dslog
     from emqx_tpu.ops import dispatchasm, sockwriter, sortutil_native
     from emqx_tpu.ops import tokdict_native, trie_native

@@ -592,18 +592,11 @@ def _merge_dataclass(obj: Any, data: Dict[str, Any]) -> None:
 
 ENV_PREFIX = "EMQX_TPU_"
 
-# runtime switches that share the prefix but are NOT config paths:
-# the native-lib kill switches read directly by the emqx_tpu.ops
-# loaders.  Without this carve-out a worker subprocess booted with
-# one in its environment (e.g. a fallback-mode test run) died with
+# a runtime switch that shares the prefix but is NOT a config path
+# (read directly by broker/broker.py).  Without this carve-out a
+# worker subprocess booted with it in its environment died with
 # "unknown config path".
-ENV_RESERVED = {
-    "EMQX_TPU_NO_NATIVE_SORT",
-    "EMQX_TPU_NO_NATIVE_TOKDICT",
-    "EMQX_TPU_NO_NATIVE_TRIE",
-    "EMQX_TPU_NO_NATIVE_DISPATCH",
-    "EMQX_TPU_NO_DECIDE",
-}
+ENV_RESERVED = {"EMQX_TPU_NO_DECIDE"}
 
 
 def apply_env_overrides(

@@ -1,122 +1,81 @@
-"""ctypes binding for the native dslog storage engine.
-
-Builds ``native/dslog.cpp`` on demand with g++ (the environment bakes
-the toolchain in; pybind11 is not available so the C ABI + ctypes is
-the binding layer — see native/dslog.cpp for the format).
+"""ctypes binding for the native dslog storage engine (pybind11 is not
+available, so the C ABI + ctypes is the binding layer — see
+native/dslog.cpp for the format; ``ops/nativelib.py`` builds it).
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
-import threading
 from typing import Optional
 
 from .. import failpoints
-
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO, "native", "dslog.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libdslog.so")
-
-_lock = threading.Lock()
-_lib = None
+from ..ops import nativelib
 
 
-def _build() -> None:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    # built from the committed source on first load in a fresh
-    # checkout (native/build/ is not committed) and again when
-    # the source is newer — never on the steady-state path, so
-    # the loop stall is accepted
-    # brokerlint: ignore[ASYNC101]
-    subprocess.run(
-        [
-            "g++",
-            "-O2",
-            "-fPIC",
-            "-shared",
-            "-std=c++17",
-            "-Wall",
-            "-o",
-            _SO,
-            _SRC,
-        ],
-        check=True,
-        capture_output=True,
-    )
+def _bind(lib) -> None:
+    lib.dslog_open.restype = ctypes.c_void_p
+    lib.dslog_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
+    lib.dslog_close.argtypes = [ctypes.c_void_p]
+    lib.dslog_append.restype = ctypes.c_int64
+    lib.dslog_append.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_uint32,
+        ctypes.c_uint64,
+        ctypes.c_char_p,
+        ctypes.c_uint32,
+    ]
+    lib.dslog_sync.restype = ctypes.c_int
+    lib.dslog_sync.argtypes = [ctypes.c_void_p]
+    lib.dslog_streams.restype = ctypes.c_int
+    lib.dslog_streams.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_uint32),
+        ctypes.c_int,
+    ]
+    lib.dslog_iter_new.restype = ctypes.c_void_p
+    lib.dslog_iter_new.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_uint32,
+        ctypes.c_uint64,
+    ]
+    lib.dslog_iter_free.argtypes = [ctypes.c_void_p]
+    lib.dslog_iter_next.restype = ctypes.c_int64
+    lib.dslog_iter_next.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.c_uint32,
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint64),
+    ]
+    lib.dslog_stream_count.restype = ctypes.c_int64
+    lib.dslog_stream_count.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
+    lib.dslog_corrupt_records.restype = ctypes.c_int64
+    lib.dslog_corrupt_records.argtypes = [ctypes.c_void_p]
+    lib.dslog_quarantined_count.restype = ctypes.c_int
+    lib.dslog_quarantined_count.argtypes = [ctypes.c_void_p]
+    lib.dslog_gc.restype = ctypes.c_int64
+    lib.dslog_gc.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
+    lib.dslog_gc2.restype = ctypes.c_int64
+    lib.dslog_gc2.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_uint64,
+        ctypes.c_uint32,
+    ]
+    lib.dslog_seg_for.restype = ctypes.c_int64
+    lib.dslog_seg_for.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_uint32,
+        ctypes.c_uint64,
+        ctypes.c_uint64,
+    ]
+    lib.dslog_cur_seg.restype = ctypes.c_int64
+    lib.dslog_cur_seg.argtypes = [ctypes.c_void_p]
 
 
 def load():
-    """Load (building if stale) the dslog shared library."""
-    global _lib
-    with _lock:
-        if _lib is not None:
-            return _lib
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(
-            _SRC
-        ):
-            _build()
-        lib = ctypes.CDLL(_SO)
-        lib.dslog_open.restype = ctypes.c_void_p
-        lib.dslog_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
-        lib.dslog_close.argtypes = [ctypes.c_void_p]
-        lib.dslog_append.restype = ctypes.c_int64
-        lib.dslog_append.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_uint32,
-            ctypes.c_uint64,
-            ctypes.c_char_p,
-            ctypes.c_uint32,
-        ]
-        lib.dslog_sync.restype = ctypes.c_int
-        lib.dslog_sync.argtypes = [ctypes.c_void_p]
-        lib.dslog_streams.restype = ctypes.c_int
-        lib.dslog_streams.argtypes = [
-            ctypes.c_void_p,
-            ctypes.POINTER(ctypes.c_uint32),
-            ctypes.c_int,
-        ]
-        lib.dslog_iter_new.restype = ctypes.c_void_p
-        lib.dslog_iter_new.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_uint32,
-            ctypes.c_uint64,
-        ]
-        lib.dslog_iter_free.argtypes = [ctypes.c_void_p]
-        lib.dslog_iter_next.restype = ctypes.c_int64
-        lib.dslog_iter_next.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_char_p,
-            ctypes.c_uint32,
-            ctypes.POINTER(ctypes.c_uint64),
-            ctypes.POINTER(ctypes.c_uint64),
-        ]
-        lib.dslog_stream_count.restype = ctypes.c_int64
-        lib.dslog_stream_count.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
-        lib.dslog_corrupt_records.restype = ctypes.c_int64
-        lib.dslog_corrupt_records.argtypes = [ctypes.c_void_p]
-        lib.dslog_quarantined_count.restype = ctypes.c_int
-        lib.dslog_quarantined_count.argtypes = [ctypes.c_void_p]
-        lib.dslog_gc.restype = ctypes.c_int64
-        lib.dslog_gc.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-        lib.dslog_gc2.restype = ctypes.c_int64
-        lib.dslog_gc2.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_uint64,
-            ctypes.c_uint32,
-        ]
-        lib.dslog_seg_for.restype = ctypes.c_int64
-        lib.dslog_seg_for.argtypes = [
-            ctypes.c_void_p,
-            ctypes.c_uint32,
-            ctypes.c_uint64,
-            ctypes.c_uint64,
-        ]
-        lib.dslog_cur_seg.restype = ctypes.c_int64
-        lib.dslog_cur_seg.argtypes = [ctypes.c_void_p]
-        _lib = lib
-        return lib
+    """The dslog shared library, or None where it cannot be built."""
+    return nativelib.load("dslog", _bind)
 
 
 class DsLog:
@@ -136,6 +95,8 @@ class DsLog:
 
     def __init__(self, directory: str, seg_bytes: int = 0) -> None:
         self._lib = load()
+        if self._lib is None:  # no Python twin: the store needs the engine
+            raise RuntimeError("native dslog unavailable")
         os.makedirs(directory, exist_ok=True)
         self._dir = directory
         self._seg_bytes = seg_bytes

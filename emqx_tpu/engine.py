@@ -342,6 +342,9 @@ class _EncArena:
         return self.fids[: self.used]
 
 
+_NO_DTIER = (None, None, None)  # no delta automaton folded yet
+
+
 class _ResidualView:
     """Read view of "wildcard filters inserted after the fold
     watermark", backed by the seq-tagged `_wild` trie — the overlay's
@@ -436,9 +439,10 @@ class MatchEngine:
         # insert, and tables pad to power-of-two capacity classes so
         # XLA re-uses a bounded set of compiled shapes instead of
         # recompiling per build.
-        self._daut: Optional[Automaton] = None
-        self._ddev: Optional[Tuple] = None
-        self._dfid_arr: Optional[np.ndarray] = None
+        # (automaton, device tables, position->fid array): ONE
+        # attribute, replaced in one store, so no reader can see the
+        # parts of two generations
+        self._dtier: Tuple = _NO_DTIER
         self._daut_fids: Set[Hashable] = set()
         self._fold_cache: Optional[_EncArena] = None  # fold encode arena
         # STICKY fold capacity classes: each new (node, bucket) shape
@@ -1036,9 +1040,7 @@ class MatchEngine:
                     return  # base swapped underneath: fold is stale
                 tp("fold_commit", gen=gen, watermark=snap_seq)
                 self._fold_cache = arena
-                self._daut = aut
-                self._ddev = dev
-                self._dfid_arr = fid_view
+                self._dtier = (aut, dev, fid_view)
                 self._daut_fids = live_fids
                 # tombstones for fids deleted while the fold assembled
                 # (fresh set: an in-flight match's captured snapshot
@@ -1135,9 +1137,7 @@ class MatchEngine:
             bp *= 2
 
     def _drop_delta_aut(self) -> None:
-        self._daut = None
-        self._ddev = None
-        self._dfid_arr = None
+        self._dtier = _NO_DTIER
         self._daut_fids = set()
         self._fold_cache = None
         # discard any in-flight fold: its inputs predate this state
@@ -1155,23 +1155,33 @@ class MatchEngine:
         t = self._build_thread
         if t is not None and t.is_alive():
             t.join()
-        self._poll_swap()
-        inputs = self._snapshot_inputs()
-        (
-            self._aut,
-            self._dev,
-            self._fid_arr,
-            self._n_base,
-            self._build_cache,
-        ) = self._build(inputs, hash_buckets=hash_buckets)
-        self._delta = {}
-        self._delta_seq = {}
-        self._residual_log = []
-        self._residual_count = 0
-        self._fold_watermark = self._wild.last_seq()
-        self._drop_delta_aut()
-        self._deleted_base = set()
-        self._deleted_daut = set()
+        # under _mlock like every other writer of this state: a fold
+        # thread's commit (gen check, then its stores) interleaved
+        # with the drop below and adopted a stale or half-cleared
+        # delta tier
+        with self._mlock:
+            self._poll_swap()
+            inputs = self._snapshot_inputs()
+            # the synchronous variant keeps _mlock across the native
+            # sort on purpose: mutations must not interleave with the
+            # table swap
+            # brokerlint: ignore[LOCK402]
+            built = self._build(inputs, hash_buckets=hash_buckets)
+            (
+                self._aut,
+                self._dev,
+                self._fid_arr,
+                self._n_base,
+                self._build_cache,
+            ) = built
+            self._delta = {}
+            self._delta_seq = {}
+            self._residual_log = []
+            self._residual_count = 0
+            self._fold_watermark = self._wild.last_seq()
+            self._drop_delta_aut()
+            self._deleted_base = set()
+            self._deleted_daut = set()
 
     def kick_rebuild(self) -> bool:
         """Start a background rebuild NOW if the delta has outgrown
@@ -1568,14 +1578,17 @@ class MatchEngine:
         (empty) successors folded into the new base, and overlaying
         those against the old base would drop every delta-resident
         subscription for the window."""
-        if self._daut is not None and self._ddev is None:
+        daut, ddev, dfids = self._dtier
+        if daut is not None and ddev is None:
             import jax
 
             # lazy upload keeps device_put off the insert path (folds
             # usually stage device arrays themselves; this covers the
             # upload-failed / use_device-toggled corners)
-            self._ddev = tuple(
-                jax.device_put(a) for a in self._daut.device_arrays()
+            self._dtier = (
+                daut,
+                tuple(jax.device_put(a) for a in daut.device_arrays()),
+                dfids,
             )
         return (
             self._aut,
@@ -1584,7 +1597,7 @@ class MatchEngine:
             _ResidualView(self._wild, self._fold_watermark),
             self._deep,
             self._deleted_base,
-            (self._daut, self._ddev, self._dfid_arr),
+            self._dtier,
             self._deleted_daut,
         )
 

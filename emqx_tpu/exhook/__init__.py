@@ -2,22 +2,10 @@
 EMQX (reference contract: /root/reference/apps/emqx_exhook/priv/protos/
 exhook.proto; bridge semantics: emqx_exhook_handler.erl:230-236).
 
-`exhook_pb2` is generated from proto/exhook.proto with protoc on demand
-(shared codegen plumbing: emqx_tpu.grpc_util; the service layer is
-hand-wired generic handlers in server.py).
+`exhook_pb2` is generated from proto/exhook.proto and committed
+(README, "Running", has the command for whoever edits the .proto); the
+service layer is hand-wired generic handlers in server.py.
 """
 
-from __future__ import annotations
-
-import os
-
-from ..grpc_util import ensure_pb2
-
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_REPO = os.path.dirname(os.path.dirname(_HERE))
-
-pb = ensure_pb2(
-    os.path.join(_REPO, "proto", "exhook.proto"), _HERE, "exhook_pb2"
-)
-
-from .server import ExhookServer  # noqa: E402,F401
+from . import exhook_pb2 as pb
+from .server import ExhookServer  # noqa: F401

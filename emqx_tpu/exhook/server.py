@@ -19,7 +19,7 @@ we ARE that server:
 
 No grpc_tools codegen exists in this environment, so method handlers
 are wired with `grpc.method_handlers_generic_handler` against the
-protoc-generated message classes.
+generated message classes.
 """
 
 from __future__ import annotations

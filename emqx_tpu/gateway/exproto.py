@@ -9,8 +9,9 @@ and serve ``ConnectionAdapter`` so that service can drive each
 connection: send bytes, authenticate a clientid, subscribe/publish on
 the broker core, start the keepalive timer, close the socket.
 
-gRPC plumbing mirrors the exhook server: protoc-generated message
-classes + hand-wired generic method handlers (no grpc_tools codegen in
+gRPC plumbing mirrors the exhook server: the committed message classes
+generated from proto/exproto.proto (README, "Running", has the
+command) + hand-wired generic method handlers (no grpc_tools codegen in
 this environment); handler->broker calls marshal onto the asyncio loop
 with ``call_soon_threadsafe``, and gateway->handler calls use
 future-based stubs so the event loop never blocks on the handler
@@ -21,7 +22,6 @@ from __future__ import annotations
 import asyncio
 import itertools
 import logging
-import os
 import threading
 import time
 from concurrent import futures
@@ -33,20 +33,13 @@ from ..access import ClientInfo
 from ..codec import mqtt as C
 from ..message import Message
 from ..broker.session import SubOpts
-from ..grpc_util import ensure_pb2
 from . import Gateway, GatewayChannel, GatewayFrame
+from . import exproto_pb2 as pb
 
 log = logging.getLogger("emqx_tpu.gateway.exproto")
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_REPO = os.path.dirname(os.path.dirname(_HERE))
-
 ADAPTER_SERVICE = "emqx.exproto.v1.ConnectionAdapter"
 HANDLER_SERVICE = "emqx.exproto.v1.ConnectionUnaryHandler"
-
-pb = ensure_pb2(
-    os.path.join(_REPO, "proto", "exproto.proto"), _HERE, "exproto_pb2"
-)
 
 SUCCESS = 0
 UNKNOWN = 1

@@ -780,11 +780,13 @@ class Profiler:
                 key=lambda s: (s[0], -s[1]),
             )
             k = 0
-            cursor = base_us  # monotonic clamp: contiguous span
-            # offsets are measured independently, so edge timestamps
-            # can disagree by an ulp — never let E(k) > B(k+1)
-            for name, off, dur in rec.spans:
-                b_ts = max(base_us + off * 1e6, cursor)
+            # laps run contiguously from the record's start
+            # (`Laps.lap`), so each boundary is computed ONCE: a lap's
+            # E and the next lap's B are the same float.  (Taken from
+            # offsets, the two disagree by an ulp now and then.)
+            cursor = base_us
+            for name, _off, dur in rec.spans:
+                b_ts = cursor
                 e_ts = b_ts + max(dur, 0.0) * 1e6
                 args = {
                     "n_msgs": rec.n_msgs,

@@ -5,87 +5,46 @@ One call splices a whole client run — head span, 2-byte packet-id
 patch, tail span per delivery — out of the window encoder's arena into
 one contiguous wire buffer (the connection's corked write), replacing
 the per-delivery Python join + ``Packet`` object churn that dominated
-the ``deliver`` stage p99 at high fan-out.  Same load/fallback
-contract as ``sortutil_native``/``tokdict_native``: a missing or
-unbuildable ``.so`` (or ``EMQX_TPU_NO_NATIVE_DISPATCH=1``) degrades to
-the pure-Python per-delivery loop in ``Session.deliver``, which stays
-bit-identical (property-tested in tests/test_dispatch_native.py)."""
+the ``deliver`` stage p99 at high fan-out.  A missing or unbuildable
+``.so`` (``ops/nativelib.py``) degrades to the pure-Python
+per-delivery loop in ``Session.deliver``, which stays bit-identical
+(property-tested in tests/test_dispatch_native.py)."""
 
 from __future__ import annotations
 
 import ctypes
-import logging
-import os
-import subprocess
-import threading
 
-_REPO = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-_SRC = os.path.join(_REPO, "native", "dispatchasm.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libdispatchasm.so")
-
-_lock = threading.Lock()
-_lib = None
-_lib_failed = False
+from . import nativelib
 
 _I64P = ctypes.POINTER(ctypes.c_int64)
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 
 
+def _bind(lib) -> None:
+    lib.da_assemble_run.restype = ctypes.c_int64
+    lib.da_assemble_run.argtypes = [
+        _U8P,                    # arena
+        _I64P, _I64P,            # head_off, head_len
+        _I64P, _I64P,            # tail_off, tail_len
+        _I64P, _I64P,            # body idx, pid (-1 = no pid)
+        ctypes.c_int64,          # n deliveries
+        _U8P,                    # out
+    ]
+    lib.da_assemble_window.restype = ctypes.c_int64
+    lib.da_assemble_window.argtypes = [
+        _U8P,                    # arena
+        _I64P, _I64P,            # head_off, head_len
+        _I64P, _I64P,            # tail_off, tail_len
+        _I64P, _I64P,            # body idx, pid (-1 = no pid)
+        _I64P, _I64P,            # run_start, run_out_off
+        ctypes.c_int64,          # n runs
+        ctypes.c_int64,          # n deliveries total
+        _U8P,                    # out
+    ]
+
+
 def load():
-    global _lib, _lib_failed
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        if os.environ.get("EMQX_TPU_NO_NATIVE_DISPATCH") == "1":
-            _lib_failed = True
-            return None
-        try:
-            if not os.path.exists(_SO) or os.path.getmtime(
-                _SO
-            ) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_SO), exist_ok=True)
-                # built from the committed source on first load in a fresh
-                # checkout (native/build/ is not committed) and again when
-                # the source is newer — never on the steady-state path, so
-                # the loop stall is accepted
-                # brokerlint: ignore[ASYNC101]
-                subprocess.run(
-                    ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-                     "-Wall", "-o", _SO, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-            lib = ctypes.CDLL(_SO)
-            lib.da_assemble_run.restype = ctypes.c_int64
-            lib.da_assemble_run.argtypes = [
-                _U8P,                    # arena
-                _I64P, _I64P,            # head_off, head_len
-                _I64P, _I64P,            # tail_off, tail_len
-                _I64P, _I64P,            # body idx, pid (-1 = no pid)
-                ctypes.c_int64,          # n deliveries
-                _U8P,                    # out
-            ]
-            lib.da_assemble_window.restype = ctypes.c_int64
-            lib.da_assemble_window.argtypes = [
-                _U8P,                    # arena
-                _I64P, _I64P,            # head_off, head_len
-                _I64P, _I64P,            # tail_off, tail_len
-                _I64P, _I64P,            # body idx, pid (-1 = no pid)
-                _I64P, _I64P,            # run_start, run_out_off
-                ctypes.c_int64,          # n runs
-                ctypes.c_int64,          # n deliveries total
-                _U8P,                    # out
-            ]
-            _lib = lib
-        except Exception:
-            logging.getLogger("emqx_tpu.ops").exception(
-                "native dispatchasm build failed; "
-                "using the per-delivery Python loop"
-            )
-            _lib_failed = True
-        return _lib
+    return nativelib.load("dispatchasm", _bind)
 
 
 def assemble_run(lib, views, body, pid_ptr, n: int,

@@ -13,30 +13,20 @@ and an errno through `on_failed`.  The order rule, the back-pressure
 and the close are `Connection`'s (broker/connection.py); the native
 side's are at the top of the C++ source.
 
-Same load contract as the other native libraries (built on demand from
-the committed source, absent or unbuildable -> `load` returns None and
-every connection keeps the transport path), with no switch: which
-connections take the sender is decided from what their socket is."""
+Where the library is absent or unbuildable (``ops/nativelib.py``)
+`load` returns None and every connection keeps the transport path;
+which connections take the sender is decided from what their socket
+is."""
 
 from __future__ import annotations
 
 import ctypes
 import logging
 import os
-import subprocess
-import threading
 import time
 from typing import Dict, List, Optional
 
-_REPO = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-_SRC = os.path.join(_REPO, "native", "sockwriter.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libsockwriter.so")
-
-_lock = threading.Lock()
-_lib = None
-_lib_failed = False
+from . import nativelib
 
 _I32P = ctypes.POINTER(ctypes.c_int32)
 _I64P = ctypes.POINTER(ctypes.c_int64)
@@ -44,54 +34,28 @@ _I64P = ctypes.POINTER(ctypes.c_int64)
 log = logging.getLogger("emqx_tpu.ops")
 
 
+def _bind(lib) -> None:
+    vp, i32, i64 = ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64
+    for name, res, args in (
+        ("sw_create", vp, []),
+        ("sw_event_fd", ctypes.c_int, [vp]),
+        ("sw_open", i32, [vp, ctypes.c_int]),
+        ("sw_close", None, [vp, i32]),
+        ("sw_submit", i64, [vp, i64, _I32P,
+                            ctypes.POINTER(ctypes.c_char_p), _I64P]),
+        ("sw_pending", i64, [vp, i32]),
+        ("sw_poll", i64, [vp, _I32P, _I32P, _I64P, i64]),
+        ("sw_take", i64, [vp, i32, ctypes.c_char_p, i64]),
+        ("sw_unpark", None, [vp, i32]),
+        ("sw_stats", None, [vp, _I64P]),
+        ("sw_stop", None, [vp]),
+    ):
+        fn = getattr(lib, name)
+        fn.restype, fn.argtypes = res, args
+
+
 def load():
-    global _lib, _lib_failed
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        try:
-            if not os.path.exists(_SO) or os.path.getmtime(
-                _SO
-            ) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_SO), exist_ok=True)
-                # link under a private name and rename into place:
-                # test workers load the library side by side.  Built
-                # on first load only (see ops/dispatchasm.py)
-                tmp = f"{_SO}.{os.getpid()}"
-                # brokerlint: ignore[ASYNC101]
-                subprocess.run(
-                    ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-                     "-Wall", "-pthread", "-o", tmp, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-                os.replace(tmp, _SO)
-            lib = ctypes.CDLL(_SO)
-            vp, i32, i64 = ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64
-            for name, res, args in (
-                ("sw_create", vp, []),
-                ("sw_event_fd", ctypes.c_int, [vp]),
-                ("sw_open", i32, [vp, ctypes.c_int]),
-                ("sw_close", None, [vp, i32]),
-                ("sw_submit", i64, [vp, i64, _I32P,
-                                    ctypes.POINTER(ctypes.c_char_p), _I64P]),
-                ("sw_pending", i64, [vp, i32]),
-                ("sw_poll", i64, [vp, _I32P, _I32P, _I64P, i64]),
-                ("sw_take", i64, [vp, i32, ctypes.c_char_p, i64]),
-                ("sw_unpark", None, [vp, i32]),
-                ("sw_stats", None, [vp, _I64P]),
-                ("sw_stop", None, [vp]),
-            ):
-                fn = getattr(lib, name)
-                fn.restype, fn.argtypes = res, args
-            _lib = lib
-        except Exception:
-            log.exception(
-                "native sockwriter build failed; "
-                "socket writes stay on the event loop's transports"
-            )
-            _lib_failed = True
-        return _lib
+    return nativelib.load("sockwriter", _bind)
 
 
 class SockSender:

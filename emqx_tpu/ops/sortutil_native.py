@@ -6,64 +6,25 @@ sorts hold the GIL)."""
 from __future__ import annotations
 
 import ctypes
-import logging
-import os
-import subprocess
-import threading
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
-_REPO = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-_SRC = os.path.join(_REPO, "native", "sortutil.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libsortutil.so")
-
-_lock = threading.Lock()
-_lib = None
-_lib_failed = False
+from . import nativelib
 
 _I64P = ctypes.POINTER(ctypes.c_int64)
 
 
+def _bind(lib) -> None:
+    lib.su_argsort_i64.argtypes = [_I64P, ctypes.c_int64, _I64P]
+    lib.su_unique_inverse_i64.restype = ctypes.c_int64
+    lib.su_unique_inverse_i64.argtypes = [
+        _I64P, ctypes.c_int64, _I64P, _I64P, _I64P,
+    ]
+
+
 def load():
-    global _lib, _lib_failed
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        if os.environ.get("EMQX_TPU_NO_NATIVE_SORT") == "1":
-            _lib_failed = True
-            return None
-        try:
-            if not os.path.exists(_SO) or os.path.getmtime(
-                _SO
-            ) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_SO), exist_ok=True)
-                # built from the committed source on first load in a fresh
-                # checkout (native/build/ is not committed) and again when
-                # the source is newer — never on the steady-state path, so
-                # the loop stall is accepted
-                # brokerlint: ignore[ASYNC101]
-                subprocess.run(
-                    ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-                     "-Wall", "-o", _SO, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-            lib = ctypes.CDLL(_SO)
-            lib.su_argsort_i64.argtypes = [_I64P, ctypes.c_int64, _I64P]
-            lib.su_unique_inverse_i64.restype = ctypes.c_int64
-            lib.su_unique_inverse_i64.argtypes = [
-                _I64P, ctypes.c_int64, _I64P, _I64P, _I64P,
-            ]
-            _lib = lib
-        except Exception:
-            logging.getLogger("emqx_tpu.ops").exception(
-                "native sortutil build failed; using numpy sorts"
-            )
-            _lib_failed = True
-        return _lib
+    return nativelib.load("sortutil", _bind)
 
 
 def _p(a: np.ndarray) -> "ctypes.POINTER":

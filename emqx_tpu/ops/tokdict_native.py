@@ -12,100 +12,61 @@ lookup path for per-topic encodes)."""
 from __future__ import annotations
 
 import ctypes
-import logging
-import os
-import subprocess
-import threading
 
 import numpy as np
 
-_REPO = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-_SRC = os.path.join(_REPO, "native", "tokdict.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libtokdict.so")
+from . import nativelib
 
-_lock = threading.Lock()
-_lib = None
-_lib_failed = False
+
+def _bind(lib) -> None:
+    lib.td_new.restype = ctypes.c_void_p
+    lib.td_free.argtypes = [ctypes.c_void_p]
+    lib.td_len.restype = ctypes.c_int64
+    lib.td_len.argtypes = [ctypes.c_void_p]
+    lib.td_add.restype = ctypes.c_int32
+    lib.td_add.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
+    ]
+    lib.td_get.restype = ctypes.c_int32
+    lib.td_get.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
+    ]
+    lib.td_seed.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+    ]
+    lib.td_encode_topics_into.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_uint8),
+    ]
+    lib.td_encode_filters.restype = ctypes.c_int64
+    lib.td_encode_filters.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_uint8),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64),
+    ]
 
 
 def load():
-    global _lib, _lib_failed
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        if os.environ.get("EMQX_TPU_NO_NATIVE_TOKDICT") == "1":
-            _lib_failed = True
-            return None
-        try:
-            if not os.path.exists(_SO) or os.path.getmtime(
-                _SO
-            ) < os.path.getmtime(_SRC):
-                os.makedirs(os.path.dirname(_SO), exist_ok=True)
-                # built from the committed source on first load in a fresh
-                # checkout (native/build/ is not committed) and again when
-                # the source is newer — never on the steady-state path, so
-                # the loop stall is accepted
-                # brokerlint: ignore[ASYNC101]
-                subprocess.run(
-                    ["g++", "-O3", "-fPIC", "-shared", "-std=c++17",
-                     "-Wall", "-o", _SO, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-            lib = ctypes.CDLL(_SO)
-            lib.td_new.restype = ctypes.c_void_p
-            lib.td_free.argtypes = [ctypes.c_void_p]
-            lib.td_len.restype = ctypes.c_int64
-            lib.td_len.argtypes = [ctypes.c_void_p]
-            lib.td_add.restype = ctypes.c_int32
-            lib.td_add.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
-            ]
-            lib.td_get.restype = ctypes.c_int32
-            lib.td_get.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
-            ]
-            lib.td_seed.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-            ]
-            lib.td_encode_topics_into.argtypes = [
-                ctypes.c_void_p,
-                ctypes.c_char_p,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int64,
-                ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_uint8),
-            ]
-            lib.td_encode_filters.restype = ctypes.c_int64
-            lib.td_encode_filters.argtypes = [
-                ctypes.c_void_p,
-                ctypes.c_char_p,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int64,
-                ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_uint8),
-                ctypes.POINTER(ctypes.c_int32),
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64),
-            ]
-            _lib = lib
-        except Exception:
-            logging.getLogger("emqx_tpu.ops").exception(
-                "native tokdict build failed; using the Python encoder"
-            )
-            _lib_failed = True
-        return _lib
+    return nativelib.load("tokdict", _bind)
 
 
 def _ptr(arr, ctype):

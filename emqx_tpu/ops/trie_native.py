@@ -16,93 +16,53 @@ tests/test_trie_host.py).
 from __future__ import annotations
 
 import ctypes
-import logging
-import os
-import subprocess
-import threading
 from typing import Dict, Hashable, Iterator, List, Tuple
 
 import numpy as np
 
 from .. import topic as T
-
-_REPO = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
-_SRC = os.path.join(_REPO, "native", "hosttrie.cpp")
-_SO = os.path.join(_REPO, "native", "build", "libhosttrie.so")
-
-_lock = threading.Lock()
-_lib = None
-_lib_failed = False
+from . import nativelib
 
 
-def _build() -> None:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    # built from the committed source on first load in a fresh
-    # checkout (native/build/ is not committed) and again when
-    # the source is newer — never on the steady-state path, so
-    # the loop stall is accepted
-    # brokerlint: ignore[ASYNC101]
-    subprocess.run(
-        ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-Wall", "-o", _SO, _SRC],
-        check=True,
-        capture_output=True,
-    )
+def _bind(lib) -> None:
+    lib.ht_new.restype = ctypes.c_void_p
+    lib.ht_free.argtypes = [ctypes.c_void_p]
+    lib.ht_len.restype = ctypes.c_int64
+    lib.ht_len.argtypes = [ctypes.c_void_p]
+    lib.ht_insert.restype = ctypes.c_int64
+    lib.ht_insert.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.c_int64,
+    ]
+    lib.ht_seq.restype = ctypes.c_int64
+    lib.ht_seq.argtypes = [ctypes.c_void_p]
+    _i64p = ctypes.POINTER(ctypes.c_int64)
+    lib.ht_insert_batch.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p,
+        _i64p, _i64p, _i64p, ctypes.c_int64, _i64p,
+    ]
+    lib.ht_match_since.restype = ctypes.c_int64
+    lib.ht_match_since.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+    ]
+    lib.ht_delete.restype = ctypes.c_int32
+    lib.ht_delete.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    lib.ht_match.restype = ctypes.c_int64
+    lib.ht_match.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64,
+    ]
 
 
 def load():
-    global _lib, _lib_failed
-    with _lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        try:
-            if not os.path.exists(_SO) or os.path.getmtime(
-                _SO
-            ) < os.path.getmtime(_SRC):
-                _build()
-            lib = ctypes.CDLL(_SO)
-            lib.ht_new.restype = ctypes.c_void_p
-            lib.ht_free.argtypes = [ctypes.c_void_p]
-            lib.ht_len.restype = ctypes.c_int64
-            lib.ht_len.argtypes = [ctypes.c_void_p]
-            lib.ht_insert.restype = ctypes.c_int64
-            lib.ht_insert.argtypes = [
-                ctypes.c_void_p,
-                ctypes.c_char_p,
-                ctypes.c_int64,
-            ]
-            lib.ht_seq.restype = ctypes.c_int64
-            lib.ht_seq.argtypes = [ctypes.c_void_p]
-            _i64p = ctypes.POINTER(ctypes.c_int64)
-            lib.ht_insert_batch.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p,
-                _i64p, _i64p, _i64p, ctypes.c_int64, _i64p,
-            ]
-            lib.ht_match_since.restype = ctypes.c_int64
-            lib.ht_match_since.argtypes = [
-                ctypes.c_void_p,
-                ctypes.c_char_p,
-                ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int64,
-            ]
-            lib.ht_delete.restype = ctypes.c_int32
-            lib.ht_delete.argtypes = [ctypes.c_void_p, ctypes.c_int64]
-            lib.ht_match.restype = ctypes.c_int64
-            lib.ht_match.argtypes = [
-                ctypes.c_void_p,
-                ctypes.c_char_p,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int64,
-            ]
-            _lib = lib
-        except Exception:
-            logging.getLogger("emqx_tpu.ops").exception(
-                "native hosttrie build failed; using the Python trie"
-            )
-            _lib_failed = True
-        return _lib
+    return nativelib.load("hosttrie", _bind)
 
 
 class NativeTrie:
@@ -265,7 +225,7 @@ class NativeTrie:
 
 def make_trie():
     """NativeTrie when buildable, else the Python HostTrie."""
-    if os.environ.get("EMQX_TPU_NO_NATIVE_TRIE") == "1" or load() is None:
+    if load() is None:
         from .trie_host import HostTrie
 
         return HostTrie()

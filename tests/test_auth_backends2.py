@@ -354,13 +354,16 @@ def test_env_overrides_and_boot_check():
         apply_env_overrides(BrokerConfig(),
                             {"EMQX_TPU_MQTT__NO_SUCH_KEY": "1"})
 
-    # the native-lib kill switches share the prefix but are runtime
-    # flags, not config paths: a worker booted with one must not die
-    applied = apply_env_overrides(BrokerConfig(), {
-        "EMQX_TPU_NO_NATIVE_DISPATCH": "1",
-        "EMQX_TPU_NO_NATIVE_SORT": "1",
-    })
-    assert applied == []
+    # EMQX_TPU_NO_DECIDE shares the prefix but is a runtime flag, not
+    # a config path: a worker booted with it must not die.  The native
+    # libraries' kill switches are gone (PR 28), so their names are
+    # unknown paths like any other
+    assert apply_env_overrides(
+        BrokerConfig(), {"EMQX_TPU_NO_DECIDE": "1"}
+    ) == []
+    with pytest.raises(ValueError):
+        apply_env_overrides(BrokerConfig(),
+                            {"EMQX_TPU_NO_" "NATIVE_DISPATCH": "1"})
 
     assert check_config(BrokerConfig()) == []
     bad = BrokerConfig()

@@ -161,6 +161,14 @@ def test_broker_survives_total_device_failure_qos1():
         mon = TestClient(port, "mon")
         await mon.connect()
         await mon.subscribe("$SYS/brokers/+/alarms/#")
+
+        async def recv_alarm():
+            # the next $SYS message of THIS alarm: on a busy machine
+            # os_mon's high_cpu alarm comes and goes on the same topics
+            while True:
+                pkt = await mon.recv_publish(timeout=5)
+                if json.loads(pkt.payload)["name"] == "engine_device_path":
+                    return pkt
         sub = TestClient(port, "sub")
         await sub.connect()
         await sub.subscribe("chaos/+/q", qos=1)
@@ -188,10 +196,8 @@ def test_broker_survives_total_device_failure_qos1():
         ):
             assert asyncio.get_event_loop().time() < deadline
             await asyncio.sleep(0.02)
-        alarm_pkt = await mon.recv_publish(timeout=5)
+        alarm_pkt = await recv_alarm()
         assert alarm_pkt.topic.endswith("/alarms/activate")
-        assert json.loads(alarm_pkt.payload)["name"] == \
-            "engine_device_path"
         assert broker.metrics.val("engine.breaker.trip") == 1
 
         # fault clears: probe re-closes, alarm deactivates, traffic
@@ -203,7 +209,7 @@ def test_broker_survives_total_device_failure_qos1():
         while eng.breaker_info()["open"]:
             assert asyncio.get_event_loop().time() < deadline
             await asyncio.sleep(0.02)
-        clear_pkt = await mon.recv_publish(timeout=5)
+        clear_pkt = await recv_alarm()
         assert clear_pkt.topic.endswith("/alarms/deactivate")
         assert not any(
             a.name == "engine_device_path"

@@ -185,10 +185,17 @@ def test_raft_rpc_drop_forces_timeout_retry_path():
                 nodes[0].update_config_async("mqtt.max_awaiting_rel", 55),
                 timeout=15.0,
             )
-            await asyncio.sleep(0.5)
             assert [p for p in fp.list_points()][0]["fires"] >= 1
-            for n in nodes:
-                assert n.broker.config.mqtt.max_awaiting_rel == 55
+            # the proposal is committed once a majority has it; the
+            # last follower applies it when its retried RPC lands
+            deadline = asyncio.get_running_loop().time() + 15.0
+            while any(
+                n.broker.config.mqtt.max_awaiting_rel != 55 for n in nodes
+            ):
+                assert asyncio.get_running_loop().time() < deadline, [
+                    n.broker.config.mqtt.max_awaiting_rel for n in nodes
+                ]
+                await asyncio.sleep(0.02)
         finally:
             await shutdown(servers, nodes)
 

@@ -22,7 +22,7 @@ from emqx_tpu.broker.session import Session, SubOpts
 from emqx_tpu.codec import mqtt as C
 from emqx_tpu.config import BrokerConfig
 from emqx_tpu.message import Message
-from emqx_tpu.ops import dispatchasm
+from emqx_tpu.ops import dispatchasm, nativelib
 
 
 def _broker():
@@ -49,8 +49,7 @@ class WireChannel(Channel):
 
 def _force_fallback(monkeypatch):
     """Make ops.dispatchasm.load() return None (missing-.so shape)."""
-    monkeypatch.setattr(dispatchasm, "_lib", None)
-    monkeypatch.setattr(dispatchasm, "_lib_failed", True)
+    monkeypatch.setitem(nativelib._libs, "dispatchasm", None)
 
 
 _native = dispatchasm.load()
@@ -220,13 +219,6 @@ def test_missing_so_full_fallback(monkeypatch):
     assert len(ch.writes) == 1  # still ONE corked write per window
     parser = C.StreamParser(version=C.MQTT_V5)
     assert [p.packet_id for p in parser.feed(ch.writes[0])] == [1, 2, 3, 4]
-
-
-def test_no_native_env_var_disables(monkeypatch):
-    monkeypatch.setattr(dispatchasm, "_lib", None)
-    monkeypatch.setattr(dispatchasm, "_lib_failed", False)
-    monkeypatch.setenv("EMQX_TPU_NO_NATIVE_DISPATCH", "1")
-    assert dispatchasm.load() is None
 
 
 # ------------------------------------------- block session bookkeeping

@@ -309,7 +309,7 @@ def test_delta_automaton_churn_equivalence(seed):
         t = engine._fold_thread
         if t is not None and t.is_alive():
             t.join(60)
-        built_delta = built_delta or engine._daut is not None
+        built_delta = built_delta or engine._dtier[0] is not None
         if round_ == 0:
             engine.rebuild()  # establish a base; later rounds churn
         if round_ == 3:
@@ -326,7 +326,7 @@ def test_delta_automaton_churn_equivalence(seed):
     assert built_delta  # the two-tier path was actually exercised
     # a big rebuild folds everything and drops the delta tier
     engine.rebuild()
-    assert engine._daut is None
+    assert engine._dtier[0] is None
     topics = [random_topic(rng) for _ in range(60)]
     check_engine_vs_oracle(engine, oracle, exact, topics)
 
@@ -343,14 +343,14 @@ def test_delta_fold_residual_bound():
     for i in range(4000):
         engine.insert(f"churn/{i % 97}/+/x{i}", i)
         assert engine._residual_count <= max(64, len(engine._delta) // 2), i
-        if engine._daut is not None:
+        if engine._dtier[0] is not None:
             shapes.add(
                 (
-                    engine._daut.node_rows.shape,
-                    engine._daut.kernel_levels,
+                    engine._dtier[0].node_rows.shape,
+                    engine._dtier[0].kernel_levels,
                 )
             )
-    assert engine._daut is not None
+    assert engine._dtier[0] is not None
     assert len(engine._daut_fids) + engine._residual_count >= 4000 - 64
     # pow2 node-capacity classes bound the traced-shape set
     assert len(shapes) <= 4
@@ -405,7 +405,7 @@ def test_async_fold_churn_equivalence():
     drain_folds(engine, timeout=20)
     topics = [random_topic(rng) for _ in range(200)]
     check_engine_vs_oracle(engine, oracle, {}, topics)
-    assert engine._daut is not None  # async folds actually ran
+    assert engine._dtier[0] is not None  # async folds actually ran
 
 
 def test_reinserted_fid_survives_fold():
@@ -426,7 +426,7 @@ def test_reinserted_fid_survives_fold():
     # force folds until fid 7 lives in the delta automaton
     for i in range(100, 140):
         engine.insert(f"churn/{i}/+", i)
-    assert engine._daut is not None and 7 in engine._daut_fids
+    assert engine._dtier[0] is not None and 7 in engine._daut_fids
     assert engine.match("moved/here/x") == {7}  # the r3 review regression
     assert 7 not in engine.match("seed/7/q")
     # and a deleted fid stays deleted across the fold

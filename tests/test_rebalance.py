@@ -378,7 +378,10 @@ def test_evacuated_client_migrates_to_peer():
         )
         await c.subscribe("m/#", qos=1)
         await srv_a.broker.eviction.start_evacuation(conn_evict_rate=100)
-        await asyncio.sleep(0.3)
+        for _ in range(300):  # up to 15 s: a limit, not a pace
+            if not srv_a.broker.cm.connected("mover"):
+                break
+            await asyncio.sleep(0.05)
         assert not srv_a.broker.cm.connected("mover")
 
         # the client follows USE_ANOTHER_SERVER to node B: takeover

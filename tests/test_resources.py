@@ -80,12 +80,14 @@ def test_rule_to_http_sink_with_failures():
         await pub.disconnect()
 
         # the first 3 POSTs fail; retries must deliver ALL 5 in order
+        # (the server holds a body before the worker has read its
+        # answer and counted the success: wait for both)
+        worker = broker.resources.get("wh1")
         for _ in range(200):
-            if len(http.bodies) == 5:
+            if len(http.bodies) == 5 and worker.stats["success"] == 5:
                 break
             await asyncio.sleep(0.02)
         assert [json.loads(b)["v"] for b in http.bodies] == [1, 2, 3, 4, 5]
-        worker = broker.resources.get("wh1")
         assert worker.stats["success"] == 5
         assert worker.stats["retried"] >= 3
         assert worker.stats["dropped"] == 0

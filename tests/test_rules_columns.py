@@ -370,7 +370,7 @@ def test_server_start_warms_and_later_folds_follow():
             while eng.index_stats()["folding"]:
                 await asyncio.sleep(0.01)
             assert eng.index_stats()["folded"] == eng.delta_aut_threshold
-            assert buckets_of(eng._daut) == {16, 32, 64}
+            assert buckets_of(eng._dtier[0]) == {16, 32, 64}
             warmed = match_batch_compact._cache_size()
             got = eng.match_batch(
                 [f"a/{i}/x" for i in range(40)] + ["b/7/y"]

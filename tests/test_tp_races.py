@@ -5,6 +5,7 @@ instead of hoping a wall-clock stress test hits the interleaving."""
 
 import random
 import threading
+import time
 
 from emqx_tpu import topic as T
 from emqx_tpu import tp
@@ -140,10 +141,59 @@ def test_base_swap_discards_inflight_fold():
     oracle_check(eng, oracle, topics)
 
 
+def rebuild_under_a_committing_fold(eng, oracle):
+    """``eng.rebuild()`` arrives while a fold thread sits inside its
+    commit, past the generation check and before its stores (it holds
+    ``_mlock`` there).  `build_engine` reaches this by chance — seed
+    folds are still in flight when it calls ``rebuild()`` — and an
+    unlocked ``rebuild()`` then cleared the delta tier between the
+    fold's stores: a snapshot with an automaton and no fid array (the
+    TypeError in `_overlay`), or a stale fold adopted over the new
+    base.  Here the interleaving is pinned, every run."""
+    drain_folds(eng)
+    with tp.collect() as trace, tp.force_ordering(
+        after="released_by_hand", block="fold_commit"
+    ) as commit_gate, tp.force_ordering(
+        after="released_by_hand", block="fold_adopt"
+    ) as adopt_gate:
+        # the fold may not take _mlock while this thread still inserts
+        for round_ in range(50):
+            if tp.events_of(trace, "fold_capture"):
+                break
+            churn(eng, oracle, 9000 + round_ * 100, 100)
+        else:
+            raise AssertionError("fold never captured")
+        adopt_gate.set()
+        assert wait_for(lambda: tp.events_of(trace, "fold_commit"), 15.0)
+        r = threading.Thread(target=eng.rebuild)
+        r.start()
+        # a rebuild that does not wait for the lock drops the tier
+        # now, under the fold's feet
+        dropped = wait_for(lambda: tp.events_of(trace, "daut_drop"), 1.5)
+        commit_gate.set()
+        r.join(15.0)
+        assert not r.is_alive()
+        drain_folds(eng)
+    assert not dropped, "rebuild() swapped state inside a fold's commit"
+    tp.assert_order(trace, "fold_commit", "daut_drop")
+    assert eng._dtier == (None, None, None)
+
+
+def wait_for(cond, timeout):
+    """Whether ``cond()`` came to hold within ``timeout`` seconds."""
+    deadline = time.monotonic() + timeout
+    while not cond():
+        if time.monotonic() > deadline:
+            return False
+        time.sleep(0.005)
+    return True
+
+
 def test_fold_failure_injection_keeps_matching():
     """An injected crash in the fold thread must leave matching on the
     residual overlay, oracle-equal, and a later fold recovers."""
     eng, oracle = build_engine()
+    rebuild_under_a_committing_fold(eng, oracle)
     topics = [f"churn/{i % 97}/x/y" for i in range(60)]
     with tp.collect() as trace:
         with tp.inject("fold_assemble_done", RuntimeError("injected")):
@@ -153,5 +203,5 @@ def test_fold_failure_injection_keeps_matching():
         # next fold (no injection) recovers the device tier
         churn(eng, oracle, 5000, 200)
         drain_folds(eng)
-    assert eng._daut is not None
+    assert eng._dtier[0] is not None
     oracle_check(eng, oracle, topics)

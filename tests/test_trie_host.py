@@ -199,17 +199,18 @@ def test_native_trie_large_matchset_grows_buffer():
 
 def test_make_trie_python_fallback(monkeypatch):
     """The Python HostTrie serves when the native lib is unavailable
-    (kill switch or failed build) — the fallback path must survive
-    the C++17 rewrite making the native trie available everywhere."""
-    from emqx_tpu.ops import trie_native
+    (a failed build or load) — the fallback path must survive the
+    C++17 rewrite making the native trie available everywhere."""
+    from emqx_tpu.ops import nativelib, trie_native
     from emqx_tpu.ops.trie_host import HostTrie
 
-    monkeypatch.setenv("EMQX_TPU_NO_NATIVE_TRIE", "1")
-    t = trie_native.make_trie()
-    assert isinstance(t, HostTrie)
-    t.insert("a/+/c", "f1")
-    t.insert("a/#", "f2")
-    assert t.match("a/b/c") == {"f1", "f2"}
-    monkeypatch.delenv("EMQX_TPU_NO_NATIVE_TRIE")
-    if trie_native.load() is not None:
+    native = trie_native.load()
+    with monkeypatch.context() as m:
+        m.setitem(nativelib._libs, "hosttrie", None)
+        t = trie_native.make_trie()
+        assert isinstance(t, HostTrie)
+        t.insert("a/+/c", "f1")
+        t.insert("a/#", "f2")
+        assert t.match("a/b/c") == {"f1", "f2"}
+    if native is not None:
         assert not isinstance(trie_native.make_trie(), HostTrie)
