@@ -1302,7 +1302,7 @@ def run_rules_bench(log, iters=None, write_json=True):
                 hits += len(passed)
                 for i in passed:
                     selected = eval_select(rule.parsed, env(i))
-                    eng._run_actions(rule, selected, msgs[i])
+                    eng._run_firings(rule, [(selected, msgs[i])])
             if eng.broker is not None and hits:
                 eng.broker.metrics.inc("rules.matched", hits)
             return hits

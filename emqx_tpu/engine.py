@@ -535,8 +535,18 @@ class MatchEngine:
         self._probe_last = time.monotonic()
         self._probe_running = False
         # compact-transfer capacity multiplier (x unique topics in the
-        # window); doubles whenever the buffer clips, never shrinks
-        self._ccap_mult = 2
+        # window); doubles whenever the buffer clips, never shrinks.
+        # A step leaves the compact kernel cold at every batch bucket
+        # (c_cap is part of its shape) until a window of that bucket
+        # comes and compiles it in its own latency, so the ladder
+        # starts where a window of full-width unique topics with a
+        # fan-in of 8 fits: `warmup()` compiles that rung at every
+        # bucket, and a fleet whose topics match two or three filters
+        # each never leaves it (it climbed 2 -> 4 at a moment the
+        # traffic chose, and the next wide window paid 0.3-0.9 s).
+        # 32 B a padded row on the device->host link, against 512 B
+        # for the dense layout
+        self._ccap_mult = 8
         # (nodes, buckets, levels, batch) classes already shape-warmed
         self._warmed_shapes: Set[Tuple[int, int, int, int]] = set()
         # widest batch bucket the background fold/build threads warm a
@@ -2076,10 +2086,7 @@ class MatchEngine:
         # cannot produce float64-correct results for these windows
         if stack.has_arith or not stack.f32_lits_safe:
             return False
-        # only the WHERE planes reach the device kernel (they are a
-        # prefix of the combined WHERE+SELECT path union); SELECT-only
-        # columns stay on the float64 numpy materialization
-        return cols.f32_safe(len(stack.paths))
+        return cols.f32_safe()
 
     def _rules_device(self, stack, rev: int, cols, info=None) -> np.ndarray:
         """One device rules step: upload the stacked program (cached
@@ -2091,13 +2098,9 @@ class MatchEngine:
             # chaos seam: an injected error degrades this window to
             # the host twin and feeds the shared device breaker
             failpoints.evaluate("dispatch.rules.device")
-        # only the WHERE planes reach the kernel: they are a prefix of
-        # the combined WHERE+SELECT path union, whose length moves
-        # with the SELECT cost gate and must not move the shape
-        n_p = len(stack.paths)
         return self._rules_run(
-            stack, rev, cols.n, cols.lit_ranks, cols.num[:n_p],
-            cols.sid[:n_p], cols.err[:n_p], cols.prs[:n_p], info,
+            stack, rev, cols.n, cols.lit_ranks, cols.num, cols.sid,
+            cols.err, cols.prs, info,
         )
 
     def _rules_run(

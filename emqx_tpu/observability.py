@@ -282,6 +282,7 @@ class WindowRecord(Laps):
         "wall0", "n_msgs", "n_deliveries", "n_clients", "n_clips",
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
         "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
+        "rules_firings", "rules_firings_run",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -295,6 +296,11 @@ class WindowRecord(Laps):
         # bucket it ran them in (both 0 where the host decided)
         self.decide_rows = 0
         self.decide_rows_padded = 0
+        # rows that passed a WHERE on a rule with actions, and those
+        # of them that a per-rule SELECT-and-actions run served (the
+        # rest went firing by firing through the interpreter)
+        self.rules_firings = 0
+        self.rules_firings_run = 0
         self.path = ""  # "host" | "dev" | "host-fallback"
         self.breaker_open = False
         self.source = source  # "publish" | "batcher" | "forwarded"
@@ -365,6 +371,8 @@ class WindowRecord(Laps):
             "n_clips": self.n_clips,
             "decide_rows": self.decide_rows,
             "decide_rows_padded": self.decide_rows_padded,
+            "rules_firings": self.rules_firings,
+            "rules_firings_run": self.rules_firings_run,
             "path": self.path,
             "breaker_open": self.breaker_open,
             "stages_us": {

@@ -1,7 +1,7 @@
 """Window column extraction for the stacked rule-matrix program.
 
 `WindowColumns` decodes each window message ONCE into parallel numpy
-planes over the union of var paths the registry's lowerable rules
+planes over the union of var paths the lowerable rules' WHERE clauses
 reference (predicate.StackedRules.paths): a float64 numeric lane, a
 per-window RANK-interned string lane, a lookup-error lane and a
 presence lane per path.  `ops.match_kernel.rules_eval_host` /
@@ -24,11 +24,13 @@ under a NUL-prefixed namespace (NUL cannot occur in MQTT UTF-8
 strings), so ``payload.a = payload.b`` over equal objects matches the
 interpreter's term equality.
 
-The per-message env dicts are `runtime.LazyEnv`: the extractor, any
-per-RULE interpreter fallbacks, and the SELECT evaluation of passing
-rules all share one env per message — and its `_PayloadStr` caches
-the JSON decode, which is what makes "decode once per window" hold
-across all three consumers.
+The planes cover the WHERE stack's paths and nothing else: a lowered
+SELECT reads its values for the rows that fired, from the messages
+(`select.materialize_rows`), never from a plane.  Both go through one
+`runtime.WindowEnvs`, which holds each message's JSON decode once a
+window; the extractor builds no `LazyEnv` for a path it can read from
+the message or the decode, so a message that only the matrix reads
+leaves none behind.
 """
 
 from __future__ import annotations
@@ -38,7 +40,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..message import Message
-from .runtime import LazyEnv, _PayloadStr, lookup_var
+from .runtime import (
+    LOOKUP_ERROR, WindowEnvs, _env_field, lookup_var, read_of, walk,
+)
 
 # reserved string-lane ids: bools are equality-comparable but must
 # never participate in rank (string) ordering
@@ -75,11 +79,11 @@ def _canon(v: Any) -> str:
 
 class WindowColumns:
     """One window's shared column planes: ``num``/``sid``/``err``/
-    ``prs`` are ``[P, W]`` over the registry's path union."""
+    ``prs`` are ``[P, W]`` over the WHERE stack's paths."""
 
     __slots__ = (
         "n", "paths", "num", "sid", "err", "prs", "lit_ranks",
-        "envs", "n_strings", "has_nan_value", "vals",
+        "n_strings", "has_nan_value",
     )
 
     def __init__(
@@ -87,104 +91,75 @@ class WindowColumns:
         msgs: Sequence[Message],
         paths: Sequence[Tuple[str, ...]],
         lit_strings: Sequence[str],
-        envs: Optional[List[Optional[LazyEnv]]] = None,
-        keep_values: bool = False,
+        envs: Optional[WindowEnvs] = None,
     ) -> None:
         n = len(msgs)
         n_paths = len(paths)
         self.n = n
         self.paths = tuple(paths)
-        self.num = np.full((n_paths, n), np.nan, np.float64)
-        self.sid = np.full((n_paths, n), SID_NONE, np.int32)
-        self.err = np.zeros((n_paths, n), bool)
-        self.prs = np.zeros((n_paths, n), bool)
-        # ``keep_values``: also keep each cell's RAW extracted value
-        # (the batched SELECT transform's input — int-ness and nested
-        # objects survive, which the f64/rank planes erase).  None
-        # covers both "missing" and "error" cells; the err lane
-        # disambiguates where it matters (expression operands).
-        self.vals: Optional[List[List[Any]]] = (
-            [[None] * n for _ in range(n_paths)] if keep_values
-            else None
-        )
         if envs is None:
-            envs = [None] * n
-        self.envs = envs
+            envs = WindowEnvs(msgs)
         self.has_nan_value = False
-        num, sid, err, prs = self.num, self.sid, self.err, self.prs
-        vals = self.vals
+        # one Python list a plane, one array build each at the end
+        # (a numpy scalar store a cell costs three list stores)
+        nan = float("nan")
+        num = [[nan] * n for _ in range(n_paths)]
+        sid = [[SID_NONE] * n for _ in range(n_paths)]
+        err = [[False] * n for _ in range(n_paths)]
+        prs = [[False] * n for _ in range(n_paths)]
         # (plane, msg, string, is_term) cells holding a string-interned
         # value, resolved after the scan once the window's full
         # dictionary is known
         pending: List[Tuple[int, int, str, bool]] = []
-        # nested payload paths walk the decoded JSON directly (ONE
-        # decode per message, shared with the lazy envs); everything
-        # else goes through the generic env lookup
-        pay_paths = [
-            (p, paths[p][1:]) for p in range(n_paths)
-            if paths[p][0] == "payload" and len(paths[p]) > 1
-        ]
-        gen_paths = [
-            p for p in range(n_paths)
-            if not (paths[p][0] == "payload" and len(paths[p]) > 1)
-        ]
-        _ERR = object()
+        # where each path is read: below the payload from the window's
+        # one decode a message, a field of the message from the
+        # message, anything else through the generic env lookup
+        by_read: Dict[str, List[Tuple[int, Any]]] = {
+            "json": [], "msg": [], "env": [],
+        }
+        for p, path in enumerate(paths):
+            how, arg = read_of(path)
+            by_read[how].append((p, arg))
+        pay_paths = by_read["json"]
+        msg_paths = by_read["msg"]
+        env_paths = by_read["env"]
 
         def classify(p: int, i: int, v: Any) -> None:
-            if vals is not None and v is not None:
-                # raw-value plane: _PayloadStr flattens to plain str
-                # here, exactly eval_select's output conversion
-                vals[p][i] = str(v) if type(v) is _PayloadStr else v
             if isinstance(v, bool):
-                sid[p, i] = SID_TRUE if v else SID_FALSE
-                prs[p, i] = True
+                sid[p][i] = SID_TRUE if v else SID_FALSE
             elif isinstance(v, (int, float)):
                 if v != v:
                     # a LITERAL NaN payload value (json.loads accepts
                     # NaN) would alias the not-a-number sentinel; the
                     # caller degrades this window to the interpreter
                     self.has_nan_value = True
-                num[p, i] = v
-                prs[p, i] = True
+                num[p][i] = v
             elif isinstance(v, str):
                 pending.append((p, i, str(v), False))
-                prs[p, i] = True
             elif v is not None:
                 # non-scalar term: canonical id, equality-only
                 pending.append((p, i, "\x00j" + _canon(v), True))
-                prs[p, i] = True
+            else:
+                return
+            prs[p][i] = True
 
+        decoded = envs.decoded
         for i in range(n):
-            env = envs[i]
-            if env is None:
-                env = envs[i] = LazyEnv(msgs[i])
             if pay_paths:
-                try:
-                    data = env["payload"].decoded()
-                except Exception:
-                    data = _ERR
+                data = decoded(i)
                 for p, rest in pay_paths:
-                    if data is _ERR:
-                        err[p, i] = True
-                        continue
-                    cur: Any = data
-                    for part in rest:
-                        if isinstance(cur, dict):
-                            if part not in cur:
-                                cur = None
-                                break
-                            cur = cur[part]
-                        else:
-                            err[p, i] = True
-                            cur = _ERR
-                            break
-                    if cur is not _ERR:
-                        classify(p, i, cur)
-            for p in gen_paths:
+                    v = walk(data, rest)
+                    if v is LOOKUP_ERROR:
+                        err[p][i] = True
+                    else:
+                        classify(p, i, v)
+            for p, key in msg_paths:
+                classify(p, i, _env_field(msgs[i], key))
+            for p, path in env_paths:
                 try:
-                    v = lookup_var(env, paths[p])
+                    v = lookup_var(envs.env(i), path)
                 except Exception:
-                    err[p, i] = True
+                    err[p][i] = True
                     continue
                 classify(p, i, v)
         # rank interning: literals seed the dictionary so every
@@ -195,31 +170,22 @@ class WindowColumns:
         rank = {s: r for r, s in enumerate(sorted(strings))}
         self.n_strings = len(rank)
         for p, i, s, term in pending:
-            sid[p, i] = SID_TERM_BASE - rank[s] if term else rank[s]
+            sid[p][i] = SID_TERM_BASE - rank[s] if term else rank[s]
+        self.num = np.array(num, np.float64).reshape(n_paths, n)
+        self.sid = np.array(sid, np.int32).reshape(n_paths, n)
+        self.err = np.array(err, bool).reshape(n_paths, n)
+        self.prs = np.array(prs, bool).reshape(n_paths, n)
         self.lit_ranks = np.fromiter(
             (rank[s] for s in lit_strings), np.int32, len(lit_strings)
         )
 
-    def env(self, i: int) -> LazyEnv:
-        """The shared lazy env for message ``i`` (fallback predicates
-        and SELECT evaluation ride the same decode cache)."""
-        return self.envs[i]
-
-    def f32_safe(self, n_paths: Optional[int] = None) -> bool:
+    def f32_safe(self) -> bool:
         """True when every numeric cell round-trips float32 — the
         device kernel computes in f32 (TPU-native), so a window
         carrying f32-unsafe values (millisecond timestamps are the
         canonical offender) stays on the float64 host twin, exactly
-        the `PredicateProgram._f32_safe` rule.
-
-        ``n_paths`` limits the scan to the first N path planes: the
-        WHERE stack's planes are a PREFIX of the combined WHERE+SELECT
-        path union, and SELECT-only planes (consumed by the float64
-        numpy materialization, never by the device kernel) must not
-        veto the device path — `SELECT timestamp` would otherwise pin
-        every window to host."""
-        a = self.num if n_paths is None else self.num[:n_paths]
-        finite = a[np.isfinite(a)]
+        the `PredicateProgram._f32_safe` rule."""
+        finite = self.num[np.isfinite(self.num)]
         if finite.size == 0:
             return True
         return bool((finite == finite.astype(np.float32)).all())

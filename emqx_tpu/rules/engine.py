@@ -22,17 +22,23 @@ engine's cost EWMAs) — the PAPER.md blueprint's "rule engine's SQL
 predicates compiled into the same batched kernel".  Non-lowerable
 predicates degrade per RULE to the interpreter over the same lazily
 materialized envs, never pushing the window off the matrix path.
+The planes hold what the matrix reads and nothing else: a rule whose
+SELECT lowered reads its values for the rows it fired on and runs its
+actions over them, once a window (`_run_rule_rows`).
 """
 
 from __future__ import annotations
 
+import functools
 import json as _json
 import logging
 import os
 import time
 from collections import Counter
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any, Callable, Dict, Iterable, List, Optional, Tuple,
+)
 
 import numpy as np
 
@@ -41,10 +47,10 @@ from .columns import WindowColumns
 from .predicate import (
     PredicateProgram, StackedRules, build_stack, compile_where,
 )
-from .runtime import LazyEnv, build_env, eval_select, eval_where
+from .runtime import WindowEnvs, build_env, eval_select, eval_where
 from .select import (
-    SelectStack, build_select_stack, compile_template,
-    materialize_rows,
+    SelectProgram, build_select_stack, compile_template,
+    materialize_rows, rows_as_dicts,
 )
 from .sql import ParsedSql, parse_sql
 
@@ -156,7 +162,9 @@ class RuleEngine:
         # every rule's SELECT+actions, "batched" pins the column
         # transform past the cost gate, None auto (EWMA-gated)
         self.select_force: Optional[str] = None
-        self._sel_cache: Optional[Tuple[int, SelectStack]] = None
+        self._sel_cache: Optional[
+            Tuple[int, Dict[str, SelectProgram]]
+        ] = None
         # cost-EWMA gate state (the WHERE matrix idiom): per-row us
         # for each lane, sampled on single-lane windows only; tripping
         # the breaker pins scalar until registry churn
@@ -193,11 +201,10 @@ class RuleEngine:
         self._pos_row = np.zeros(0, np.int64)
         self._pos_of: Dict[str, int] = {}
         self._ids_cache: Dict[Tuple[str, ...], np.ndarray] = {}
-        # per-position batched-egress plan: (SelectProgram, planes)
-        # when the rule's SELECT lowered AND every action is window-
-        # shaped (Sink/Aggregate); None degrades the rule to the
-        # scalar referee loop
-        self._pos_selp: List[Optional[tuple]] = []
+        # per-position lowered SELECT of a rule with actions: its
+        # firings take the per-rule run; None degrades the rule to
+        # the scalar referee loop
+        self._pos_selp: List[Optional[SelectProgram]] = []
 
     # ------------------------------------------------------ registry
 
@@ -224,21 +231,16 @@ class RuleEngine:
             return None
         return self._stacked(), self.rules_rev
 
-    def _select_stack(self, stack: StackedRules) -> SelectStack:
-        """The enabled registry's lowered SELECT programs, sharing the
-        WHERE stack's path union (SELECT-only paths are APPENDED, so
-        the WHERE rows' plane indices survive)."""
+    def _select_progs(self) -> Dict[str, SelectProgram]:
+        """The enabled registry's lowered SELECT programs, by rule."""
         cached = self._sel_cache
         if cached is not None and cached[0] == self.rules_rev:
             return cached[1]
-        sel = build_select_stack(
-            [
-                (rid, r.parsed)
-                for rid, r in self.rules.items()
-                if r.enabled
-            ],
-            stack.paths,
-        )
+        sel = build_select_stack([
+            (rid, r.parsed)
+            for rid, r in self.rules.items()
+            if r.enabled
+        ])
         self._sel_cache = (self.rules_rev, sel)
         return sel
 
@@ -321,7 +323,7 @@ class RuleEngine:
             rule.passed += 1
             hits += 1
             selected = eval_select(rule.parsed, env)
-            self._run_actions(rule, selected, msg)
+            self._run_firings(rule, [(selected, msg)])
         if self.broker is not None and hits:
             self.broker.metrics.inc("rules.matched", hits)
         return hits
@@ -340,22 +342,23 @@ class RuleEngine:
         envs.  Matched/passed/failed counters update once per rule
         and broker metrics flush in one `inc_bulk` pass.
 
+        The planes cover the WHERE stack's paths alone.  A rule
+        whose SELECT lowered reads its values for the rows it fired
+        on, once a window (`_run_rule_rows`), whatever its actions.
+
         ``rec`` (the window's profiler record) takes the sub-stages
         of the ``rules`` lap: ``rules_extract`` (column extraction),
         ``rules_eval`` (the matrix, with the device round trip that
         blocks the loop as ``rules_device_wait`` inside it) and
-        ``rules_actions`` (the actions' loop)."""
+        ``rules_actions`` (the actions' loop); and the window's
+        firings on rules with actions (``rules_firings``), with how
+        many of them a per-rule run served (``rules_firings_run``)."""
         if not items:
             return 0
         msgs = [m for m, _ in items]
         n = len(msgs)
-        envs: List[Optional[LazyEnv]] = [None] * n
-
-        def env(i: int) -> LazyEnv:
-            e = envs[i]
-            if e is None:
-                e = envs[i] = LazyEnv(msgs[i])
-            return e
+        envs = WindowEnvs(msgs)
+        env = envs.env
 
         # flatten the sink to (rule-position, msg) pair columns over
         # the rev-stable position space (see __init__): one flatten-
@@ -366,10 +369,10 @@ class RuleEngine:
             self._matrix_enabled and self.eval_force != "scalar"
         )
         stack: Optional[StackedRules] = None
-        selstack: Optional[SelectStack] = None
+        sel_progs: Dict[str, SelectProgram] = {}
         if use_matrix:
             stack = self._stacked()
-            selstack = self._select_stack(stack)
+            sel_progs = self._select_progs()
         key = (self.rules_rev, use_matrix)
         if self._flat_key != key:
             self._flat_key = key
@@ -391,19 +394,9 @@ class RuleEngine:
                 np.int64, n_all,
             )
             self._ids_cache = {}
-            sel_progs = selstack.progs if selstack is not None else {}
             self._pos_selp = [
-                (
-                    (sel_progs[r.rule_id],
-                     selstack.planes[r.rule_id])
-                    if r.enabled and r.rule_id in sel_progs
-                    and r.actions
-                    and all(
-                        isinstance(a, (SinkAction, AggregateAction))
-                        for a in r.actions
-                    )
-                    else None
-                )
+                sel_progs.get(r.rule_id)
+                if r.enabled and r.actions else None
                 for r in objs
             ]
             # registry churn re-arms the SELECT cost gate
@@ -440,32 +433,10 @@ class RuleEngine:
         if use_matrix:
             known = prow >= 0
             active = np.unique(prow[known])
-            # SELECT lane decision: extract the combined WHERE+SELECT
-            # path union (WHERE rows' plane indices are a prefix, so
-            # the matrix kernels are untouched) and keep raw values
-            # whenever some live matched rule has a batched plan
-            use_all = (
-                selstack.n_lowered > 0
-                and self.select_force != "scalar"
-                and (
-                    self.select_force == "batched"
-                    or not self._sel_batch_off
-                )
-            )
-            batch_sel = False
-            if use_all and ppos.size:
-                selp = self._pos_selp
-                batch_sel = any(
-                    selp[p] is not None
-                    for p in np.unique(ppos[plive]).tolist()
-                )
-            if active.size or batch_sel:
+            if active.size:
                 t0 = time.perf_counter()
                 cols = WindowColumns(
-                    msgs,
-                    selstack.all_paths if use_all else stack.paths,
-                    stack.lit_strings, envs,
-                    keep_values=batch_sel,
+                    msgs, stack.paths, stack.lit_strings, envs
                 )
                 t1 = time.perf_counter()
                 if cols.has_nan_value:
@@ -474,7 +445,7 @@ class RuleEngine:
                     # rules take the interpreter (bit-exactness over
                     # speed for a pathological payload)
                     pass
-                elif active.size and self.broker is not None:
+                elif self.broker is not None:
                     ev_info: Optional[Dict] = (
                         {} if rec is not None else None
                     )
@@ -487,7 +458,7 @@ class RuleEngine:
                     if ev_info:
                         start, dur = ev_info["device_wait"]
                         rec.sub("rules_device_wait", dur, start)
-                elif active.size:  # standalone: the host twin directly
+                else:  # standalone: the host twin directly
                     from ..ops.match_kernel import rules_eval_host
 
                     sub = rules_eval_host(
@@ -548,45 +519,48 @@ class RuleEngine:
             # message index ascending within a rule — identical
             # across the device / host / scalar-referee paths
             order = np.lexsort((pmsg[sel], ppos[sel]))
-            sel_l = sel[order].tolist()
-            ppos_l = ppos.tolist()
-            pmsg_l = pmsg.tolist()
+            spos = ppos[sel][order]
+            smsg_l = pmsg[sel][order].tolist()
+            # the per-rule run serves every lowered SELECT unless the
+            # lane is pinned to the referee or its cost gate tripped
+            use_run = self.select_force != "scalar" and (
+                self.select_force == "batched"
+                or not self._sel_batch_off
+            )
             selp = self._pos_selp
-            use_batched = cols is not None and cols.vals is not None
             t_act0 = time.perf_counter()  # hoisted (no clocks in loop)
             rows_b = 0
             rows_s = 0
-            k = 0
-            n_sel = len(sel_l)
-            while k < n_sel:
-                # consecutive run of pairs for ONE rule (sel_l is
-                # rule-major after the lexsort)
-                pos = ppos_l[sel_l[k]]
-                k2 = k + 1
-                while k2 < n_sel and ppos_l[sel_l[k2]] == pos:
-                    k2 += 1
+            # one run of consecutive pairs a rule (the pairs are
+            # rule-major after the lexsort)
+            cuts = (np.flatnonzero(spos[1:] != spos[:-1]) + 1).tolist()
+            for k, k2 in zip([0] + cuts, cuts + [len(smsg_l)]):
+                pos = spos[k]
                 rule = objs[pos]
                 if not rule.actions:
                     # nothing consumes the SELECT columns: skip the
                     # per-hit projection entirely (counter-only rules)
-                    k = k2
                     continue
-                plan = selp[pos] if use_batched else None
-                if plan is not None:
-                    rows = [pmsg_l[sel_l[t]] for t in range(k, k2)]
-                    self._run_rule_batched(rule, plan, cols, rows, mloc)
+                rows = smsg_l[k:k2]
+                prog = selp[pos] if use_run else None
+                if prog is not None:
+                    self._run_rule_rows(rule, prog, envs, rows, mloc)
                     rows_b += k2 - k
                 else:
-                    for t in range(k, k2):
-                        i = pmsg_l[sel_l[t]]
-                        selected = eval_select(rule.parsed, env(i))
-                        self._run_actions(rule, selected, msgs[i], mloc)
+                    # a generator: a firing's SELECT is evaluated
+                    # when the firing before it has run its actions
+                    parsed = rule.parsed
+                    self._run_firings(rule, (
+                        (eval_select(parsed, env(i)), msgs[i])
+                        for i in rows
+                    ), mloc)
                     rows_s += k2 - k
-                k = k2
             t_act1 = time.perf_counter()
             self._sel_lane_account(rows_b, rows_s, t_act1 - t_act0)
             if rec is not None:
                 rec.sub("rules_actions", t_act1 - t_act0, t_act0)
+                rec.rules_firings = rows_b + rows_s
+                rec.rules_firings_run = rows_b
         if hits:
             mloc["rules.matched"] += hits
         if self.broker is not None and mloc:
@@ -632,35 +606,51 @@ class RuleEngine:
             self._sel_batch_off = True
             self._stats["select_ewma_off"] += 1
 
-    def _run_rule_batched(
+    def _run_rule_rows(
         self,
         rule: Rule,
-        plan: tuple,
-        cols: WindowColumns,
+        prog: SelectProgram,
+        envs: WindowEnvs,
         rows: List[int],
         mloc: Counter,
     ) -> None:
-        """One rule's whole matched-row set through its lowered
-        SELECT and window-shaped actions: one `materialize_rows` pass
-        over the shared column planes, then ONE bulk handoff per
-        (action, window) — `BufferWorker.enqueue_batch` for sinks,
-        one `Aggregator.push` for aggregate actions.  Counter totals
-        and per-sink query streams match the scalar referee exactly
-        (same values, same order); only the cross-ACTION interleave
-        differs (batched emits action-major within a rule)."""
-        prog, planes = plan
-        names, colvals = materialize_rows(prog, planes, cols, rows)
+        """One rule's whole fired-row set through its lowered SELECT:
+        one `materialize_rows` pass over the rows, then the actions.
+
+        A rule of window-shaped actions alone (Sink / Aggregate) gets
+        ONE bulk handoff per (action, window) —
+        `BufferWorker.enqueue_batch` for sinks, one `Aggregator.push`
+        for aggregate actions.  Counter totals and per-sink query
+        streams match the scalar referee exactly (same values, same
+        order); only the cross-ACTION interleave differs (action-major
+        within a rule).
+
+        A rule with a function, console or republish action keeps the
+        referee's order to the call: firing by firing, the rule's
+        actions in their order, one fresh ``selected`` dict a firing,
+        a raising action failing itself alone (`_run_firings`)."""
+        names, colvals = materialize_rows(prog, envs, rows)
         n = len(rows)
+        if not all(
+            isinstance(a, (SinkAction, AggregateAction))
+            for a in rule.actions
+        ):
+            msgs = envs.msgs
+            self._run_firings(rule, zip(
+                rows_as_dicts(names, colvals, n),
+                [msgs[i] for i in rows],
+            ), mloc)
+            return
         resources = (
             self.broker.resources if self.broker is not None else None
         )
         for action in rule.actions:
             try:
                 if isinstance(action, AggregateAction):
-                    action.aggregator.push([
-                        dict(zip(names, row)) for row in zip(*colvals)
-                    ])
-                else:  # SinkAction (plan eligibility guarantees it)
+                    action.aggregator.push(
+                        rows_as_dicts(names, colvals, n)
+                    )
+                else:  # SinkAction
                     if resources is None:
                         raise RuntimeError(
                             "sink action without a broker"
@@ -680,10 +670,8 @@ class RuleEngine:
                         queries = prog_t.render_rows(colmap, n)
                     else:
                         queries = [
-                            _json.dumps(
-                                dict(zip(names, row)), default=str
-                            )
-                            for row in zip(*colvals)
+                            _json.dumps(d, default=str)
+                            for d in rows_as_dicts(names, colvals, n)
                         ]
                     worker.enqueue_batch(queries)
                 rule.actions_success += n
@@ -699,33 +687,43 @@ class RuleEngine:
                     exc,
                 )
 
-    def _run_actions(
+    def _run_firings(
         self,
         rule: Rule,
-        selected: Dict[str, Any],
-        msg: Message,
+        firings: Iterable[Tuple[Dict[str, Any], Message]],
         mloc: Optional[Counter] = None,
     ) -> None:
-        for action in rule.actions:
-            try:
-                self._run_action(action, selected, msg)
-                rule.actions_success += 1
-                if mloc is not None:
-                    mloc["actions.success"] += 1
-                elif self.broker is not None:
-                    self.broker.metrics.inc("actions.success")
-            except Exception as exc:
-                rule.actions_failed += 1
-                if mloc is not None:
-                    mloc["actions.failed"] += 1
-                elif self.broker is not None:
-                    self.broker.metrics.inc("actions.failed")
-                log.warning(
-                    "rule %s action %s failed: %s",
-                    rule.rule_id,
-                    getattr(action, "kind", action),
-                    exc,
-                )
+        """The rule's actions over ``firings`` (``(selected, msg)``
+        each), firing-major: a firing's actions run in their order on
+        its one ``selected`` dict, and an action that raises fails
+        itself alone.  The kind of each action is resolved once here,
+        not once a firing."""
+        runs = [
+            (a, a.fn if isinstance(a, FunctionAction)
+             else functools.partial(self._run_action, a))
+            for a in rule.actions
+        ]
+        ok = failed = 0
+        for selected, msg in firings:
+            for action, run in runs:
+                try:
+                    run(selected, msg)
+                    ok += 1
+                except Exception as exc:
+                    failed += 1
+                    log.warning(
+                        "rule %s action %s failed: %s",
+                        rule.rule_id,
+                        getattr(action, "kind", action),
+                        exc,
+                    )
+        rule.actions_success += ok
+        rule.actions_failed += failed
+        done = {"actions.success": ok, "actions.failed": failed}
+        if mloc is not None:
+            mloc.update(done)
+        elif self.broker is not None:
+            self.broker.metrics.inc_bulk(done)
 
     def _run_action(
         self, action: Action, selected: Dict[str, Any], msg: Message
@@ -795,7 +793,6 @@ class RuleEngine:
         engine's per-cell cost EWMAs and breaker state — exposed
         through ``/metrics``, ``GET /api/v5/rules`` and $SYS."""
         stack = self._stacked()
-        selstack = self._select_stack(stack)
         out: Dict[str, Any] = {
             "rules": len(self.rules),
             "lowered": stack.n_lowered,
@@ -807,7 +804,7 @@ class RuleEngine:
             "fallback_rule_evals": self._stats["fallback_rule_evals"],
             # output half (PR 20): lowered SELECT registry split, the
             # per-lane row counts and cost EWMAs, breaker state
-            "select_lowered": selstack.n_lowered,
+            "select_lowered": len(self._select_progs()),
             "select_batched_rows": self._stats["select_batched_rows"],
             "select_scalar_rows": self._stats["select_scalar_rows"],
             "select_ewma_off": self._stats["select_ewma_off"],

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import json
 import time
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..message import Message
 from .funcs import FUNCS
@@ -24,7 +24,10 @@ class EvalError(Exception):
     pass
 
 
-def build_env(msg: Message, node: str = "emqx_tpu@local") -> Dict[str, Any]:
+NODE = "emqx_tpu@local"
+
+
+def build_env(msg: Message, node: str = NODE) -> Dict[str, Any]:
     """The '$events/message_publish' env (emqx_rule_events.erl
     eventmsg_publish): flat columns + lazily-decoded payload.  Built
     field-by-field from `_env_field` — the same single source of
@@ -48,7 +51,7 @@ class _PayloadStr(str):
         return self._decoded  # type: ignore[attr-defined]
 
 
-def _env_field(msg: Message, key: str, node: str) -> Any:
+def _env_field(msg: Message, key: str, node: str = NODE) -> Any:
     """One `build_env` field, computed on demand (LazyEnv)."""
     if key == "event":
         return "message.publish"
@@ -83,6 +86,9 @@ _ENV_KEYS = (
     "publish_received_at", "node",
 )
 _ENV_FIELDS = frozenset(_ENV_KEYS)
+# the fields `_env_field` reads off the message as they stand (the
+# payload is the one that a reader may have to decode)
+_MSG_FIELDS = _ENV_FIELDS - {"payload"}
 
 
 class LazyEnv(dict):
@@ -97,7 +103,7 @@ class LazyEnv(dict):
 
     __slots__ = ("_msg", "_node")
 
-    def __init__(self, msg: Message, node: str = "emqx_tpu@local"):
+    def __init__(self, msg: Message, node: str = NODE):
         super().__init__()
         self._msg = msg
         self._node = node
@@ -115,6 +121,81 @@ class LazyEnv(dict):
             return self[key]
         except KeyError:
             return default
+
+
+_UNREAD = object()
+# a payload that does not decode, or a path that cannot be descended:
+# what `lookup_var` raises for, as a value a column can hold
+LOOKUP_ERROR = object()
+
+
+class WindowEnvs:
+    """One dispatch window's messages and what has been read of them
+    so far: the payload's JSON decode, once a message a window, and
+    the `LazyEnv` of a message the interpreter reads.  The column
+    extractor and the lowered SELECTs read ``decoded`` and the
+    message's own fields and build no env; an env built afterwards
+    takes the decode that is there."""
+
+    __slots__ = ("msgs", "envs", "_data")
+
+    def __init__(self, msgs: Sequence[Message]) -> None:
+        self.msgs = msgs
+        self.envs: List[Optional[LazyEnv]] = [None] * len(msgs)
+        self._data: List[Any] = [_UNREAD] * len(msgs)
+
+    def env(self, i: int) -> LazyEnv:
+        e = self.envs[i]
+        if e is None:
+            e = self.envs[i] = LazyEnv(self.msgs[i])
+            data = self._data[i]
+            if data is not _UNREAD and data is not LOOKUP_ERROR:
+                e["payload"]._decoded = data
+        return e
+
+    def decoded(self, i: int) -> Any:
+        """The payload as JSON, or `LOOKUP_ERROR` where it is none."""
+        data = self._data[i]
+        if data is _UNREAD:
+            e = self.envs[i]
+            try:
+                if e is not None:
+                    data = e["payload"].decoded()
+                else:
+                    data = json.loads(
+                        self.msgs[i].payload.decode("utf-8", "replace")
+                    )
+            except Exception:
+                data = LOOKUP_ERROR
+            self._data[i] = data
+        return data
+
+
+def read_of(path: Tuple[str, ...]) -> Tuple[str, Any]:
+    """Where a window reads a var path without an env, as ``(how,
+    arg)``: ``("json", rest)`` below the payload, from its decode
+    (`walk`); ``("msg", field)`` a field of the message
+    (`_env_field`); ``("env", path)`` anything else, through
+    `lookup_var` over the message's `LazyEnv`."""
+    if path[0] == "payload" and len(path) > 1:
+        return "json", path[1:]
+    if len(path) == 1 and path[0] in _MSG_FIELDS:
+        return "msg", path[0]
+    return "env", path
+
+
+def walk(cur: Any, rest: Tuple[str, ...]) -> Any:
+    """`lookup_var` below the payload: ``rest`` descended from the
+    decoded JSON ``cur``; None for a missing key, `LOOKUP_ERROR`
+    where `lookup_var` raises."""
+    for part in rest:
+        if isinstance(cur, dict):
+            if part not in cur:
+                return None
+            cur = cur[part]
+        else:
+            return LOOKUP_ERROR
+    return cur
 
 
 def lookup_var(env: Dict[str, Any], path: Tuple[str, ...]) -> Any:

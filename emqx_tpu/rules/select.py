@@ -3,9 +3,12 @@
 The output half of the rule matrix (the WHERE half lives in
 `predicate.py`/`columns.py`): a lowerable SELECT list — field
 projections, literals, arithmetic, ``*`` — compiles ONCE per registry
-revision into a `SelectProgram` whose inputs are raw-value planes on
-the shared `WindowColumns`, so one pass over a window materializes
-action payloads for every matched row of every lowered rule.  Rules
+revision into a `SelectProgram` whose slots name var paths, and
+`materialize_rows` reads those paths for the rows that fired, a rule
+at a time, from where the interpreter reads them: the message's own
+fields and the window's one JSON decode a message
+(`runtime.WindowEnvs`).  A message no rule fired on costs the SELECT
+lane nothing, and no value passes through a column plane.  Rules
 whose SELECT uses nodes the compiler doesn't cover (function calls,
 CASE, comparisons) degrade per RULE to the scalar interpreter
 (`runtime.eval_select`), which stays the property-tested referee.
@@ -20,16 +23,17 @@ re-walking the regex and re-splitting every dotted path per message.
 
 Value semantics are anchored to the interpreter on purpose:
 
-- projection/star values come from a raw-value plane filled during
-  the one `WindowColumns` walk (``keep_values``); a lookup error or a
-  missing key is ``None``, exactly `eval_select`'s catch;
+- projection/star values are what `lookup_var` gives for the path
+  (int-ness and nested objects as they are, the payload flattened to
+  ``str``); a lookup error or a missing key is ``None``, exactly
+  `eval_select`'s catch;
 - arithmetic closures call `runtime.arith_op` — the SAME function the
   interpreter calls — so int-ness preservation (``json.dumps(5)`` !=
   ``json.dumps(5.0)``), string ``+`` concat and div-by-zero ->
   ``None`` hold bit-identically;
-- expression operands distinguish lookup ERROR (raises, field ->
-  ``None``) from missing (operand is ``None`` -> arithmetic raises),
-  via the err lane, like `lookup_var`.
+- expression operands distinguish lookup ERROR (`LOOKUP_ERROR` in
+  the slot: raises, field -> ``None``) from missing (operand is
+  ``None`` -> arithmetic raises), like `lookup_var`.
 """
 
 from __future__ import annotations
@@ -39,7 +43,8 @@ import re
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from .runtime import (
-    EvalError, _PayloadStr, _STAR_FIELDS, _default_name, arith_op,
+    LOOKUP_ERROR, EvalError, WindowEnvs, _PayloadStr, _STAR_FIELDS,
+    _default_name, _env_field, arith_op, lookup_var, read_of, walk,
 )
 from .sql import ParsedSql
 
@@ -184,31 +189,32 @@ _ARITH_SYMS = ("+", "-", "*", "/", "div", "mod")
 
 def _compile_expr(
     expr: tuple, reg: Callable[[Tuple[str, ...]], int]
-) -> Callable[[tuple, tuple], Any]:
+) -> Callable[[tuple], Any]:
     """AST subtree -> closure over one row's gathered operand values
-    (``vals``) and error flags (``errs``), indexed by the local path
-    slots ``reg`` hands out.  Raises `_Unsupported` on nodes outside
-    the lowerable subset (calls, CASE, comparisons, IN, NOT)."""
+    (``vals``), indexed by the local path slots ``reg`` hands out.
+    Raises `_Unsupported` on nodes outside the lowerable subset
+    (calls, CASE, comparisons, IN, NOT)."""
     kind = expr[0]
     if kind == "lit":
         v = expr[1]
-        return lambda vals, errs: v
+        return lambda vals: v
     if kind == "var":
         k = reg(expr[1])
 
-        def var_fn(vals, errs, _k=k):
-            if errs[_k]:
+        def var_fn(vals, _k=k):
+            v = vals[_k]
+            if v is LOOKUP_ERROR:
                 # `lookup_var` raised for this row: the interpreter's
                 # eval_expr propagates, so the compiled form does too
                 raise EvalError("lookup error")
-            return vals[_k]
+            return v
 
         return var_fn
     if kind == "neg":
         f = _compile_expr(expr[1], reg)
 
-        def neg_fn(vals, errs, _f=f):
-            v = _f(vals, errs)
+        def neg_fn(vals, _f=f):
+            v = _f(vals)
             if not isinstance(v, (int, float)) or isinstance(v, bool):
                 raise EvalError(f"negating non-number {v!r}")
             return -v
@@ -218,9 +224,7 @@ def _compile_expr(
         sym = expr[1]
         fa = _compile_expr(expr[2], reg)
         fb = _compile_expr(expr[3], reg)
-        return lambda vals, errs: arith_op(
-            sym, fa(vals, errs), fb(vals, errs)
-        )
+        return lambda vals: arith_op(sym, fa(vals), fb(vals))
     raise _Unsupported(kind)
 
 
@@ -236,16 +240,15 @@ class SelectProgram:
       the eight `_STAR_FIELDS`
 
     ``paths`` is the tuple of var paths the program reads; slots index
-    into it.  ``has_expr`` gates the error-lane gather: only compiled
-    expressions distinguish lookup-error from missing (projections
-    emit ``None`` for both)."""
+    into it, and ``reads`` says where each slot's values come from
+    (`runtime.read_of`)."""
 
-    __slots__ = ("fields", "paths", "has_expr")
+    __slots__ = ("fields", "paths", "reads")
 
     def __init__(self, fields: tuple, paths: tuple) -> None:
         self.fields = fields
         self.paths = paths
-        self.has_expr = any(f[0] == "expr" for f in fields)
+        self.reads = tuple(read_of(p) for p in paths)
 
 
 def compile_select(parsed: ParsedSql) -> Optional[SelectProgram]:
@@ -283,103 +286,96 @@ def compile_select(parsed: ParsedSql) -> Optional[SelectProgram]:
     return SelectProgram(tuple(fields), tuple(paths))
 
 
-class SelectStack:
-    """The enabled registry's lowered SELECT programs over one shared
-    path union: ``all_paths`` extends the WHERE stack's path list (the
-    WHERE rows' plane indices stay valid — SELECT paths are strictly
-    APPENDED), ``planes[rule_id]`` maps each program's local slots to
-    plane rows in that combined space."""
-
-    __slots__ = ("progs", "planes", "all_paths", "n_lowered")
-
-    def __init__(self, progs, planes, all_paths) -> None:
-        self.progs: Dict[str, SelectProgram] = progs
-        self.planes: Dict[str, Tuple[int, ...]] = planes
-        self.all_paths: Tuple[Tuple[str, ...], ...] = all_paths
-        self.n_lowered = len(progs)
-
-
 def build_select_stack(
     rules: Sequence[Tuple[str, ParsedSql]],
-    base_paths: Sequence[Tuple[str, ...]],
-) -> SelectStack:
-    paths: List[Tuple[str, ...]] = list(base_paths)
-    ix: Dict[Tuple[str, ...], int] = {
-        p: k for k, p in enumerate(paths)
-    }
+) -> Dict[str, SelectProgram]:
+    """The lowered SELECT program of every rule that has one."""
     progs: Dict[str, SelectProgram] = {}
-    planes: Dict[str, Tuple[int, ...]] = {}
     for rid, parsed in rules:
         prog = compile_select(parsed)
-        if prog is None:
-            continue
-        pl: List[int] = []
-        for p in prog.paths:
-            k = ix.get(p)
-            if k is None:
-                k = ix[p] = len(paths)
-                paths.append(p)
-            pl.append(k)
-        progs[rid] = prog
-        planes[rid] = tuple(pl)
-    return SelectStack(progs, planes, tuple(paths))
+        if prog is not None:
+            progs[rid] = prog
+    return progs
 
 
 def materialize_rows(
     prog: SelectProgram,
-    planes: Tuple[int, ...],
-    cols,  # WindowColumns built with keep_values=True
+    envs: WindowEnvs,
     rows: Sequence[int],
 ) -> Tuple[List[str], List[List[Any]]]:
-    """One rule's SELECT over its matched window rows in one pass:
-    gather the program's value/err planes for ``rows``, then produce
+    """One rule's SELECT over the window rows it fired on, in one
+    pass: read each of the program's paths for ``rows``, then produce
     one output column per SELECT field.  Returns ``(names, columns)``
     aligned with the (star-expanded) field list; a per-row dict built
     as ``dict(zip(names, row))`` is bit-identical to
     `runtime.eval_select` (duplicate names keep first position, last
     value — plain dict-assignment semantics)."""
-    vals_planes = cols.vals
-    gv: List[List[Any]] = []
-    ge: List[List[bool]] = []
-    for g in planes:
-        plane = vals_planes[g]
-        gv.append([plane[i] for i in rows])
-    if prog.has_expr:
-        # scalar-index the numpy err rows: matched sets are usually a
-        # few rows, where fancy-index + tolist costs more than it saves
-        err_planes = cols.err
-        for g in planes:
-            erow = err_planes[g]
-            ge.append([erow[i] for i in rows])
+    msgs = envs.msgs
     n = len(rows)
+    datas: Optional[List[Any]] = None
+    gv: List[List[Any]] = []  # a slot's values, LOOKUP_ERROR included
+    for how, arg in prog.reads:
+        if how == "msg":
+            gv.append([_env_field(msgs[i], arg) for i in rows])
+        elif how == "json":
+            if datas is None:
+                decoded = envs.decoded
+                datas = [decoded(i) for i in rows]
+            gv.append([walk(d, arg) for d in datas])
+        else:
+            col: List[Any] = []
+            for i in rows:
+                try:
+                    v = lookup_var(envs.env(i), arg)
+                except Exception:
+                    v = LOOKUP_ERROR
+                # the payload as a whole reads as its text, exactly
+                # eval_select's output conversion
+                col.append(str(v) if type(v) is _PayloadStr else v)
+            gv.append(col)
     names: List[str] = []
     colvals: List[List[Any]] = []
-    vrows = erows = None
+    vrows = None
+
+    def projected(k: int) -> List[Any]:
+        if prog.reads[k][0] == "msg":
+            return gv[k]  # a message's field never fails to read
+        return [None if v is LOOKUP_ERROR else v for v in gv[k]]
+
     for kind, name, arg in prog.fields:
         if kind == "star":
             for sname, k in arg:
                 names.append(sname)
-                colvals.append(gv[k])
+                colvals.append(projected(k))
         elif kind == "var":
             names.append(name)
-            colvals.append(gv[arg])
+            colvals.append(projected(arg))
         elif kind == "lit":
             names.append(name)
             colvals.append([arg] * n)
         else:  # compiled expression
             if vrows is None:  # one transpose, shared by every expr
                 vrows = list(zip(*gv)) if gv else [()] * n
-                erows = list(zip(*ge)) if ge else [()] * n
-            fn = arg
             out: List[Any] = []
-            for r in range(n):
+            for vals in vrows:
                 try:
-                    v = fn(vrows[r], erows[r])
+                    out.append(arg(vals))
                 except (EvalError, TypeError, ValueError):
-                    v = None
-                if isinstance(v, _PayloadStr):
-                    v = str(v)
-                out.append(v)
+                    out.append(None)
             names.append(name)
             colvals.append(out)
     return names, colvals
+
+
+def rows_as_dicts(
+    names: Sequence[str], colvals: Sequence[Sequence[Any]], n: int
+) -> List[Dict[str, Any]]:
+    """`materialize_rows`'s columns as one fresh dict a row, equal to
+    ``dict(zip(names, row))`` (duplicate names keep first position,
+    last value), filled a column at a time: a third of the cost of a
+    transpose and a ``dict(zip())`` a row."""
+    out: List[Dict[str, Any]] = [{} for _ in range(n)]
+    for name, col in zip(names, colvals):
+        for d, v in zip(out, col):
+            d[name] = v
+    return out

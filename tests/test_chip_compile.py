@@ -102,7 +102,7 @@ def _match_args(sharding, batch):
 def test_match_batch_compact_10m_subs(one_chip, batch):
     _, mem = _compile(
         match_batch_compact, *_match_args(one_chip, batch),
-        f_width=F_WIDTH, m_cap=M_CAP, c_cap=2 * batch,
+        f_width=F_WIDTH, m_cap=M_CAP, c_cap=8 * batch,  # the first rung
     )
     # the tables are counted at their logical size (32 B and 64 B a
     # row); what the resident layout pads them to only the chip's

@@ -456,3 +456,69 @@ def test_tls_pubsub_roundtrip(tmp_path):
         await srv.stop()
 
     run(t())
+
+
+# ---- the boot heap, frozen while a server of the process serves ----
+
+def _plain_server():
+    cfg = BrokerConfig()
+    cfg.listeners = [ListenerConfig(bind="127.0.0.1", port=0)]
+    return BrokerServer(cfg)
+
+
+def test_start_freezes_the_boot_heap_and_the_last_stop_thaws_it():
+    import gc
+
+    async def t():
+        base = BrokerServer._serving
+        a, b = _plain_server(), _plain_server()
+        await a.start()
+        assert BrokerServer._serving == base + 1
+        assert gc.get_freeze_count() > 0
+        await b.start()
+        await a.stop()
+        # another server of the process still serves: frozen it stays
+        assert BrokerServer._serving == base + 1
+        assert gc.get_freeze_count() > 0
+        await a.stop()  # a second stop() of one server counts once
+        assert BrokerServer._serving == base + 1
+        await b.stop()
+        assert BrokerServer._serving == base
+        if base == 0:
+            assert gc.get_freeze_count() == 0
+
+    run(t())
+
+
+def test_a_full_collection_walks_what_came_after_start_alone():
+    import gc
+    import weakref
+
+    class Node:
+        pass
+
+    async def t():
+        boot = [[i] for i in range(2000)]  # tracked, alive at start()
+        srv = _plain_server()
+        await srv.start()
+        try:
+            after = [[i] for i in range(50)]
+            seen = {id(o) for o in gc.get_objects()}
+            assert not seen.intersection(map(id, boot))
+            assert seen.issuperset(map(id, after))
+            # and the collector still serves what came after: a cycle
+            # that dies while the server runs is collected
+            x, y = Node(), Node()
+            x.peer, y.peer = y, x
+            gone = weakref.ref(x)
+            del x, y
+            gc.collect()
+            assert gone() is None
+        finally:
+            await srv.stop()
+        if BrokerServer._serving == 0:
+            assert {id(o) for o in gc.get_objects()}.issuperset(
+                map(id, boot)
+            )
+
+    run(t())

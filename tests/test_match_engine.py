@@ -498,3 +498,33 @@ def test_insert_many_duplicate_fid_last_wins():
     assert eng.match("c/x") == set()
     assert eng.match("d/x") == {1}
     assert eng.match("e/x") == {2}
+
+
+def test_compact_clip_rematches_dense_and_steps_the_ladder():
+    """A window whose hits outgrow the compact buffer is matched
+    again on the dense kernel, exactly, and the capacity multiplier
+    doubles so that the next such window fits."""
+    engine = MatchEngine(
+        max_levels=8, f_width=32, m_cap=64, use_device=True
+    )
+    oracle = HostTrie()
+    # twelve filters that every topic g/a/b/<x> matches
+    plus = [
+        "g/a/b/+", "+/a/b/+", "g/+/b/+", "g/a/+/+", "+/+/b/+",
+        "+/a/+/+", "g/+/+/+", "+/+/+/+",
+    ]
+    hashes = ["#", "g/#", "g/a/#", "g/a/b/#"]
+    for fid, flt in enumerate(plus + hashes):
+        engine.insert(flt, fid)
+        oracle.insert(flt, fid)
+    engine.rebuild()
+    first = engine._ccap_mult
+    topics = [f"g/a/b/c{i}" for i in range(16)]
+    # 16 unique topics x 12 hits each: 192 codes, past the first
+    # rung's first * 16 and inside the next
+    assert first * 16 < 12 * len(topics) <= 2 * first * 16
+    check_engine_vs_oracle(engine, oracle, {}, topics)
+    assert engine._ccap_mult == 2 * first
+    # the next rung holds the same window: no clip, no further step
+    check_engine_vs_oracle(engine, oracle, {}, topics)
+    assert engine._ccap_mult == 2 * first

@@ -304,8 +304,9 @@ def test_register_file_past_the_budget_is_refused_by_shape(monkeypatch):
 
 def test_warmup_compiles_the_rules_kernel_before_traffic():
     """`warmup()` compiles the registered program at every window
-    bucket, and neither a wider SELECT path union nor another literal
-    count makes a served window compile again."""
+    bucket, and no served window compiles again: its planes are the
+    WHERE stack's paths, whatever the SELECTs read
+    (`test_planes_are_the_where_paths_and_no_env_is_left_behind`)."""
     from emqx_tpu.ops.match_kernel import rules_eval_batch
 
     stack, msgs = _small_stack_and_window()
@@ -313,20 +314,75 @@ def test_warmup_compiles_the_rules_kernel_before_traffic():
     eng.rules_source = lambda: (stack, 0)
     assert eng.warmup(32) == 0
     warmed = rules_eval_batch._cache_size()
-    for paths in (stack.paths, stack.paths + [("payload", "other")]):
-        for n in (3, 20):
-            cols = WindowColumns(msgs[:n], paths, stack.lit_strings)
-            mat, path = eng.rules_eval_window(stack, 0, cols)
-            assert path == "dev"
-            want = [
-                [eval_where(parse_sql(
-                    f'SELECT * FROM "t" WHERE payload.a > {i} '
-                    f"and payload.s = 's{i % 3}'"
-                ).where, build_env(m)) for m in msgs[:n]]
-                for i in range(5)
-            ]
-            assert mat.tolist() == want
+    for n in (3, 20):
+        cols = WindowColumns(msgs[:n], stack.paths, stack.lit_strings)
+        mat, path = eng.rules_eval_window(stack, 0, cols)
+        assert path == "dev"
+        want = [
+            [eval_where(parse_sql(
+                f'SELECT * FROM "t" WHERE payload.a > {i} '
+                f"and payload.s = 's{i % 3}'"
+            ).where, build_env(m)) for m in msgs[:n]]
+            for i in range(5)
+        ]
+        assert mat.tolist() == want
     assert rules_eval_batch._cache_size() == warmed
+
+
+def test_planes_are_the_where_paths_and_no_env_is_left_behind(
+    monkeypatch,
+):
+    """The window's planes cover the WHERE stack's paths alone, however
+    much the lowered SELECTs read besides; and a message that only the
+    matrix and a lowered SELECT read materialises no env field beyond
+    ``payload`` (most build no env at all)."""
+    import emqx_tpu.rules.engine as RE
+
+    built, windows = [], []
+
+    class Cols(WindowColumns):
+        def __init__(self, msgs, paths, lits, envs=None):
+            super().__init__(msgs, paths, lits, envs)
+            built.append(self)
+            windows.append(envs)
+
+    monkeypatch.setattr(RE, "WindowColumns", Cols)
+    eng = RuleEngine()  # standalone: the host twin
+    fired = []
+    act = [FunctionAction(lambda s, m: fired.append(s))]
+    eng.add_rule(
+        "star", 'SELECT * FROM "t/#" WHERE payload.a > 1', act
+    )
+    eng.add_rule(
+        "wide", "SELECT payload.b AS b, payload.c.d AS d, clientid, "
+        'payload FROM "t/#" WHERE qos >= 0 and payload.a > 2', act,
+    )
+    stack = eng._stacked()
+    assert sorted(stack.paths) == [("payload", "a"), ("qos",)]
+    assert len(eng._select_progs()) == 2
+    msgs = [
+        Message(topic="t/1", payload=b'{"a": %d, "b": "x"}' % a, qos=1)
+        for a in range(5)
+    ]
+    assert eng.apply_batch(
+        [(m, ["star", "wide"]) for m in msgs]
+    ) == 5
+    assert len(fired) == 5 and eng.stats()["select_scalar_rows"] == 0
+    (cols,) = built
+    n_p = len(stack.paths)
+    assert cols.paths == tuple(stack.paths)
+    for plane in (cols.num, cols.sid, cols.err, cols.prs):
+        assert plane.shape == (n_p, 5)
+    # a0, a1 fired nothing; a2 `star` only; a3, a4 both: the whole
+    # payload (`SELECT payload`, `*`) is the one read that builds an
+    # env, and it builds that one field
+    (envs,) = windows
+    assert [e is None for e in envs.envs] == [
+        True, True, False, False, False,
+    ]
+    assert all(
+        set(e) == {"payload"} for e in envs.envs if e is not None
+    )
 
 
 def test_server_start_warms_and_later_folds_follow():

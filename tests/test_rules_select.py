@@ -25,7 +25,11 @@ from emqx_tpu.broker.broker import Broker
 from emqx_tpu.config import BrokerConfig
 from emqx_tpu.message import Message
 from emqx_tpu.rules.engine import (
-    AggregateAction, RuleEngine, SinkAction, render_template,
+    AggregateAction, ConsoleAction, FunctionAction, RepublishAction,
+    RuleEngine, SinkAction, render_template,
+)
+from emqx_tpu.rules.runtime import (
+    _UNREAD, LazyEnv, WindowEnvs, eval_select,
 )
 from emqx_tpu.rules.select import (
     TemplateProgram, build_select_stack, compile_select,
@@ -181,23 +185,47 @@ def test_compile_select_covers_and_rejects():
         assert compile_select(parse_sql(sql)) is None, sql
 
 
-def test_select_stack_appends_paths_after_base():
-    base = [("payload", "w"), ("qos",)]
-    stack = build_select_stack(
-        [("r1", parse_sql(
-            'SELECT payload.a AS a, qos FROM "t/#"'
-        ))],
-        base,
+def test_select_slots_name_paths_and_read_the_fired_rows_only():
+    """A program's slots name var paths (no plane rows), each with
+    where a window reads it; `materialize_rows` reads them for the
+    rows asked for and touches no other message."""
+    parsed = parse_sql(
+        'SELECT payload.a AS a, qos, payload, flags.retain AS r '
+        'FROM "t/#"'
     )
-    # base paths keep their indices; new SELECT paths strictly append
-    assert stack.all_paths[:2] == (("payload", "w"), ("qos",))
-    assert ("payload", "a") in stack.all_paths[2:]
-    # qos reuses the base plane
-    prog = stack.progs["r1"]
-    qos_slot = dict(
-        (p, k) for k, p in enumerate(prog.paths)
-    )[("qos",)]
-    assert stack.planes["r1"][qos_slot] == 1
+    progs = build_select_stack([
+        ("r1", parsed),
+        ("r2", parse_sql('SELECT lower(topic) AS l FROM "t/#"')),
+    ])
+    assert set(progs) == {"r1"}  # r2 stays with the interpreter
+    prog = progs["r1"]
+    assert prog.paths == (
+        ("payload", "a"), ("qos",), ("payload",), ("flags", "retain"),
+    )
+    assert prog.reads == (
+        ("json", ("a",)), ("msg", "qos"), ("env", ("payload",)),
+        ("env", ("flags", "retain")),
+    )
+    msgs = [
+        Message(topic="t/1", payload=b'{"a": %d}' % i, qos=i % 3)
+        for i in range(6)
+    ]
+    envs = WindowEnvs(msgs)
+    rows = [1, 4]
+    names, cols = materialize_rows(prog, envs, rows)
+    assert names == ["a", "qos", "payload", "r"]
+    assert [dict(zip(names, r)) for r in zip(*cols)] == [
+        eval_select(parsed, LazyEnv(msgs[i])) for i in rows
+    ]
+    assert type(cols[2][0]) is str  # the payload flattens to text
+    # only the fired rows were read: an env (for the two paths that
+    # need one) and a decode each, nothing for the other four
+    assert [e is not None for e in envs.envs] == [
+        i in rows for i in range(6)
+    ]
+    assert [d is not _UNREAD for d in envs._data] == [
+        i in rows for i in range(6)
+    ]
 
 
 # ----------------------------------- seeded-world referee equality
@@ -418,3 +446,277 @@ def test_select_force_and_ewma_breaker_stats():
     assert "select_batch_disabled" in st
     assert "select_batched_us_ewma" in st
     assert len(w.queries) == 8
+
+
+# ------------------- the per-rule run for every action kind (PR 29)
+# A rule whose SELECT lowered takes the per-rule run whatever its
+# actions; the interpreter (`select_force = "scalar"`) is the referee
+# for the sequence of calls, not for their totals alone.
+
+_RUN_PAYLOADS = [
+    b"not json {", b"[1, 2]", b"5", b'"text"', b"null", b"",
+    b'{"b": 1}',  # lacks the key
+    b'{"a": {"b": {"c": [1, {"d": null}]}}, "z": 0}',
+    b'{"a": 1267650600228229401496703205376, "z": 3}',  # 2**100
+    b'{"a": 9007199254740993, "z": 2, "s": "x"}',  # 2**53 + 1
+    b'{"a": 2.5, "z": 0.0, "s": "y"}',
+    b'{"a": true, "z": false}', b'{"a": null, "z": null}',
+    b'{"a": "str", "z": 1, "s": 7}',
+    b'{"a": 4, "z": 2, "s": "zz", "obj": {"k": [1, 2]}}',
+    b'\xff\xfe{"a": 1}',  # not UTF-8
+]
+
+_RUN_SELECTS = [
+    "*",
+    "*, payload.a AS topic",  # an alias over a star field
+    "payload.a AS x, payload.z AS x, topic AS x",  # aliases collide
+    "payload",
+    "payload AS p, payload.a.b AS ab, payload.a.b.c AS abc",
+    "payload.a / payload.z AS q, payload.s + 1 AS bad, -payload.a AS n",
+    "payload.a + payload.z AS s, payload.a div payload.z AS d, 'k' AS l",
+    "flags.retain AS r, flags AS f, topic.x AS tx, nope AS nope",
+    "payload.obj AS o, payload.obj.k AS k, clientid, username, qos",
+    "timestamp, id, node, event, retain, pub_props",
+    # not lowered: the interpreter serves these in both runs
+    "lower(clientid) AS l, payload.a AS a",
+]
+
+_RUN_WHERES = [
+    None, "qos >= 0", "payload.z >= 0", "is_not_null(payload.a)",
+    "payload.s = 'x' OR qos < 2",
+    "regex_match(topic, 't/.*')",  # WHERE stays with the interpreter
+]
+
+
+def _action_world(seed):
+    rng = random.Random(seed)
+    rules = []
+    for i in range(rng.randint(6, 12)):
+        where = rng.choice(_RUN_WHERES)
+        sql = (
+            f'SELECT {rng.choice(_RUN_SELECTS)} '
+            f'FROM "{rng.choice(_FILTERS)}"'
+            + (f" WHERE {where}" if where else "")
+        )
+        rules.append((f"r{i}", sql, [
+            rng.choice(["fn", "fn", "raise", "console", "republish",
+                        "sink"])
+            for _ in range(rng.randint(1, 3))
+        ]))
+    windows = [
+        [
+            (rng.choice(_TOPICS), rng.choice(_RUN_PAYLOADS),
+             rng.randint(0, 2), bool(rng.getrandbits(1)),
+             rng.choice(["c1", "c2"]))
+            for _ in range(rng.randint(1, 14))
+        ]
+        for _ in range(5)
+    ]
+    return rules, windows
+
+
+def _run_action_world(rules, windows, force):
+    """The world through one lane: every action call in order, the
+    per-rule counters, the broker's metrics, the lane's row counts."""
+    cfg = BrokerConfig()
+    cfg.engine.use_device = False
+    b = Broker(config=cfg)
+    b.rules.select_force = force
+    calls = []
+    sinks = {}
+
+    def fn_action(tag, fail):
+        def fn(selected, msg):
+            # the dict as handed over: a later mutation by another
+            # action of the firing would show in the copy of that one
+            calls.append((tag, list(selected.items()), msg.topic,
+                          msg.payload))
+            if fail and len(calls) % 3 == 0:
+                raise RuntimeError("one action of one firing")
+            selected["seen_by"] = tag
+
+        return FunctionAction(fn)
+
+    for rid, sql, kinds in rules:
+        actions = []
+        for k, kind in enumerate(kinds):
+            if kind in ("fn", "raise"):
+                actions.append(
+                    fn_action(f"{rid}.{k}", kind == "raise")
+                )
+            elif kind == "console":
+                actions.append(ConsoleAction())
+            elif kind == "republish":
+                actions.append(RepublishAction(
+                    topic="out/${topic}", payload="${payload} ${x}",
+                ))
+            else:
+                # a worker of its own: a rule of sinks alone hands a
+                # window over action-major, so only the stream of one
+                # (rule, action) has the referee's order
+                name = f"sink:{rid}.{k}"
+                sinks[name] = b.resources._workers[name] = FakeWorker()
+                actions.append(SinkAction(name, payload="${topic}"))
+        b.rules.add_rule(rid, sql, actions=actions)
+    # what a republish lands on: its order is part of the sequence
+    b.rules.add_rule(
+        "echo", 'SELECT topic, payload FROM "out/#"',
+        actions=[fn_action("echo", False)],
+    )
+    for w, win in enumerate(windows):
+        b.publish_many([
+            Message(topic=t, payload=p, qos=q, retain=r,
+                    from_client=c, timestamp=1.7e9,
+                    mid=b"%08d%08d" % (w, k))
+            for k, (t, p, q, r, c) in enumerate(win)
+        ])
+    counters = {
+        rid: (r.matched, r.passed, r.failed, r.actions_success,
+              r.actions_failed)
+        for rid, r in b.rules.rules.items()
+    }
+    # ``actions.batched`` is the bulk hand-over's own count (a rule
+    # of sinks alone), which the referee never makes
+    metrics = {
+        k: v for k, v in b.metrics.all().items()
+        if v and k != "actions.batched"
+    }
+    queries = {name: w.queries for name, w in sinks.items()}
+    return calls, queries, counters, metrics, b.rules.stats()
+
+
+@pytest.mark.parametrize("seed", [1, 4, 7, 12, 23, 42, 77, 101])
+def test_per_rule_run_equals_interpreter_call_for_call(seed):
+    """Function, console, republish and sink actions in one rule: the
+    per-rule run makes the interpreter's calls, in its order, with
+    its ``selected`` (keys, their order, values and their types), and
+    leaves the rule and broker counters equal."""
+    rules, windows = _action_world(seed)
+    ref = _run_action_world(rules, windows, "scalar")
+    run = _run_action_world(rules, windows, None)
+    assert len(ref[0]) > 0
+    for a, b in zip(ref[0], run[0]):
+        assert a == b
+        # == lets 1 pass for True and 2 for 2.0: hold the types too
+        assert [type(v) for _, v in a[1]] == [type(v) for _, v in b[1]]
+    assert len(ref[0]) == len(run[0])
+    assert ref[1] == run[1], "sink query streams differ"
+    assert ref[2] == run[2], "rule counters differ"
+    assert ref[3] == run[3], "broker metrics differ"
+    assert ref[4]["select_batched_rows"] == 0
+    assert run[4]["select_batched_rows"] > 0
+    assert run[4]["select_ewma_off"] == 0
+
+
+def _fn_broker():
+    cfg = BrokerConfig()
+    cfg.engine.use_device = False
+    return Broker(config=cfg)
+
+
+def test_two_function_actions_keep_firing_major_order():
+    b = _fn_broker()
+    calls = []
+    b.rules.add_rule(
+        "r", 'SELECT payload.a AS a FROM "t/#" WHERE payload.a > 0',
+        actions=[
+            FunctionAction(lambda s, m: calls.append(("f", s["a"]))),
+            FunctionAction(lambda s, m: calls.append(("g", s["a"]))),
+        ],
+    )
+    b.publish_many([
+        Message(topic="t/1", payload=b'{"a": %d}' % a)
+        for a in (3, 0, 5, 7)
+    ])
+    assert calls == [
+        ("f", 3), ("g", 3), ("f", 5), ("g", 5), ("f", 7), ("g", 7),
+    ]
+    assert b.rules.stats()["select_batched_rows"] == 3
+
+
+def test_action_that_raises_fails_one_action_not_the_run():
+    b = _fn_broker()
+    calls = []
+
+    def picky(selected, msg):
+        if selected["a"] == 5:
+            raise ValueError("not this one")
+        calls.append(("picky", selected["a"]))
+
+    rule = b.rules.add_rule(
+        "r", 'SELECT payload.a AS a FROM "t/#" WHERE payload.a > 0',
+        actions=[
+            FunctionAction(picky),
+            FunctionAction(lambda s, m: calls.append(("after", s["a"]))),
+        ],
+    )
+    b.publish_many([
+        Message(topic="t/1", payload=b'{"a": %d}' % a)
+        for a in (3, 5, 7)
+    ])
+    assert calls == [
+        ("picky", 3), ("after", 3), ("after", 5),
+        ("picky", 7), ("after", 7),
+    ]
+    assert (rule.actions_success, rule.actions_failed) == (5, 1)
+    assert b.metrics.val("actions.success") == 5
+    assert b.metrics.val("actions.failed") == 1
+    assert b.rules.stats()["select_batched_rows"] == 3
+
+
+def test_each_firing_gets_its_own_selected_dict():
+    b = _fn_broker()
+    seen = []
+
+    def keep(selected, msg):
+        assert "mark" not in selected  # no other firing's dict
+        selected["mark"] = msg.topic
+        seen.append(selected)
+
+    b.rules.add_rule(
+        "r", 'SELECT * FROM "t/#"',
+        actions=[FunctionAction(keep), FunctionAction(
+            # the firing's second action sees what its first left
+            lambda s, m: seen.append(s["mark"])
+        )],
+    )
+    b.rules.add_rule(
+        "r2", 'SELECT * FROM "t/#"', actions=[FunctionAction(keep)],
+    )
+    b.publish_many([
+        Message(topic=f"t/{i}", payload=b"{}") for i in range(3)
+    ])
+    dicts = [s for s in seen if isinstance(s, dict)]
+    assert len(dicts) == 6 and len({id(d) for d in dicts}) == 6
+    assert [s for s in seen if isinstance(s, str)] == [
+        "t/0", "t/1", "t/2",
+    ]
+
+
+def test_window_record_counts_firings_and_those_a_run_served():
+    """``rules_firings`` / ``rules_firings_run`` on the window's
+    record: rows that passed a WHERE on a rule with actions, and
+    those of them a per-rule run served."""
+    b = _fn_broker()
+    hits = []
+    act = [FunctionAction(lambda s, m: hits.append(1))]
+    b.rules.add_rule(
+        "low", 'SELECT topic FROM "t/#" WHERE payload.a > 0', act
+    )
+    b.rules.add_rule(  # SELECT not lowered: the interpreter's
+        "fn", 'SELECT lower(topic) AS l FROM "t/#" WHERE payload.a > 1',
+        act,
+    )
+    b.rules.add_rule(  # no actions: not a firing anybody serves
+        "bare", 'SELECT topic FROM "t/#" WHERE payload.a > 0',
+    )
+    b.publish_many([
+        Message(topic="t/1", payload=b'{"a": %d}' % a)
+        for a in (0, 1, 2, 3)
+    ])
+    rec = b.profiler.windows(1)[-1]
+    assert (rec["rules_firings"], rec["rules_firings_run"]) == (5, 3)
+    assert len(hits) == 5
+    b.publish(Message(topic="q/none", payload=b"{}"))
+    rec = b.profiler.windows(1)[-1]
+    assert (rec["rules_firings"], rec["rules_firings_run"]) == (0, 0)

@@ -65,11 +65,13 @@ DISPATCH_FUNCS = (
     # work may creep back in
     DispatchFn("emqx_tpu/rules/engine.py", "RuleEngine.apply_batch"),
     DispatchFn("emqx_tpu/rules/columns.py", "WindowColumns.__init__"),
-    # windowed egress (PR 20): batched SELECT materialization and the
-    # sink flush loop move per-ROW work to per-WINDOW — keep it there
+    # windowed egress (PR 20, PR 29): the SELECT of a rule's fired
+    # rows, its actions firing by firing or as one hand-over, and the
+    # sink flush loop — per-ROW work stays per-RULE-RUN or per-WINDOW
     DispatchFn("emqx_tpu/rules/select.py", "materialize_rows"),
     DispatchFn("emqx_tpu/rules/engine.py",
-               "RuleEngine._run_rule_batched"),
+               "RuleEngine._run_rule_rows"),
+    DispatchFn("emqx_tpu/rules/engine.py", "RuleEngine._run_firings"),
     DispatchFn("emqx_tpu/resources.py", "BufferWorker._flush_once"),
     DispatchFn("emqx_tpu/engine.py", "MatchEngine.rules_eval_window"),
     DispatchFn("emqx_tpu/broker/broker.py", "Broker._resume_enqueue"),
