@@ -411,6 +411,8 @@ class LoopClock:
         "ingress_s", "ingress_reads", "ingress_packets",
         "ingress_publishes", "ingress_acks", "ingress_acks_run",
         "ingress_bytes",
+        "ingress_publish_s", "ingress_publish_reads",
+        "ingress_ack_s", "ingress_ack_reads",
         "egress_s", "egress_writes", "egress_packets", "egress_bytes",
         "egress_in_window_s", "egress_in_window_writes",
         "egress_writes_sender", "egress_bytes_sender", "egress_parked",
@@ -438,12 +440,23 @@ class LoopClock:
                 publishes: int, acks: int, acks_run: int = 0) -> None:
         """One socket read's parse + channel work, begun at ``t0``:
         ``acks_run`` of its ``acks`` crossed as `AckRun`s (a run
-        counts as the packets it carries, everywhere here)."""
+        counts as the packets it carries, everywhere here).  A read
+        that held PUBLISH packets alone, or acknowledgements alone,
+        is also the cost of its packet type (``ingress_publish_*``,
+        ``ingress_ack_*``); a mixed or partial one is in neither."""
         now = time.perf_counter()
         if self.tid is None:
             self.tid = threading.get_ident()
-        self.ingress_s += now - t0
+        dt = now - t0
+        self.ingress_s += dt
         self.ingress_reads += 1
+        if publishes == packets:
+            if packets:
+                self.ingress_publish_s += dt
+                self.ingress_publish_reads += 1
+        elif acks == packets:
+            self.ingress_ack_s += dt
+            self.ingress_ack_reads += 1
         self.ingress_packets += packets
         self.ingress_publishes += publishes
         self.ingress_acks += acks
