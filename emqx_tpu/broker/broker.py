@@ -47,12 +47,8 @@ _PREPARE_ERROR = object()
 from .. import topic as T
 from ..codec import mqtt as C
 from .cm import ConnectionManager
-from .session import Session, SubOpts, publish_entries
+from .session import Session, SubOpts, window_entries
 from .shared import SharedSubManager
-
-# shared all--1 pid segment for pure-QoS0 planned runs (views of one
-# buffer instead of one np.full per run)
-_NEG1_SEG = np.full(4096, -1, dtype=np.int64)
 
 
 class Broker:
@@ -1652,10 +1648,20 @@ class Broker:
         client_rows, opts_rows)`` columns (host numpy or the device
         decide kernel, per the engine's cost model), and the whole
         window's wire assembles in ONE GIL-released native splice with
-        per-client output slices.  Per run, Python touches only
-        session state (packet-id block + bulk inflight insert) and the
-        consumers that asked for per-delivery objects; delivery lists
-        materialize lazily via `_materialize_run`.
+        per-client output slices.  Per run, Python touches only the
+        session: look-up, stall test, cork, one packet-id block and
+        one bulk inflight insert of entries the window built once a
+        (message, QoS); a run that takes no other branch is *plain*
+        (``WindowRecord.n_clients_plain``).  What the splice reads —
+        body slots (one `key_slots` a protocol version over the kept
+        key column), packet ids (each run's first id plus the pending
+        rank) and counts (one bincount) — is built once a window
+        after the loop.  The branches off that path (no session,
+        detached, stalled, a subscription identifier, no room in the
+        in-flight window, a channel without ``send_wire``, the exact
+        id allocator, a consumer of per-delivery objects) stay in the
+        one loop body; delivery lists materialize lazily via
+        `_materialize_run`.
 
         Wire bytes, counts, per-qos sent metrics and inflight windows
         are bit-identical to `_dispatch_scalar` (the property suite in
@@ -1689,12 +1695,9 @@ class Broker:
         drop = (packed & DEC_DROP_BIT) != 0
         retn = (packed & DEC_RETAIN_BIT) != 0
         sidb = (packed & DEC_SUBID_BIT) != 0
-        # body-slot keys for both effective-QoS variants (the run
-        # picks one by its session's upgrade_qos)
-        ri = retn.astype(np.int64)
-        base_key = sm_a * 6 + ri
-        kmin = base_key + qmin * 2
-        kmax = base_key + qmax * 2
+        # body-slot keys less the effective QoS, which a run's variant
+        # adds (the run picks one by its session's upgrade_qos)
+        base_key = sm_a * 6 + retn
         if rec is not None:
             if "device_wait" in dec_info:
                 start, dur = dec_info["upload"]
@@ -1726,13 +1729,6 @@ class Broker:
         traced_clients: Optional[Dict] = {} if samp is not None else None
         lib = dispatchasm.load()
         native_ok = lib is not None
-        # the window splice plan: per-run body/pid columns accumulate
-        # here and ONE native call after the loop assembles every
-        # client's wire into one buffer with per-run output offsets
-        plan_bodies: List[np.ndarray] = []
-        plan_pids: List[np.ndarray] = []
-        plan_sends: List[Tuple] = []  # (send_wire, (n0, n1, n2))
-        plan_counts: List[Tuple[int, int]] = []  # (k, e) per planned run
         cnt = np.zeros(n, dtype=np.int64)
         now_w = time.time()  # ONE clock read for the whole window
         floor = now_w - self.slow_subs.threshold_ms / 1000.0
@@ -1740,71 +1736,103 @@ class Broker:
         cm_lookup = self.cm.lookup
         cm_channel = self.cm.channel
         client_of = router.client_of_row
-        sm_l = sm_a.tolist()
-        cuts = np.flatnonzero(sra[1:] != sra[:-1]) + 1
-        bounds = [0, *cuts.tolist(), nd_total]
-        # per-RUN aggregates, reduced window-wide in a handful of
-        # vectorized passes so the run loop does no per-run numpy
-        # reductions: subid/no-local presence, kept counts, pending
-        # (QoS>0) and QoS1 counts for BOTH effective-QoS variants
-        starts = np.asarray(bounds[:-1], dtype=np.int64)
+        starts = np.concatenate(
+            (np.zeros(1, dtype=np.int64),
+             np.flatnonzero(sra[1:] != sra[:-1]) + 1)
+        )
+        bounds = starts.tolist()
+        bounds.append(nd_total)
+        nruns = len(starts)
+        run_len = np.diff(starts, append=nd_total)
+        rows_l = sra[starts].tolist()
+        # what a run is told from columns, window-wide: a subscription
+        # identifier in it, and whether a consumer wants its deliveries
+        # as objects (hook / batch sink: every run; an OTel or sampled
+        # message: the runs that carry one)
         keepw = ~drop
-        keep_i = keepw.astype(np.int64)
-        run_subid = np.maximum.reduceat(sidb, starts)
-        run_drop = np.maximum.reduceat(drop, starts)
-        run_kq_min = np.add.reduceat(keep_i * (qmin > 0), starts)
-        run_kq_max = np.add.reduceat(keep_i * (qmax > 0), starts)
-        run_n1_min = np.add.reduceat(keep_i * (qmin == 1), starts)
-        run_n1_max = np.add.reduceat(keep_i * (qmax == 1), starts)
+        subid_l = np.maximum.reduceat(sidb, starts).tolist()
+        cons_l: Optional[List[bool]] = None
+        if deliver_hook or delivered_runs is not None:
+            cons_l = [True] * nruns
+        elif otel is not None or samp is not None:
+            traced = otel if samp is None else (
+                samp if otel is None else otel | samp
+            )
+            cons_l = np.maximum.reduceat(traced[sm_a], starts).tolist()
+        # the delivery lists' message column as a list: only the runs
+        # off the wire path (and a window with consumers) read it
+        sm_l: Optional[List[int]] = (
+            sm_a.tolist() if cons_l is not None else None
+        )
         # L2 overload shed: effective-QoS0 deliveries fold out of the
         # kept-for-wire set in ONE vectorized AND per QoS variant
         # ($SYS messages exempt — the overload alarm itself must
-        # survive the ladder).  The kq/n1 aggregates above count only
-        # QoS>0 deliveries, so they need no variant forms; the kept
-        # masks and their per-run drop/shed aggregates do.
+        # survive the ladder)
         shed0 = self.olp.shed_qos0_mask
+        elig = shed_cell = None
         if shed0:
             elig = np.fromiter(
                 (not m.sys for m in msgs), bool, n
             )[sm_a]
-            shed_min = keepw & (qmin == 0) & elig
-            shed_max = keepw & (qmax == 0) & elig
-            kw_min = keepw & ~shed_min
-            kw_max = keepw & ~shed_max
-            rdrop_min = np.maximum.reduceat(~kw_min, starts)
-            rdrop_max = np.maximum.reduceat(~kw_max, starts)
-            rshed_min = np.add.reduceat(
-                shed_min.astype(np.int64), starts
-            )
-            rshed_max = np.add.reduceat(
-                shed_max.astype(np.int64), starts
-            )
-            shed_cell: Optional[List[int]] = [0]
-        else:
-            kw_min = kw_max = rdrop_min = rdrop_max = None
-            rshed_min = rshed_max = None
-            shed_cell = None
+            shed_cell = [0]
         shed_native = 0
         # per-connection outbound high-watermark: a stalled
         # subscriber past it takes the drop/queue path, never the wire
         out_wm = self.config.mqtt.outbound_high_watermark
-        # one shareable inflight-entry list / pid layout per unique
-        # run shape: a fanout window's runs overwhelmingly repeat the
-        # same (deliveries, qos) pattern, so entry construction runs
-        # once per SHAPE, not once per subscriber (entries are
+        # ONE in-flight entry a distinct (message, effective QoS > 0)
+        # of the window, whichever variant asks first (entries are
         # replace-not-mutate; see session._InflightEntry)
-        ecache: Dict = {}
-        bcache: Dict = {}
-        # a full run (every window message once, in order) bumps every
-        # count by one — recognized by byte-compare against the iota
-        # pattern so the hot fanout shape skips per-element scatter
-        iota_b = np.arange(n, dtype=sm_a.dtype).tobytes()
-        full_runs = 0
-        n_clients = 0
-        for bi in range(len(bounds) - 1):
-            k, e = bounds[bi], bounds[bi + 1]
-            clientid = client_of(int(sra[k]))
-            n_clients += 1
+        ent_tbl = np.empty(2 * n, dtype=object)
+        variants: List[Optional[Tuple]] = [None, None]
+
+        def variant(upgrade: bool) -> Tuple:
+            """One effective-QoS variant's view of the window, made on
+            the first run whose session asks for it (a deployment has
+            one `upgrade_qos`): per-run kept / pending / QoS1 counts
+            and shed units as lists, each run's offset into the
+            variant's pending-order entry list, and last the
+            per-delivery (kept mask, pending mask, body keys)."""
+            q = qmax if upgrade else qmin
+            if shed0:
+                kw = keepw & ~((q == 0) & elig)
+                shed_l = np.add.reduceat(
+                    (keepw & ~kw).astype(np.int64), starts
+                ).tolist()
+            else:
+                kw = keepw
+                shed_l = None
+            pend = keepw & (q > 0)
+            kq = np.add.reduceat(pend.astype(np.int64), starts)
+            n1 = np.add.reduceat(
+                (pend & (q == 1)).astype(np.int64), starts
+            )
+            nk = np.add.reduceat(kw.astype(np.int64), starts)
+            ents = window_entries(
+                msgs, sm_a[pend] * 2 + (q[pend] - 1), ent_tbl, now_w
+            )
+            v = variants[upgrade] = (
+                kq.tolist(), n1.tolist(), nk.tolist(),
+                (np.cumsum(kq) - kq).tolist(), ents, shed_l,
+                (kw, pend, base_key + q * 2),
+            )
+            return v
+
+        # what the run loop leaves for the window's columnar pass:
+        # which runs are in the splice plan, their first packet id
+        # (the exact allocator's explicit lists aside), protocol
+        # version and QoS variant; `late` marks the connected runs
+        # that count whether or not the splice succeeds
+        planned = bytearray(nruns)
+        late = bytearray(nruns)
+        upg_b = bytearray(nruns)
+        first = [-1] * nruns
+        ver_l = [-1] * nruns
+        pid_over: List[Tuple[int, List[int]]] = []
+        plan_sends: List[Tuple] = []  # (send_wire, (n0, n1, n2))
+        cur = kq_l = n1_l = nk_l = poff_l = ents = shed_l = rows = None
+        n_plain = 0
+        for bi in range(nruns):
+            clientid = client_of(rows_l[bi])
             try:
                 session = cm_lookup(clientid)
                 if session is None:
@@ -1813,31 +1841,25 @@ class Broker:
                         # detached across a restart: already persisted
                         # by the gate, replays on resume — not a drop
                         continue
-                    mloc["delivery.dropped"] += e - k
+                    mloc["delivery.dropped"] += bounds[bi + 1] - bounds[bi]
                     continue
-                upgrade = session.upgrade_qos
-                eff = (qmax if upgrade else qmin)[k:e]
                 channel = cm_channel(clientid)
-                if channel is None:
-                    # detached persistent session: materialize the run
-                    # (off the wire hot path) and take the SAME
-                    # queue/bake/replicate code the scalar path uses
-                    flags = self._queue_detached_run(
-                        session, clientid,
-                        self._materialize_run(
-                            msgs, router, sm_l, so_a, k, e
-                        ),
-                        mloc, bake_cache,
-                    )
-                    for t, f in enumerate(flags):
-                        if f:
-                            cnt[sm_l[k + t]] += 1
-                    continue
-                if out_wm and self._stalled(session, channel):
-                    # stalled subscriber past its outbound watermark:
-                    # the queue path keeps the wire buffers bounded
-                    # (see `_queue_stalled_run`, shared with scalar)
-                    flags = self._queue_stalled_run(
+                if channel is None or (
+                    out_wm and self._stalled(session, channel)
+                ):
+                    # detached persistent session, or a stalled
+                    # subscriber past its outbound watermark (the
+                    # queue path keeps the wire buffers bounded):
+                    # materialize the run (off the wire hot path) and
+                    # take the SAME queue/bake/replicate code the
+                    # scalar path uses
+                    k, e = bounds[bi], bounds[bi + 1]
+                    if sm_l is None:
+                        sm_l = sm_a.tolist()
+                    flags = (
+                        self._queue_detached_run if channel is None
+                        else self._queue_stalled_run
+                    )(
                         session, clientid,
                         self._materialize_run(
                             msgs, router, sm_l, so_a, k, e
@@ -1854,202 +1876,117 @@ class Broker:
                     corked.append(channel)
                 version = getattr(channel, "version", None)
                 send_wire = getattr(channel, "send_wire", None)
+                upgrade = session.upgrade_qos
+                if upgrade is not cur:
+                    cur = upgrade
+                    kq_l, n1_l, nk_l, poff_l, ents, shed_l, rows = (
+                        variants[upgrade] or variant(upgrade)
+                    )
+                kq = kq_l[bi]
                 # lazy delivery lists: materialize ONLY for an actual
                 # consumer — hook/batch sink (window-wide), or a
                 # traced/sampled message in THIS run
                 deliveries = None
-                need = deliver_hook or delivered_runs is not None
-                if not need and otel is not None:
-                    need = bool(otel[sm_a[k:e]].any())
-                sampled_run = (
-                    samp is not None and bool(samp[sm_a[k:e]].any())
-                )
-                if need or sampled_run:
-                    deliveries = self._materialize_run(
-                        msgs, router, sm_l, so_a, k, e
+                sampled_run = False
+                plain = cons_l is None or not cons_l[bi]
+                if not plain:
+                    k, e = bounds[bi], bounds[bi + 1]
+                    need = deliver_hook or delivered_runs is not None
+                    if not need and otel is not None:
+                        need = bool(otel[sm_a[k:e]].any())
+                    sampled_run = (
+                        samp is not None
+                        and bool(samp[sm_a[k:e]].any())
                     )
-                kq = int(
-                    (run_kq_max if upgrade else run_kq_min)[bi]
-                )
-                planned = False
-                native = (
+                    if need or sampled_run:
+                        deliveries = self._materialize_run(
+                            msgs, router, sm_l, so_a, k, e
+                        )
+                in_plan = False
+                if (
                     native_ok
                     and version is not None
                     and send_wire is not None
-                    and not run_subid[bi]
-                )
-                if native and kq and not session.inflight.room_for(kq):
+                    and not subid_l[bi]
                     # full/near-full inflight window: the scalar
                     # loop queues the overflow per delivery
-                    native = False
-                if native:
-                    if shed0:
-                        # the run's variant kept mask folds the shed
-                        # in; its aggregates were reduced window-wide
-                        kww = kw_max if upgrade else kw_min
-                        has_drop = bool(
-                            (rdrop_max if upgrade else rdrop_min)[bi]
+                    and (not kq or session.inflight.room_for(kq))
+                ):
+                    if kq:
+                        # the run's entries are its slice of the
+                        # variant's pending-order list; its packet ids
+                        # one consecutive block but for a wrap or a
+                        # collision (the exact allocator's list)
+                        p0 = poff_l[bi]
+                        pids = session.bookkeep_entries(
+                            ents[p0:p0 + kq]
                         )
-                        shed_native += int(
-                            (rshed_max if upgrade else rshed_min)[bi]
-                        )
-                    else:
-                        kww = keepw
-                        has_drop = bool(run_drop[bi])
-                    keysw = kmax if upgrade else kmin
-                    if has_drop:
-                        keep = kww[k:e]
-                        keys = keysw[k:e][keep]
-                    else:
-                        keys = keysw[k:e]
-                    # per-window body-column cache: fanout runs repeat
-                    # the same key pattern, so the slot gather runs
-                    # once per distinct (version, keys) shape
-                    bkey = (version, keys.tobytes())
-                    body = bcache.get(bkey)
-                    if body is None:
-                        body = bcache[bkey] = enc.key_slots(
-                            msgs, version, keys
-                        )
-                    nk = len(body)
-                    n1 = n2 = 0
-                    if kq == 0:
-                        pid_seg = _NEG1_SEG[:nk] if nk <= len(
-                            _NEG1_SEG
-                        ) else np.full(nk, -1, dtype=np.int64)
-                    else:
-                        n1 = int(
-                            (run_n1_max if upgrade else run_n1_min)[bi]
-                        )
-                        n2 = kq - n1
-                        if has_drop or kq != nk:
-                            # mixed run: locate the pending positions
-                            effk = eff[kww[k:e]] if has_drop else eff
-                            pend_pos = np.flatnonzero(effk > 0)
-                            if has_drop:
-                                pend_abs = (
-                                    np.flatnonzero(kww[k:e])[pend_pos]
-                                    + k
-                                )
-                            else:
-                                pend_abs = pend_pos + k
-                            pend_sm = sm_a[pend_abs]
-                            pend_q = effk[pend_pos]
-                            ekey = (
-                                pend_sm.tobytes(), pend_q.tobytes()
-                            )
-                            entries = ecache.get(ekey)
-                            if entries is None:
-                                entries = ecache[ekey] = \
-                                    publish_entries(
-                                        zip(
-                                            map(msgs.__getitem__,
-                                                pend_sm.tolist()),
-                                            pend_q.tolist(),
-                                        ),
-                                        now_w,
-                                    )
-                            pids = session.bookkeep_entries(entries)
-                            pid_seg = np.full(nk, -1, dtype=np.int64)
-                            pid_seg[pend_pos] = (
-                                np.arange(
-                                    pids, pids + kq, dtype=np.int64
-                                )
-                                if type(pids) is int else pids
-                            )
+                        if type(pids) is int:
+                            first[bi] = pids
                         else:
-                            # the common shape: every delivery kept
-                            # and pending — the run's entry list is
-                            # the cached window shape, pids are the
-                            # whole segment
-                            ekey = (
-                                sm_a[k:e].tobytes(), eff.tobytes()
-                            )
-                            entries = ecache.get(ekey)
-                            if entries is None:
-                                entries = ecache[ekey] = \
-                                    publish_entries(
-                                        zip(
-                                            map(msgs.__getitem__,
-                                                sm_l[k:e]),
-                                            eff.tolist(),
-                                        ),
-                                        now_w,
-                                    )
-                            pids = session.bookkeep_entries(entries)
-                            pid_seg = (
-                                np.arange(
-                                    pids, pids + nk, dtype=np.int64
-                                )
-                                if type(pids) is int
-                                else np.asarray(pids, dtype=np.int64)
-                            )
+                            pid_over.append((bi, pids))
+                            plain = False
+                    if shed_l is not None:
+                        shed_native += shed_l[bi]
+                    nk = nk_l[bi]
                     if nk:  # an all-dropped run has no wire (and
                         # would break the assemble plan's reduceat)
-                        plan_bodies.append(body)
-                        plan_pids.append(pid_seg)
+                        n1 = n1_l[bi]
                         plan_sends.append(
-                            (send_wire, (nk - kq, n1, n2))
+                            (send_wire, (nk - kq, n1, kq - n1))
                         )
                         # counts for planned runs are deferred until
                         # the window splice SUCCEEDS (parity with the
                         # scalar path, where a native failure raises
                         # before counting)
-                        plan_counts.append((k, e))
-                        planned = True
+                        planned[bi] = in_plan = True
+                        upg_b[bi] = upgrade
+                        ver_l[bi] = version
+                    n_plain += plain
                 else:
                     if deliveries is None:
+                        if sm_l is None:
+                            sm_l = sm_a.tolist()
                         deliveries = self._materialize_run(
-                            msgs, router, sm_l, so_a, k, e
+                            msgs, router, sm_l, so_a,
+                            bounds[bi], bounds[bi + 1],
                         )
                     packets = session.deliver(
                         deliveries, encoder=enc, version=version,
                         shed_qos0=shed0, shed_cell=shed_cell,
                     )
                     channel.send_packets(packets)
-                if deliver_hook:
-                    self.hooks.run(
-                        "message.delivered", clientid, deliveries
-                    )
-                if delivered_runs is not None:
-                    delivered_runs.append((clientid, deliveries))
-                if sampled_run:
-                    # a sampled message's lifecycle span names the
-                    # clients that RECEIVED it (guard: sampled runs
-                    # only — unsampled windows never enter here); a
-                    # no-local-dropped (or olp-shed) delivery never
-                    # reached this client, so the run's kept mask
-                    # gates the attribution
-                    if shed0:
-                        dropr = ~(kw_max if upgrade else kw_min)[k:e]
-                    else:
-                        dropr = drop[k:e]
-                    for t, (dm, _o) in enumerate(deliveries):
-                        if dropr[t]:
-                            continue
-                        tctx = getattr(dm, "_trace_ctx", None)
-                        if tctx is not None:
-                            traced_clients.setdefault(
-                                id(dm), []
-                            ).append(clientid)
-                if scan_slow:
-                    self._slow_scan_run(
-                        clientid,
-                        map(msgs.__getitem__, sm_l[k:e]),
-                        now_w, floor,
-                    )
-                if tracer is not None and deliveries is not None:
-                    self._deliver_span(clientid, deliveries)
+                if cons_l is not None and cons_l[bi]:
+                    if deliver_hook:
+                        self.hooks.run(
+                            "message.delivered", clientid, deliveries
+                        )
+                    if delivered_runs is not None:
+                        delivered_runs.append((clientid, deliveries))
+                    if sampled_run:
+                        # a sampled message's lifecycle span names the
+                        # clients that RECEIVED it (guard: sampled
+                        # runs only — unsampled windows never enter
+                        # here); a no-local-dropped (or olp-shed)
+                        # delivery never reached this client, so the
+                        # run's kept mask gates the attribution
+                        keptr = rows[0][k:e]
+                        for t, (dm, _o) in enumerate(deliveries):
+                            if not keptr[t]:
+                                continue
+                            tctx = getattr(dm, "_trace_ctx", None)
+                            if tctx is not None:
+                                traced_clients.setdefault(
+                                    id(dm), []
+                                ).append(clientid)
+                    if tracer is not None and deliveries is not None:
+                        self._deliver_span(clientid, deliveries)
                 # a connected run counts every delivery (parity with
-                # the scalar path's all-delivered return), counted
+                # the scalar path's all-delivered return), marked
                 # LAST so a failed run contributes none; native-
                 # planned runs count after the window splice succeeds
-                if not planned:
-                    sm_run = sm_a[k:e]
-                    if e - k == n and sm_run.tobytes() == iota_b:
-                        full_runs += 1
-                    else:
-                        np.add.at(cnt, sm_run, 1)
+                if not in_plan:
+                    late[bi] = True
             except Exception:
                 log.exception("dispatch to %s failed", clientid)
                 mloc["messages.publish.error"] += 1
@@ -2062,29 +1999,100 @@ class Broker:
             if nshed:
                 mloc["delivery.dropped"] += nshed
                 mloc["delivery.dropped.olp_shed"] += nshed
-        if plan_bodies:
+        if rec is not None:
+            rec.n_clients_plain = n_plain
+        counted = np.frombuffer(late, dtype=bool)
+        if scan_slow:
+            # the window's OLDEST publish is past the slow-subs
+            # threshold (a flood's every window is): ONE pass over the
+            # connected runs' deliveries, not a scan a run
+            served = counted | np.frombuffer(planned, dtype=bool)
+            self._slow_scan_window(
+                msgs, sra, sm_a,
+                None if served.all() else np.repeat(served, run_len),
+                now_w, floor,
+            )
+        if plan_sends:
+            # the window's columnar pass over the planned runs: the
+            # kept key column in run order IS the splice's body
+            # column, packet ids are each run's first id plus the
+            # pending rank, sizes come from the run bounds
+            in_plan_a = np.frombuffer(planned, dtype=bool)
+            vmin, vmax = variants
+            if vmin is None or vmax is None:
+                kw, pend, keys = (vmin or vmax)[-1]
+            else:
+                upg_row = np.repeat(
+                    np.frombuffer(upg_b, dtype=bool), run_len
+                )
+                kw, pend, keys = (
+                    np.where(upg_row, a, b)
+                    for a, b in zip(vmax[-1], vmin[-1])
+                )
+            sel = kw if len(plan_sends) == nruns else (
+                kw & np.repeat(in_plan_a, run_len)
+            )
+            pend_cum = np.cumsum(pend)
+            # pending deliveries before each run's first: a pending
+            # row's id is its run's first id plus its rank in the run
+            before = pend_cum[starts] - pend[starts]
+            pid_row = np.where(
+                pend,
+                np.repeat(
+                    np.asarray(first, dtype=np.int64) - before - 1,
+                    run_len,
+                ) + pend_cum,
+                -1,
+            )
+            for bi, pids in pid_over:
+                k, e = bounds[bi], bounds[bi + 1]
+                pid_row[k:e][pend[k:e]] = pids
+            sel_cum = np.cumsum(sel)
+            run_start = (sel_cum[starts] - sel[starts])[in_plan_a]
+            if sel_cum[-1] != nd_total:
+                keys = keys[sel]
+                pid_row = pid_row[sel]
+            vers = set(ver_l)
+            vers.discard(-1)
+            if len(vers) == 1:
+                body_all = enc.key_slots(msgs, vers.pop(), keys)
+            else:
+                ver_row = np.repeat(np.asarray(ver_l), run_len)
+                if len(ver_row) != len(keys):
+                    ver_row = ver_row[sel]
+                body_all = np.empty(len(keys), dtype=np.int64)
+                for version in vers:
+                    at = ver_row == version
+                    body_all[at] = enc.key_slots(
+                        msgs, version, keys[at]
+                    )
             if self._assemble_window_native(
-                lib, enc, plan_bodies, plan_pids, plan_sends, mloc, asm
+                lib, enc, body_all, pid_row, run_start, plan_sends,
+                mloc, asm,
             ):
-                for k, e in plan_counts:
-                    if e - k == n and sm_a[k:e].tobytes() == iota_b:
-                        full_runs += 1
-                    else:
-                        np.add.at(cnt, sm_a[k:e], 1)
-        if full_runs:
-            cnt += full_runs
+                counted = counted | in_plan_a
+        # ONE bincount over the counted runs' message column
+        if counted.all():
+            cnt += np.bincount(sm_a, minlength=n)
+        elif counted.any():
+            cnt += np.bincount(
+                sm_a[np.repeat(counted, run_len)], minlength=n
+            )
         if cnt.any():
             for i in np.flatnonzero(cnt).tolist():
                 counts[i] += int(cnt[i])
-        return n_clients, traced_clients
+        return nruns, traced_clients
 
     def _assemble_window_native(
-        self, lib, enc, plan_bodies, plan_pids, plan_sends, mloc, asm
+        self, lib, enc, body_all, pid_all, run_start, plan_sends,
+        mloc, asm,
     ) -> bool:
         """Execute the window's splice plan: ONE GIL-released
         `da_assemble_window` call builds every planned run's wire into
         one buffer, then each connection gets its zero-copy slice as a
-        corked ``Raw`` blob.  On a span-table mismatch (negative
+        corked ``Raw`` blob.  ``body_all`` / ``pid_all`` are the
+        planned runs' kept deliveries in run order, ``run_start`` each
+        run's first.  On a span-table mismatch (negative
         return) NO run's bytes ship — QoS>0 deliveries redeliver via
         the inflight retry path with dup=1, QoS0 are lost as on any
         failed write — because a partially shifted buffer could
@@ -2093,19 +2101,7 @@ class Broker:
         runs' delivery counts too (the ``message.delivered`` hooks may
         already have fired — that asymmetry is accepted on this
         defensive invariant-violated path)."""
-        nruns = len(plan_bodies)
-        run_lens = np.fromiter(
-            (len(b) for b in plan_bodies), np.int64, nruns
-        )
-        run_start = np.zeros(nruns, dtype=np.int64)
-        np.cumsum(run_lens[:-1], out=run_start[1:])
-        body_all = (
-            plan_bodies[0] if nruns == 1
-            else np.concatenate(plan_bodies)
-        )
-        pid_all = (
-            plan_pids[0] if nruns == 1 else np.concatenate(plan_pids)
-        )
+        nruns = len(plan_sends)
         # per-run byte sizes from the (now complete) span tables in
         # ONE vectorized pass over the window columns; the exclusive
         # cumsum is each run's planned output offset.  Zero-length
@@ -2448,6 +2444,33 @@ class Broker:
                         tctx.trace_id if tctx is not None else ""
                     ),
                 )
+
+    def _slow_scan_window(
+        self, msgs, sra, sm_a, served, now: float, floor: float
+    ) -> None:
+        """`_slow_scan_run` for a whole window of the columns path:
+        the latency of every delivery of the ``served`` rows (None:
+        all) in one numpy pass, `SlowSubs.slowest` picks the few that
+        a record a delivery would have left on the board, and only
+        those are recorded, in the runs' order: same board, same
+        trace linkage, no per-delivery Python."""
+        slow = self.slow_subs
+        ts = np.fromiter(
+            (m.timestamp or 0.0 for m in msgs), np.float64, len(msgs)
+        )
+        lat = np.where(
+            (ts > 0.0) & (ts < floor), (now - ts) * 1000.0, -1.0
+        )[sm_a]
+        if served is not None:
+            lat = np.where(served, lat, -1.0)
+        client_of = self.router.client_of_row
+        for t in slow.slowest(lat).tolist():
+            m = msgs[sm_a[t]]
+            tctx = getattr(m, "_trace_ctx", None)
+            slow.record(
+                client_of(int(sra[t])), m.topic, float(lat[t]),
+                trace_id=tctx.trace_id if tctx is not None else "",
+            )
 
     def _deliver_span(
         self, clientid: str, deliveries: List[Tuple[Message, SubOpts]]

@@ -46,12 +46,24 @@ def _neg1_ptr(n: int):
     return _NEG1_PTR
 
 
-def publish_entries(pairs, now: float) -> List["_InflightEntry"]:
-    """Fresh PUBLISHING-phase inflight entries for ``(msg, qos)``
-    pairs, all stamped with one clock read — the factory the window
-    dispatch uses to build each unique run shape's shareable entry
-    list (`Session.bookkeep_entries`)."""
-    return [_InflightEntry(_PUBLISHING, m, q, now) for m, q in pairs]
+def window_entries(msgs, keys, table, now: float) -> list:
+    """The in-flight entries of a window's pending (QoS>0) deliveries,
+    in ``keys``' order: ``keys`` is an int64 column of
+    ``msg_idx * 2 + effective_qos - 1`` and ``table`` the window's
+    object array of ``2 * len(msgs)`` entries (``None`` until built),
+    shared by both effective-QoS variants.  ONE entry a distinct
+    (message, QoS) of the window, stamped with the window's one clock
+    read and shared by every subscriber that is owed it (entries are
+    replace-not-mutate, see `_InflightEntry`): a run takes its slice
+    of the returned list into `Session.bookkeep_entries`."""
+    seen = np.zeros(len(table), dtype=bool)
+    seen[keys] = True
+    for key in np.flatnonzero(seen).tolist():
+        if table[key] is None:
+            table[key] = _InflightEntry(
+                _PUBLISHING, msgs[key >> 1], (key & 1) + 1, now
+            )
+    return table[keys].tolist()
 
 
 @dataclass
@@ -429,10 +441,10 @@ class Session:
 
     def bookkeep_entries(self, entries: List[_InflightEntry]):
         """`bookkeep_run` for pre-built entries: the columns dispatch
-        builds ONE entry list per unique (deliveries, qos) run shape
-        and shares it across every subscriber in the window (entries
-        are replace-not-mutate, see `_InflightEntry`), so a fanout-256
-        window constructs 64 entries instead of 16384.
+        builds ONE entry a distinct (message, qos) of the window
+        (`window_entries`) and shares it across every subscriber
+        (entries are replace-not-mutate, see `_InflightEntry`), so a
+        fanout-256 window constructs 64 entries instead of 16384.
 
         Returns an ``int`` first-pid when the block is the consecutive
         fast path (ids ``pid..pid+n-1``, no list ever materialized) or

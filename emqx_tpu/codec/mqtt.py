@@ -1107,28 +1107,28 @@ class DispatchEncoder:
         return s
 
     def key_slots(self, msgs, version: int, keys) -> "np.ndarray":
-        """Vectorized slot resolution for one run's body-key column
-        (``key = msg_idx*6 + effective_qos*2 + retain``): one numpy
-        table gather for every delivery whose body the window already
-        encoded, `slot_for` only for the run's NEW unique bodies —
-        per-delivery Python vanishes after a window's first few
-        clients.  Returns the int64 ``body`` (arena slot) column."""
+        """Vectorized slot resolution for a window's body-key column
+        (``key = msg_idx*6 + effective_qos*2 + retain``) at one
+        protocol version: the distinct keys are told by one scatter
+        into a flag table (no sort), `slot_for` encodes each body the
+        table lacks — once a (message, QoS, retain, version) — and
+        one gather maps the whole column.  The window dispatch calls
+        this once a version present, never once a run.  Returns the
+        int64 ``body`` (arena slot) column."""
         tbl = self._key_tbl.get(version)
         need = 6 * len(msgs)
         if tbl is None or len(tbl) < need:
             tbl = self._key_tbl[version] = np.full(
                 need, -1, dtype=np.int64
             )
-        body = tbl[keys]
-        if len(body) and body.min() < 0:
-            for key in np.unique(keys[body < 0]).tolist():
-                i, qr = divmod(key, 6)
-                qos, retain = divmod(qr, 2)
-                tbl[key] = self.slot_for(
-                    msgs[i], qos, bool(retain), version
-                )
-            body = tbl[keys]
-        return body
+        new = np.zeros(len(tbl), dtype=bool)
+        new[keys] = True
+        new &= tbl < 0
+        for key in np.flatnonzero(new).tolist():
+            i, qr = divmod(key, 6)
+            qos, retain = divmod(qr, 2)
+            tbl[key] = self.slot_for(msgs[i], qos, bool(retain), version)
+        return tbl[keys]
 
     def span_arrays(self) -> Tuple:
         """The span tables as contiguous int64 arrays (lazily rebuilt

@@ -282,7 +282,7 @@ class WindowRecord(Laps):
         "wall0", "n_msgs", "n_deliveries", "n_clients", "n_clips",
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
         "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
-        "rules_firings", "rules_firings_run",
+        "rules_firings", "rules_firings_run", "n_clients_plain",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -291,6 +291,9 @@ class WindowRecord(Laps):
         self.n_msgs = n_msgs
         self.n_deliveries = 0
         self.n_clients = 0
+        # of them, the runs the window's columnar pass served whole
+        # (`Broker._dispatch_columns`: no exceptional branch taken)
+        self.n_clients_plain = 0
         self.n_clips = 0  # compact clips re-matched on the dense kernel
         # delivery rows the device decide step was given, and the
         # bucket it ran them in (both 0 where the host decided)
@@ -368,6 +371,7 @@ class WindowRecord(Laps):
             "n_msgs": self.n_msgs,
             "n_deliveries": self.n_deliveries,
             "n_clients": self.n_clients,
+            "n_clients_plain": self.n_clients_plain,
             "n_clips": self.n_clips,
             "decide_rows": self.decide_rows,
             "decide_rows_padded": self.decide_rows_padded,

@@ -18,6 +18,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
+import numpy as np
+
 
 @dataclass
 class Alarm:
@@ -349,6 +351,27 @@ class SlowSubs:
             heapq.heappush(self._heap, item)
         elif item > self._heap[0]:
             heapq.heapreplace(self._heap, item)
+
+    def slowest(self, lat_ms: "np.ndarray") -> "np.ndarray":
+        """Positions in ``lat_ms`` — a window's delivery latencies in
+        the order `record` would have been called — that such a run
+        of calls would leave on the board: not under the threshold,
+        not under a full board's lowest, and of those the ``top_k``
+        largest, the later of equals first (the heap's own order:
+        latency, then sequence).  ONE pass a window where the scan
+        was one call a delivery; the caller records the few positions
+        returned, in order, and the board reads as it would have."""
+        floor = self.threshold_ms
+        if len(self._heap) >= self.top_k:
+            floor = max(floor, self._heap[0][0])
+        pos = np.flatnonzero(lat_ms >= floor)
+        k = self.top_k
+        if len(pos) > k:
+            lat = lat_ms[pos]
+            pos = pos[lat >= np.partition(lat, -k)[-k]]
+            if len(pos) > k:  # equals at the cut: the later ones stay
+                pos = np.sort(pos[np.lexsort((pos, lat_ms[pos]))[-k:]])
+        return pos
 
     def tick(self, now: Optional[float] = None) -> int:
         """Drop entries older than ``expire_interval``; returns the

@@ -109,7 +109,7 @@ def _build_world(seed):
     return clients, windows
 
 
-def _run_world(clients, windows, mode):
+def _run_world(clients, windows, mode, setup=None, ts=1.0e9):
     b = _broker(
         decide=mode if mode in ("host", "dev") else None,
         columns=mode != "scalar",
@@ -124,6 +124,11 @@ def _run_world(clients, windows, mode):
             True, c["cid"], ch, max_inflight=c["max_inflight"]
         )
         session.upgrade_qos = c["upgrade"]
+        # a case's own session state: where the packet-id counter
+        # stands, and packet ids already in flight
+        session._next_pid = c.get("next_pid", session._next_pid)
+        for pid in c.get("held", ()):
+            session.inflight.insert(pid, _held_entry())
         for s in c["subs"]:
             opts = SubOpts(
                 qos=s["qos"], retain_as_published=s["rap"],
@@ -132,8 +137,10 @@ def _run_world(clients, windows, mode):
             session.subscribe(s["flt"], opts)
             b.subscribe(c["cid"], s["flt"], opts)
         chans[c["cid"]] = ch
+    if setup is not None:
+        setup(b, chans, mode)
     counts = []
-    ts = 1.0e9
+    plain = []
     for win in windows:
         msgs = [
             Message(
@@ -144,6 +151,8 @@ def _run_world(clients, windows, mode):
             for w in win
         ]
         counts.append(b.publish_many(msgs))
+        rec = b.profiler.windows(1)[0]
+        plain.append((rec["n_clients_plain"], rec["n_clients"]))
     wires = {
         cid: b"".join(bytes(x) for x in ch.writes)
         for cid, ch in chans.items()
@@ -162,16 +171,33 @@ def _run_world(clients, windows, mode):
         for c in clients
     }
     stats = b.router.engine.stats()
-    return counts, wires, sent, inflights, stats
+    board = [(e["clientid"], e["topic"]) for e in b.slow_subs.top()]
+    return counts, wires, sent, inflights, stats, plain, board
+
+
+def _held_entry():
+    from emqx_tpu.broker.session import _InflightEntry, _PUBLISHING
+
+    return _InflightEntry(
+        _PUBLISHING, Message(topic="held", qos=1), 1, 1.0e9
+    )
 
 
 @pytest.mark.skipif(_native is None, reason="native dispatchasm unavailable")
 @pytest.mark.parametrize("seed", [1, 2, 7, 23, 41])
-def test_three_paths_bit_identical(seed):
+@pytest.mark.parametrize("stamp", ["old", "unstamped"])
+def test_three_paths_bit_identical(seed, stamp):
+    # an old stamp puts every window past the slow-subs threshold:
+    # the board the window's one pass leaves is the one a scan a run
+    # leaves (equal latencies: the later delivery stays)
+    ts = 1.0e9 if stamp == "old" else 0.0
     clients, windows = _build_world(seed)
-    scalar = _run_world(clients, windows, "scalar")
-    host = _run_world(clients, windows, "host")
-    dev = _run_world(clients, windows, "dev")
+    scalar = _run_world(clients, windows, "scalar", ts=ts)
+    host = _run_world(clients, windows, "host", ts=ts)
+    dev = _run_world(clients, windows, "dev", ts=ts)
+    assert sum(p for p, _ in host[5]) > 0 and host[5] == dev[5]
+    assert scalar[6] == host[6] == dev[6]
+    assert len(scalar[6]) == (10 if stamp == "old" else 0)
     for other, label in ((host, "host"), (dev, "dev")):
         assert scalar[0] == other[0], (label, "counts")
         for cid in scalar[1]:
@@ -189,6 +215,115 @@ def test_three_paths_bit_identical(seed):
         )
         for pkt in C.StreamParser(version=version).feed(wire):
             assert pkt.type == C.PUBLISH
+
+
+def _client(cid, flt="t/#", qos=1, **kw):
+    sub = {"flt": flt, "qos": qos, "rap": False, "no_local": False,
+           "subid": None}
+    sub.update({k: kw.pop(k) for k in list(kw) if k in sub})
+    return {"cid": cid, "version": C.MQTT_V5, "upgrade": False,
+            "max_inflight": 32, "subs": [sub], **kw}
+
+
+def _window(n, qos=1, frm="pub", retain=False):
+    return [{"topic": f"t/{i}", "qos": qos, "retain": retain,
+             "payload": b"p%d" % i, "from": frm} for i in range(n)]
+
+
+def _closes_midway(b, chans, mode):
+    """`b1`'s channel starts closing while the window is dispatched:
+    in the columns path when the LAST run corks (b1 is planned by
+    then, the splice has not run), in the scalar path on b1's own
+    cork (before its one write): the same wire either way."""
+    victim = chans["b1"]
+    trigger = chans["b2" if mode != "scalar" else "b1"]
+    real = trigger.cork
+
+    def cork():
+        victim._closing = True
+        real()
+
+    trigger.cork = cork
+
+
+# what the window's columnar pass newly decides, each held to the
+# scalar referee: (clients, windows, set-up, plain runs of n a window)
+_PASS_WORLDS = {
+    # both protocol versions planned in one splice: one key_slots a
+    # version over that version's rows
+    "v4_and_v5": (
+        [_client("a0", version=C.MQTT_V4), _client("a1"),
+         _client("a2", version=C.MQTT_V4, qos=0), _client("a3", qos=2)],
+        [_window(5, qos=2, retain=True), _window(3)],
+        None, [(4, 4), (4, 4)],
+    ),
+    # the id block would pass 65,535: the exact allocator's list
+    "pid_wraps": (
+        [_client("a0"), _client("a1", next_pid=65533), _client("a2")],
+        [_window(6)], None, [(2, 3)],
+    ),
+    # an id of the block is still in flight: the exact allocator skips
+    "pid_collides": (
+        [_client("a0"), _client("a1", next_pid=10, held=[13]),
+         _client("a2", held=[40])],
+        [_window(6), _window(2)], None, [(2, 3), (3, 3)],
+    ),
+    # no room for the run's four QoS1 deliveries: the per-delivery
+    # loop sends two and queues two, beside two plain runs
+    "no_room": (
+        [_client("a0"), _client("a1", max_inflight=2), _client("a2")],
+        [_window(4)], None, [(2, 3)],
+    ),
+    # a run whose every delivery no-local drops, between plain runs
+    "all_dropped": (
+        [_client("a0"), _client("a1", no_local=True), _client("a2")],
+        [_window(4, frm="a1"), _window(2)], None, [(3, 3), (3, 3)],
+    ),
+    # a channel that starts closing between plan and splice: its blob
+    # is dropped and not counted as sent
+    "closing": (
+        [_client("b0"), _client("b1"), _client("b2")],
+        [_window(3)], _closes_midway, [(3, 3)],
+    ),
+    # sessions that upgrade and sessions that do not, one window:
+    # both QoS variants' columns, entries shared where (message, QoS)
+    # agree
+    "upgrade_mixed": (
+        [_client("a0", qos=2, upgrade=True), _client("a1", qos=0),
+         _client("a2", qos=0, upgrade=True), _client("a3", qos=2),
+         _client("a4", qos=1, upgrade=True)],
+        [_window(4, qos=1), _window(3, qos=0), _window(3, qos=2)],
+        None, [(5, 5)] * 3,
+    ),
+}
+
+
+@pytest.mark.skipif(_native is None, reason="native dispatchasm unavailable")
+@pytest.mark.parametrize("case", sorted(_PASS_WORLDS))
+def test_columnar_pass_worlds_match_scalar(case):
+    clients, windows, setup, want_plain = _PASS_WORLDS[case]
+    scalar = _run_world(clients, windows, "scalar", setup)
+    for mode in ("host", "dev"):
+        other = _run_world(clients, windows, mode, setup)
+        assert scalar[6] == other[6] != [], (mode, "slow-subs board")
+        assert scalar[0] == other[0], (mode, "counts")
+        for cid in scalar[1]:
+            assert scalar[1][cid] == other[1][cid], (mode, cid)
+        assert scalar[2] == other[2], (mode, "sent metrics")
+        assert scalar[3] == other[3], (mode, "inflight")
+        assert other[5] == want_plain, (mode, "plain runs")
+    assert any(scalar[1].values()) and any(scalar[3].values())
+    if case == "closing":
+        assert scalar[1]["b1"] == b"" and scalar[2]["messages.sent"] == 6
+        assert scalar[0] == [[3, 3, 3]]  # counted as delivered, as ever
+    if case == "pid_wraps":
+        assert [p for p, _ in scalar[3]["a1"]] == [
+            1, 2, 3, 4, 65534, 65535
+        ]
+    if case == "pid_collides":
+        assert [p for p, _ in scalar[3]["a1"]][:4] == [11, 12, 13, 14]
+    if case == "no_room":
+        assert len(scalar[3]["a1"]) == 2
 
 
 def test_decide_kernel_twins_bit_identical():
@@ -324,6 +459,91 @@ def test_empty_hook_registry_skips_hook_walk(monkeypatch):
     monkeypatch.setattr(b.hooks, "run", spy)
     b.publish_many([Message(topic="t/x", qos=0)] * 3)
     assert "message.delivered" not in names
+
+
+# ------------------------------- the columnar pass engages, counted
+
+def _exact_broker(n_subs, n_topics, max_inflight=32):
+    """``n_subs`` subscribers over ``n_topics`` exact topics (the
+    benchmark's two exact deployments in small): QoS1 where every
+    subscriber has a topic of its own, QoS 0/1 alternating else."""
+    b = _broker()
+    for i in range(n_subs):
+        cid = f"s{i}"
+        ch = WireChannel(b)
+        s, _ = b.cm.open_session(True, cid, ch, max_inflight=max_inflight)
+        opts = SubOpts(qos=1 if n_topics == n_subs else i // n_topics & 1)
+        s.subscribe(f"x/{i % n_topics}", opts)
+        b.subscribe(cid, f"x/{i % n_topics}", opts)
+    return b
+
+
+@pytest.mark.skipif(_native is None, reason="native dispatchasm unavailable")
+@pytest.mark.parametrize("shape", ["p2p_512", "fanout_4x250", "hook"])
+def test_window_is_served_by_the_columnar_pass(shape, monkeypatch):
+    """A point-to-point window of 512 runs and a fan-out window of
+    4 x 250: every run plain, ONE in-flight entry a (message, QoS),
+    ONE `key_slots` a protocol version, and the ring field that
+    `deliver_plain_run_pct.flood` reads says so; a window with a
+    `message.delivered` hook has no plain run."""
+    import importlib.util
+    import json
+    import os
+
+    from emqx_tpu.broker import session as S
+
+    if shape == "fanout_4x250":
+        b = _exact_broker(1000, 4, max_inflight=4096)
+        msgs = [Message(topic=f"x/{i % 4}", qos=1) for i in range(64)]
+        runs, owed = 1000, 64 * 250
+    else:
+        b = _exact_broker(512, 512)
+        msgs = [Message(topic=f"x/{i}", qos=1) for i in range(512)]
+        runs, owed = 512, 512
+    if shape == "hook":
+        b.hooks.add("message.delivered", lambda cid, ds: None)
+    built = []
+
+    class Entry(S._InflightEntry):
+        __slots__ = ()
+
+        def __init__(self, phase, msg, qos, ts):
+            built.append((id(msg), qos))
+            super().__init__(phase, msg, qos, ts)
+
+    monkeypatch.setattr(S, "_InflightEntry", Entry)
+    slots = []
+    real = C.DispatchEncoder.key_slots
+    monkeypatch.setattr(
+        C.DispatchEncoder, "key_slots",
+        lambda self, msgs, version, keys: slots.append(version)
+        or real(self, msgs, version, keys),
+    )
+    assert sum(b.publish_many(msgs)) == owed
+    (rec,) = b.profiler.windows(1)
+    assert rec["n_clients"] == runs
+    assert rec["n_clients_plain"] == (0 if shape == "hook" else runs)
+    assert len(built) == len(set(built)) == len(msgs)
+    assert slots == [C.MQTT_V5]
+    # the benchmark's reader over this ring, and over a ring of a
+    # program from before the field
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "profiler_ratio",
+        os.path.join(root, "benchmark", "readers", "profiler_ratio.py"),
+    )
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    args = json.load(open(os.path.join(
+        root, "benchmark", "metrics", "deliver_plain_run_pct.flood.json"
+    )))["args"]
+    ring = b.profiler.windows(1)
+    assert reader.read({"ring": ring, "window_s": 1.0}, **args) == (
+        0.0 if shape == "hook" else 100.0
+    )
+    old = [{k: v for k, v in r.items() if k != "n_clients_plain"}
+           for r in ring]
+    assert reader.read({"ring": old, "window_s": 1.0}, **args) is None
 
 
 # ------------------------------------------- sampled-run tracer guard

@@ -9,6 +9,7 @@ import tempfile
 _MGMT_TMP = tempfile.TemporaryDirectory(prefix="emqx-mgmt-")
 
 import aiohttp
+import pytest
 
 from emqx_tpu.broker.listener import BrokerServer
 from emqx_tpu.config import BrokerConfig, ListenerConfig
@@ -148,6 +149,33 @@ def test_slow_subs_topk():
     top = ss.top()
     assert [e["clientid"] for e in top] == ["c", "d"]
     assert top[0]["latency_ms"] == 500.0
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_slow_subs_slowest_is_what_a_record_a_delivery_leaves(seed):
+    """`slowest` picks, from a window's latencies, the positions whose
+    records leave the board a record of EVERY position would have:
+    over boards empty, part full and full, with many equal latencies
+    (one message's deliveries) at the cut."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    one, all_ = (SlowSubs(top_k=5, threshold_ms=10.0) for _ in range(2))
+    for w in range(6):
+        # few distinct values: ties everywhere, some under the threshold
+        lat = rng.choice(
+            rng.uniform(0.0, 40.0 + 20.0 * (w % 3), 4 + seed), 60
+        )
+        picked = one.slowest(lat).tolist()
+        assert picked == sorted(picked) and len(picked) <= 5
+        for t in picked:
+            one.record(f"c{w}.{t}", "t", float(lat[t]))
+        for t in range(len(lat)):
+            all_.record(f"c{w}.{t}", "t", float(lat[t]))
+        assert [(e["clientid"], e["latency_ms"]) for e in one.top()] == [
+            (e["clientid"], e["latency_ms"]) for e in all_.top()
+        ]
+    assert len(one.top()) == 5
 
 
 def test_hierarchical_limiter_levels():
