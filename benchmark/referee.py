@@ -92,6 +92,12 @@ def rule_where(i: int, seq: np.ndarray) -> np.ndarray:
 
 # ------------------------------------------------------------ expected
 
+class Overlap(Exception):
+    """A topic of the pool matches two filters of one subscriber: the
+    broker owes a delivery a subscription, the count below one a
+    subscriber, so the run would blame the program for the live set."""
+
+
 class Expected:
     """What the reference says of the publishes ``seqs`` (any order):
     deliveries a subscriber and firings a rule."""
@@ -107,12 +113,23 @@ class Expected:
         for j, (_cid, flts, _qos) in enumerate(subs):
             for flt in flts:
                 tree.add(flt, j)
-        # subscriber -> pool indices of the topics it must receive
-        # (its filters are disjoint: at most one delivery a publish)
+        # subscriber -> pool indices of the topics it must receive; its
+        # filters are disjoint on the whole pool (at most one delivery a
+        # publish), whichever topics this run came to send
+        sent_on = set(used.tolist())
         hit = [[] for _ in subs]
-        for t in used:
-            for j in set(tree.match(pool[t])):
-                hit[j].append(t)
+        for t, topic in enumerate(pool):
+            owed = tree.match(topic)
+            if len(set(owed)) != len(owed):
+                j = next(j for j in owed if owed.count(j) > 1)
+                raise Overlap(
+                    f"topic {topic!r} matches more than one filter of "
+                    f"subscriber {subs[j][0]!r}: "
+                    f"{[f for f in subs[j][1] if matches(topic, f)]}"
+                )
+            if t in sent_on:
+                for j in owed:
+                    hit[j].append(t)
         self.sub_seqs = [
             self.seqs[np.isin(tix, np.asarray(h, dtype=np.int64))]
             if h else self.seqs[:0] for h in hit

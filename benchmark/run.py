@@ -72,7 +72,10 @@ def load_json(*parts):
 
 def load_cell(name: str, overrides=None):
     """The cell's entry in BENCHMARK.json, its traffic file, its
-    configuration's file, and the metrics that list it."""
+    configuration's file, and the metrics that list it.  A rehearsal's
+    ``overrides`` merge into the two files key by key; a group it lists
+    under ``replace`` is put in whole (one that swaps a generator must
+    not inherit the old one's arguments)."""
     bench = load_json(REPO, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -80,12 +83,19 @@ def load_cell(name: str, overrides=None):
     cell = cells[name]
     work = load_json(HERE, "workloads", name + ".json")
     conf = load_json(HERE, "configs", cell["config"] + ".json")
+    overrides = overrides or {}
+    whole = overrides.get("replace", ())
     for target, over in ((work, "workload"), (conf, "config")):
-        for k, v in ((overrides or {}).get(over) or {}).items():
-            if isinstance(v, dict) and isinstance(target.get(k), dict):
+        for k, v in (overrides.get(over) or {}).items():
+            if (k not in whole and isinstance(v, dict)
+                    and isinstance(target.get(k), dict)):
                 target[k].update(v)
             else:
                 target[k] = v
+    # a generator nobody has is refused here, before the chip is taken
+    for kind, group in (("table", conf["table"]), ("live", conf["live"]),
+                        ("pool", work["topics"])):
+        traffic.generator(kind, group["generator"])
     # which metrics the cell reports is BENCHMARK.json's to say; how
     # each is read is the metric's own file
     metrics = {}
@@ -298,8 +308,7 @@ async def run_cell(args, cell, work, conf, metrics, devs, peak, compiles,
                 fired_rule.append(i), fired_seq.append(int(msg.payload[lo:hi]))
             )
         )])
-    table = dict(conf["table"])
-    pairs, pops = traffic.TABLES[table.pop("generator")](**table)
+    pairs, pops = traffic.generate("table", conf["table"])
     n_table = len(pairs)
     table_depth = max([body_depth(f) for f, _ in pairs[:10]] + [0])
     t = time.monotonic()
@@ -323,8 +332,7 @@ async def run_cell(args, cell, work, conf, metrics, devs, peak, compiles,
         port = server.listeners[0].port
 
         # ---------------------------------------- subscribers, then fold
-        live = dict(conf["live"])
-        subs = traffic.LIVE[live.pop("generator")](**live)
+        subs = traffic.generate("live", conf["live"])
         n_live_filters = len({f for _, flts, _ in subs for f in flts})
         t = time.monotonic()
         mark = compiles.mark()
@@ -675,7 +683,7 @@ def main(argv=None, fault=None, overrides=None) -> int:
             args, cell, work, conf, metrics, devs, peak, compiles, fault,
             trace_dir,
         ))
-    except Refused as e:
+    except (Refused, traffic.BadGenerator, referee.Overlap) as e:
         print(f"refused: {e}", file=sys.stderr)
         return 1
     finally:

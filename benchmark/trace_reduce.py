@@ -7,10 +7,12 @@ a chip.  Busy time is the union of the intervals in which an XLA
 operation ran on the device; a kernel's time is the sum of the device
 durations of the XLA modules (jitted programs) that carry its name.
 
-The program has no `named_scope` or `TraceAnnotation` yet: kernels are
-told apart by the module names JAX gives them (``jit_<function>``), and
-idle gaps are named from the benchmark's side, by the profiler ring's
-stage laps put on the trace's clock.
+The program's ``emqx/<name>`` `TraceAnnotation`s are in the chip's
+``.xplane.pb`` (host planes) and nothing here reads them yet; its
+kernels' `named_scope`s were not seen there.  So kernels are told apart
+by the module names JAX gives them (``jit_<function>``), and idle gaps
+are named from the benchmark's side, by the profiler ring's stage laps
+put on the trace's clock.
 """
 
 import glob

@@ -2,15 +2,28 @@
 subscribers, topic pools and schedules, all from parameters in the
 configuration's and the cell's data files and from ``--seed``.
 
-Imports nothing of the program (`emqx_tpu`) and no JAX.  The fleet
-generators are copies of `bench.make_filters` / `bench.make_topics`
-and of `chip_smoke.rule_sql` / `live_filters` / `payload_of` (see
-PERF.md, Open questions: the originals are a later PR's to delete).
+A data file's ``table``, ``live`` or ``topics`` group names its
+``generator``; the group's other keys are that generator's arguments.
+`generator` finds it by name: the six built-ins below first (`TABLES`,
+`LIVE`, `POOLS`), then ``generators/<name>.py`` and its function
+``table``, ``live`` or ``pool`` (README.md, "A generator", has the
+contract).  So a later deployment brings its generators as a new file.
+
+Imports nothing of the program (`emqx_tpu`) and no JAX, and neither
+does a generator file.  The fleet generators are copies of
+`bench.make_filters` / `bench.make_topics` and of `chip_smoke.rule_sql`
+/ `live_filters` / `payload_of` (see PERF.md, Open questions: the
+originals are a later PR's to delete).
 
 Every seed gets the SAME multiset of topics, gaps and sizes in another
 order: pools are drawn from the fixed ``pool_seed`` of the data file,
 ``--seed`` only permutes them.  So two seeds do the same work.
 """
+
+import importlib.util
+import inspect
+import os
+import re
 
 import numpy as np
 
@@ -156,6 +169,63 @@ def pool_exact(rng, pool: int, pops):
 POOLS = {"fleet_zipf": pool_fleet_zipf, "exact_topics": pool_exact}
 
 
+# ------------------------------------------------- generators by name
+
+GENERATORS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "generators")
+BUILT_IN = {"table": TABLES, "live": LIVE, "pool": POOLS}
+
+
+class BadGenerator(Exception):
+    """A data file names a generator nobody has, or hands one an argument
+    it does not take; `run.py` refuses the run."""
+
+
+def generator(kind: str, name: str):
+    """The ``kind`` (``table``, ``live`` or ``pool``) generator a data
+    file calls ``name``: the built-in of that name, else the function
+    ``kind`` of ``generators/<name>.py``.  A file may not bear a
+    built-in's name: a new file never changes what a cell that is there
+    runs."""
+    path = os.path.join(GENERATORS, f"{name}.py")
+    on_file = re.fullmatch(r"\w+", str(name)) and os.path.exists(path)
+    if on_file and any(name in d for d in BUILT_IN.values()):
+        raise BadGenerator(
+            f"{path} bears the name of a built-in generator of "
+            f"traffic.py: give the file another name"
+        )
+    if name in BUILT_IN[kind]:
+        return BUILT_IN[kind][name]
+    if not on_file:
+        raise BadGenerator(
+            f"no {kind} generator {name!r}: not among traffic.py's "
+            f"{sorted(BUILT_IN[kind])}, and there is no {path}"
+        )
+    spec = importlib.util.spec_from_file_location("generator_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    if not callable(getattr(mod, kind, None)):
+        raise BadGenerator(f"{path} has no function {kind!r}")
+    return getattr(mod, kind)
+
+
+def generate(kind: str, group: dict, *args, **kwargs):
+    """What the generator a data file's ``group`` names makes of the
+    group's other keys.  A key it does not take is refused, not left to
+    run as the default; so is a value it raises `ValueError` over."""
+    group = dict(group)
+    name = group.pop("generator")
+    fn = generator(kind, name)
+    try:
+        inspect.signature(fn).bind(*args, **kwargs, **group)
+    except TypeError as e:
+        raise BadGenerator(f"{kind} generator {name!r}: {e}") from None
+    try:
+        return fn(*args, **kwargs, **group)
+    except ValueError as e:
+        raise BadGenerator(f"{kind} generator {name!r}: {e}") from e
+
+
 def topic_pool(spec: dict, pops, seed: int, publishers: int):
     """The cell's topic pool in this seed's order.  Publish ``seq`` goes
     out on connection ``seq % publishers`` with topic
@@ -163,9 +233,8 @@ def topic_pool(spec: dict, pops, seed: int, publishers: int):
     count keeps its order (one topic a publisher, as the exact cell
     wants); any other is permuted by the seed."""
     spec = dict(spec)
-    gen = POOLS[spec.pop("generator")]
     pool_seed = spec.pop("pool_seed", 1)
-    pool = gen(np.random.default_rng(pool_seed), pops=pops, **spec)
+    pool = generate("pool", spec, np.random.default_rng(pool_seed), pops=pops)
     if len(pool) > publishers:
         order = np.random.default_rng(seed).permutation(len(pool))
         pool = [pool[i] for i in order]

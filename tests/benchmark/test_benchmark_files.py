@@ -3,9 +3,11 @@ and stay inside the contract's limits (checked here so that a later PR
 that adds a cell or a metric as data finds its slip before the driver
 does)."""
 
+import ast
 import json
 import os
 import re
+import sys
 
 import pytest
 
@@ -16,6 +18,11 @@ BENCH = os.path.join(REPO, "benchmark")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+sys.path.insert(0, BENCH)
+
+import traffic  # noqa: E402
 
 
 def load(*parts):
@@ -139,3 +146,54 @@ def test_paths_hold_every_benchmark_file():
                 continue
             for f in files:
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", f), f
+
+
+DATA_FILES = sorted(
+    os.path.join(d, f) for d in ("configs", "workloads")
+    for f in os.listdir(os.path.join(BENCH, d)) if f.endswith(".json")
+)
+GENERATOR_FILES = sorted(
+    f[:-3] for f in os.listdir(os.path.join(BENCH, "generators"))
+    if f.endswith(".py")
+)
+
+
+@pytest.mark.parametrize("path", DATA_FILES)
+def test_every_generator_a_data_file_names_resolves(path):
+    data = load(BENCH, path)
+    groups = {"table": "table", "live": "live", "topics": "pool"}
+    named = [(kind, data[g]["generator"]) for g, kind in groups.items()
+             if g in data]
+    assert len(named) == (2 if path.startswith("configs") else 1)
+    for kind, name in named:
+        assert callable(traffic.generator(kind, name))
+
+
+@pytest.mark.parametrize("name", GENERATOR_FILES)
+def test_a_generator_file_keeps_the_contract_of_its_place(name):
+    """Standard library and numpy only; not a built-in's name; has one of
+    the three functions; and something names it: a configuration, a cell
+    or a test (a generator nothing runs is dead weight in `paths`)."""
+    assert NAME.match(name)
+    assert not any(name in d for d in traffic.BUILT_IN.values())
+    path = os.path.join(BENCH, "generators", name + ".py")
+    tree = ast.parse(open(path).read())
+    roots = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            roots |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, "a generator file stands alone"
+            roots.add(node.module.split(".")[0])
+    assert roots <= set(sys.stdlib_module_names) | {"numpy"}, roots
+    defined = {n.name for n in tree.body if isinstance(n, ast.FunctionDef)}
+    assert defined & {"table", "live", "pool"}
+    quoted = re.compile(r"""["']%s["']""" % re.escape(name))
+    users = [
+        os.path.join(root, f)
+        for top in (BENCH, os.path.join(REPO, "tests", "benchmark"))
+        for root, _dirs, files in os.walk(top) for f in files
+        if f.endswith((".json", ".py")) and "generators" not in root
+        and quoted.search(open(os.path.join(root, f)).read())
+    ]
+    assert users, f"nothing names the generator {name!r}"

@@ -159,3 +159,43 @@ def test_every_seed_gets_the_same_work_in_another_order():
     assert not np.allclose(x, y)
     assert np.allclose(np.sort(np.diff(x, prepend=0))[5:-5],
                        np.sort(np.diff(y, prepend=0))[5:-5], atol=0.05)
+
+
+def test_a_topic_on_two_filters_of_one_subscriber_is_refused_by_name():
+    """The broker owes a delivery a subscription, `Expected` counts one
+    a subscriber: a live set that overlaps on the pool is the data
+    file's fault and must not read as `deliveries_duplicated`."""
+    subs = [("sub0", ["tele/a1/+"], 1),
+            ("sub1", ["tele/+/b2", "tele/a1/+", "tele/a2/b2"], 0)]
+    pool = ["tele/a0/b0", "tele/a1/b2", "tele/a1/b1"]
+    with pytest.raises(R.Overlap) as e:
+        R.Expected(pool, subs, 0, np.arange(1))   # the topic was not even sent
+    said = str(e.value)
+    assert "'sub1'" in said and "'tele/a1/b2'" in said
+    assert "tele/+/b2" in said and "tele/a1/+" in said
+    assert "tele/a2/b2" not in said and "sub0" not in said
+    # two subscribers on one topic are fan-out, not overlap
+    exp = R.Expected(pool[::2], subs, 0, np.arange(4))
+    assert exp.n_deliveries == 4
+
+
+@pytest.mark.parametrize("cell", [
+    "fleet-1m-rules.flood-qos1", "fleet-1m-rules.paced-qos1",
+    "exact-1k-fanout.flood-qos1", "p2p-1k.flood-qos1",
+])
+def test_every_cells_live_set_is_disjoint_on_its_whole_pool(cell):
+    """At the cells' own sizes (the table's populations apart from its
+    pairs: only `pops` reaches the pool)."""
+    import json
+
+    bench = os.path.join(REPO, "benchmark")
+    work = json.load(open(os.path.join(bench, "workloads", cell + ".json")))
+    conf = json.load(open(os.path.join(
+        bench, "configs", cell.rsplit(".", 1)[0] + ".json"
+    )))
+    _pairs, pops = TR.generate("table", conf["table"])
+    subs = TR.generate("live", conf["live"])
+    pool = TR.topic_pool(work["topics"], pops, 3000000032,
+                         work["publishers"])
+    exp = R.Expected(pool, subs, 0, np.arange(len(pool)))
+    assert exp.n_deliveries > 0
