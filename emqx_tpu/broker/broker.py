@@ -1143,6 +1143,7 @@ class Broker:
             rec.n_clips = sum(
                 name == "dense_rematch" for name, _, _ in timings
             )
+            rec.n_host_rows = info.get("host_rows", 0)
             rec.path = path
             rec.breaker_open = self.router.engine.breaker_open
         remote: Optional[List[Set[str]]] = None

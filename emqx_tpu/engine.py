@@ -14,7 +14,12 @@ threshold.  Deletions are masked out of stale device results by fid.
 
 Any topic the kernel flags (frontier overflow, match-cap overflow, too
 deep) is re-matched on the `HostTrie` oracle, so results are always
-exact regardless of kernel capacity bounds.
+exact regardless of kernel capacity bounds.  Those rows are counted
+(``stats()["host_rows"]``, a window's ``info["host_rows"]``) and timed
+(the span ``overlay_host``), and a build whose table can need a wider
+frontier than ``f_width`` (`Automaton.frontier_need`, also in
+``stats()``) logs one warning: a window that reads ``dev`` may still
+have had most of its rows matched here on the host.
 """
 
 from __future__ import annotations
@@ -547,6 +552,9 @@ class MatchEngine:
         # 32 B a padded row on the device->host link, against 512 B
         # for the dense layout
         self._ccap_mult = 8
+        # rows of ``dev`` windows that a kernel flagged and `_overlay`
+        # handed to the host trie (bumped under ``_mlock``)
+        self._host_rows = 0
         # (nodes, buckets, levels, batch) classes already shape-warmed
         self._warmed_shapes: Set[Tuple[int, int, int, int]] = set()
         # widest batch bucket the background fold/build threads warm a
@@ -1051,6 +1059,7 @@ class MatchEngine:
                 tp("fold_commit", gen=gen, watermark=snap_seq)
                 self._fold_cache = arena
                 self._dtier = (aut, dev, fid_view)
+                self._warn_frontier(aut, "delta")
                 self._daut_fids = live_fids
                 # tombstones for fids deleted while the fold assembled
                 # (fresh set: an in-flight match's captured snapshot
@@ -1184,6 +1193,7 @@ class MatchEngine:
                 self._n_base,
                 self._build_cache,
             ) = built
+            self._warn_frontier(self._aut, "base")
             self._delta = {}
             self._delta_seq = {}
             self._residual_log = []
@@ -1192,6 +1202,19 @@ class MatchEngine:
             self._drop_delta_aut()
             self._deleted_base = set()
             self._deleted_daut = set()
+
+    def _warn_frontier(self, aut, tier: str) -> None:
+        """One warning a build whose table can need a wider frontier
+        than the kernel is run at: the answers stay exact, but the
+        rows that pass the width are the host trie's."""
+        if aut.frontier_need > self.f_width:
+            import logging
+
+            logging.getLogger("emqx_tpu.engine").warning(
+                "%s automaton can need a frontier of %d, f_width is %d: "
+                "rows whose frontier passes %d are matched on the host",
+                tier, aut.frontier_need, self.f_width, self.f_width,
+            )
 
     def kick_rebuild(self) -> bool:
         """Start a background rebuild NOW if the delta has outgrown
@@ -1280,6 +1303,7 @@ class MatchEngine:
                 self._n_base,
                 self._build_cache,
             ) = built
+            self._warn_frontier(self._aut, "base")
             delta: Dict[Hashable, Tuple[str, ...]] = {}
             for flt, fid in self._pending_inserts:
                 if self._by_fid.get(fid) == flt and fid not in self._deep:
@@ -1566,6 +1590,13 @@ class MatchEngine:
         out["rules_dev_refused"] = self._rul_stats["dev_refused"]
         out["rules_host_us_ewma"] = self._rul_host_us
         out["rules_dev_us_ewma"] = self._rul_dev_us
+        out["host_rows"] = self._host_rows
+        # of the base and the delta automaton, the wider (0: neither)
+        out["frontier_need"] = max(
+            (aut.frontier_need for aut in (self._aut, self._dtier[0])
+             if aut is not None),
+            default=0,
+        )
         return out
 
     # -------------------------------------------------------------- match
@@ -2380,8 +2411,11 @@ class MatchEngine:
         as a device window.  While the profiler is on it also receives
         ``timings``: ``(name, start, dur)`` of the sections timed here
         (``device_wait``, ``expand_codes``, ``dense_rematch`` once a
-        compact clip, ``overlay_lock_wait``, ``overlay``); the caller's
-        ``seq`` in it tags their trace annotations."""
+        compact clip, ``overlay_lock_wait``, ``overlay``, and inside
+        it ``overlay_host`` where a row went to the host trie); the
+        caller's ``seq`` in it tags their trace annotations.  A ``dev``
+        window also leaves ``host_rows`` there: the rows a kernel
+        flagged, which `_overlay` matched on the host."""
         if pending[0] != "dev":
             if info is not None:
                 info["path"] = pending[0]
@@ -2417,10 +2451,13 @@ class MatchEngine:
         tp("match_overlay")
         with self._mlock:
             tm.lap("overlay_lock_wait", then="overlay")
-            out = self._overlay(topics, words, rows, gpos, ovf, snap, dflat)
+            out, n_host = self._overlay(
+                topics, words, rows, gpos, ovf, snap, dflat, tm
+            )
             tm.lap("overlay")
         if info is not None:
             info["timings"] = tm.timings()
+            info["host_rows"] = n_host
         if self.use_device is None and len(words) >= 64:
             cpu_us = (
                 (cpu0 + time.thread_time() - c1) / len(words) * 1e6
@@ -2456,8 +2493,13 @@ class MatchEngine:
         return out
 
     def _overlay(
-        self, topics, words, rows, gpos, ovf, snap, dflat=None
-    ) -> List[Set[Hashable]]:
+        self, topics, words, rows, gpos, ovf, snap, dflat=None, tm=NO_LAPS
+    ) -> Tuple[List[Set[Hashable]], int]:
+        """The window's answers, and how many of its rows the host
+        trie gave: the rows a kernel flagged (``ovf``, base or delta),
+        counted from the flag vector and matched in one pass that
+        ``tm`` times as ``overlay_host``; a window without one pays
+        neither."""
         fid_arr, delta, deep = snap[2], snap[3], snap[4]
         deleted_base, deleted_daut = snap[5], snap[7]
         fids_flat = fid_arr[gpos]
@@ -2470,10 +2512,18 @@ class MatchEngine:
             dper = np.bincount(drows, minlength=len(words))
             dchunks = np.split(dflat_fids, np.cumsum(dper)[:-1])
             ovf = ovf | dovf  # either kernel overflowing -> host row
+        n_host = int(ovf.sum())
+        hosted: Dict[int, Set[Hashable]] = {}
+        if n_host:
+            self._host_rows += n_host
+            t_host = tm.now()
+            for i in np.flatnonzero(ovf).tolist():
+                hosted[i] = self.match_host(words[i])
+            tm.nest("overlay_host", t_host)
         out: List[Set[Hashable]] = []
         for i, ws in enumerate(words):
-            if ovf[i]:
-                out.append(self.match_host(ws))
+            if i in hosted:
+                out.append(hosted[i])
                 continue
             # tombstones are per-generation: a fid deleted from the base
             # may live on (re-inserted) in the delta automaton, so each
@@ -2493,7 +2543,7 @@ class MatchEngine:
             if len(deep):
                 fids |= deep.match_words(ws)
             out.append(fids)
-        return out
+        return out, n_host
 
     def match_batch_flat(self, words: Sequence[T.Words]):
         """Device fast path: encoded topics -> flat row-sorted

@@ -245,6 +245,19 @@ class Laps:
             self._ann = None
             ann.__exit__(None, None, None)
 
+    def now(self) -> float:
+        """A ``perf_counter`` reading to hand to `nest`."""
+        return time.perf_counter()
+
+    def nest(self, name: str, start: float) -> None:
+        """A span from ``start`` (`now`) to this instant, nested inside
+        the lap that is running: it comes out among `timings` beside
+        the lap that holds it, and the chain of laps goes on
+        untouched."""
+        self.spans.append(
+            (name, start - self.t0, time.perf_counter() - start)
+        )
+
     def timings(self) -> List[Tuple[str, float, float]]:
         """The spans as ``(name, start, dur)`` with ``start`` on the
         perf_counter clock, for `WindowRecord.sub`."""
@@ -265,6 +278,12 @@ class _NoLaps:
     def unmark(self) -> None:
         pass
 
+    def now(self) -> float:
+        return 0.0
+
+    def nest(self, name: str, start: float) -> None:
+        pass
+
     def timings(self) -> Tuple:
         return ()
 
@@ -283,6 +302,7 @@ class WindowRecord(Laps):
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
         "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
         "rules_firings", "rules_firings_run", "n_clients_plain",
+        "n_host_rows",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -295,6 +315,9 @@ class WindowRecord(Laps):
         # (`Broker._dispatch_columns`: no exceptional branch taken)
         self.n_clients_plain = 0
         self.n_clips = 0  # compact clips re-matched on the dense kernel
+        # rows of a ``dev`` window that a kernel flagged (frontier or
+        # match cap passed) and the host trie matched instead
+        self.n_host_rows = 0
         # delivery rows the device decide step was given, and the
         # bucket it ran them in (both 0 where the host decided)
         self.decide_rows = 0
@@ -330,6 +353,11 @@ class WindowRecord(Laps):
         self.subs.append(
             (name, None if start is None else start - self.t0, dur_s)
         )
+
+    def nest(self, name: str, start: float) -> None:
+        # a record's spans are its contiguous laps alone (the trace
+        # export walks them end to end): what nests is a sub-stage
+        self.sub(name, time.perf_counter() - start, start)
 
     def lap_parts(self, name: str, wait: str, entered: float,
                   timings: Sequence[Tuple[str, float, float]]) -> None:
@@ -373,6 +401,7 @@ class WindowRecord(Laps):
             "n_clients": self.n_clients,
             "n_clients_plain": self.n_clients_plain,
             "n_clips": self.n_clips,
+            "n_host_rows": self.n_host_rows,
             "decide_rows": self.decide_rows,
             "decide_rows_padded": self.decide_rows_padded,
             "rules_firings": self.rules_firings,

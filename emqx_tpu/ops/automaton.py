@@ -104,6 +104,10 @@ class Automaton:
     max_levels: int
     kernel_levels: int  # deepest filter body + 1: scan length needed
     n_nodes: int
+    # the widest frontier any topic can reach in this trie (a bound,
+    # `_frontier_need`): a kernel run at an ``f_width`` under it flags
+    # the rows that pass it, and the host trie matches those
+    frontier_need: int = 1
 
     def expand(self, val: int) -> Sequence[int]:
         """Device match code (node*2 | kind) -> filter positions."""
@@ -265,6 +269,34 @@ def _build_fp_table(
         return rows, salt
 
 
+def _frontier_need(
+    e_parent: List[np.ndarray],
+    e_tok: List[np.ndarray],
+    e_child: List[np.ndarray],
+    n_nodes: int,
+) -> int:
+    """The widest frontier a topic can reach, from the edges by depth.
+
+    A node's *shape* says which of the levels above it were entered by
+    a ``+`` edge (``2 * shape[parent] + (tok == '+')``, kept as a dense
+    rank within its depth so no depth overflows).  The literal levels
+    of a path are the topic's own words, so a topic reaches at most
+    one node of a shape: the number of distinct shapes at a depth
+    bounds every topic's frontier there, and is reached where the
+    levels above are fully populated.  One pass over the edge arrays a
+    depth, no sort, no loop over nodes."""
+    shape = np.zeros(n_nodes, np.int64)
+    need = n_prev = 1
+    for ep, et, ec in zip(e_parent, e_tok, e_child):
+        key = 2 * shape[ep] + (et == PLUS_TOK)
+        seen = np.zeros(2 * n_prev, bool)
+        seen[key] = True
+        shape[ec] = (np.cumsum(seen) - 1)[key]
+        n_prev = int(seen.sum())
+        need = max(need, n_prev)
+    return need
+
+
 def encode_filters(
     filters: Sequence[Tuple[object, Tuple[str, ...]]],
     tdict: TokenDict,
@@ -416,4 +448,5 @@ def assemble_automaton(
         # frontier dies at depth+1 where the trie has no edges).
         kernel_levels=depth + 1,
         n_nodes=n_nodes,
+        frontier_need=_frontier_need(e_parent, e_tok, e_child, n_nodes),
     )

@@ -82,6 +82,10 @@ class ShardedIndex:
         return max(a.n_nodes for a in self.shards)
 
     @property
+    def frontier_need(self) -> int:
+        return max(a.frontier_need for a in self.shards)
+
+    @property
     def offsets(self) -> List[int]:
         """Global filter-position offset of each shard (shard-local
         positions + offset = position into the concatenated fid list)."""

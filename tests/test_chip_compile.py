@@ -84,15 +84,16 @@ def _compile(fn, *args, **static):
     return compiled, mem
 
 
-def _match_args(sharding, batch):
+def _match_args(sharding, batch, nodes=N_NODES, buckets=N_BUCKETS,
+                levels=LEVELS):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     return (
-        s((N_BUCKETS, 16), jnp.int32),   # fp_rows
-        s((N_NODES, 8), jnp.int32),      # node_rows
+        s((buckets, 16), jnp.int32),     # fp_rows
+        s((nodes, 8), jnp.int32),        # node_rows
         s((), jnp.uint32),               # salt
-        s((batch, LEVELS), jnp.int32),   # tokens
+        s((batch, levels), jnp.int32),   # tokens
         s((batch,), jnp.int32),          # lengths
         s((batch,), jnp.bool_),          # dollar
     )
@@ -108,6 +109,22 @@ def test_match_batch_compact_10m_subs(one_chip, batch):
     # row); what the resident layout pads them to only the chip's
     # memory_stats() says
     assert mem.argument_size_in_bytes >= N_NODES * 32 + N_BUCKETS * 64
+
+
+@pytest.mark.parametrize("nodes,buckets,levels", [
+    (262_144, 262_144, 9),   # the base: 171,117 nodes, 7 id levels + root
+    (4_096, 2_048, 17),      # the live delta: scans `max_levels` + 1
+], ids=["base", "delta"])
+def test_match_batch_compact_plus_100k_at_32_lanes(one_chip, nodes, buckets,
+                                                   levels):
+    """`plus-100k` (PR 33) runs the kernel at `f_width` 32, a shape no
+    other deployment compiles: a sort of 64 candidates a level and a
+    32 x 32 `in_prev` compare, at a full window of 4,096."""
+    _compile(
+        match_batch_compact,
+        *_match_args(one_chip, 4096, nodes, buckets, levels),
+        f_width=32, m_cap=M_CAP, c_cap=8 * 4096,
+    )
 
 
 @pytest.mark.parametrize("batch", [16, 4096])

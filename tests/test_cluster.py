@@ -553,7 +553,13 @@ def test_clean_session_churn_does_not_leak_registry():
             c = TestClient(srv_a.listeners[0].port, f"churn-{i}")
             await c.connect(clean_start=True)
             await c.disconnect()
-        await settle(0.3)
+        # the close announcements replicate within a heartbeat on a
+        # quiet box; under the suite's six workers it can take longer
+        for _ in range(100):
+            await settle(0.05)
+            if not any(cid.startswith("churn-")
+                       for node in (a, b) for cid in node.clients):
+                break
         assert not [
             cid for cid in a.clients if cid.startswith("churn-")
         ], a.clients
