@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import asyncio
+from typing import Callable, List
 
 
 async def cancel_and_wait(task: asyncio.Task, poll: float = 0.5) -> None:
@@ -20,3 +21,26 @@ async def cancel_and_wait(task: asyncio.Task, poll: float = 0.5) -> None:
         await task
     except BaseException:
         pass
+
+
+class Gate(asyncio.Event):
+    """An `asyncio.Event` that a callback can wait for as a coroutine
+    does: ``call(fn)`` runs ``fn()`` inside the next `set` (at once
+    while set).  For a waiter with no coroutine to park, such as a
+    transport's ``data_received``."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self._calls: List[Callable[[], None]] = []
+
+    def call(self, fn: Callable[[], None]) -> None:
+        if self.is_set():
+            fn()
+        else:
+            self._calls.append(fn)
+
+    def set(self) -> None:
+        super().set()
+        calls, self._calls = self._calls, []
+        for fn in calls:
+            fn()

@@ -24,7 +24,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from ..aio import cancel_and_wait
+from ..aio import Gate, cancel_and_wait
 from ..access import AccessControl
 from ..config import BrokerConfig
 from ..engine import MatchEngine
@@ -2811,9 +2811,9 @@ class PublishBatcher:
         self.high_watermark = batch_max
         self.low_watermark = batch_max // 4
         self.global_high = batch_max * 2
-        self._uncongested = asyncio.Event()
+        self._uncongested = Gate()
         self._uncongested.set()
-        self._source_waits: Dict[object, asyncio.Event] = {}
+        self._source_waits: Dict[object, Gate] = {}
 
     def depth(self) -> int:
         return self._total + self._inflight_msgs()
@@ -2843,18 +2843,24 @@ class PublishBatcher:
             )
             ev = self._source_waits.get(source)
             if ev is None:
-                ev = self._source_waits[source] = asyncio.Event()
+                ev = self._source_waits[source] = Gate()
             ev.clear()
             self._uncongested.clear()
             return True
         return False
 
-    async def wait_uncongested(self, source: object = None) -> None:
+    def _release_gate(self, source: object) -> Gate:
         ev = self._source_waits.get(source)
-        if ev is not None:
-            await ev.wait()
-        else:
-            await self._uncongested.wait()
+        return ev if ev is not None else self._uncongested
+
+    async def wait_uncongested(self, source: object = None) -> None:
+        await self._release_gate(source).wait()
+
+    def when_uncongested(self, source: object, resume) -> None:
+        """`wait_uncongested` for a reader with no coroutine to park
+        (`Connection.data_received`): ``resume()`` runs inside the
+        release that would wake the waiter."""
+        self._release_gate(source).call(resume)
 
     def _maybe_release(self) -> None:
         """Dispatch-side: wake paused sources whose lanes drained to
@@ -2908,8 +2914,9 @@ class PublishBatcher:
 
     async def _landed(self) -> bool:
         """Give the loop the turns a readable socket needs to land its
-        publishes in the lanes (`data_received`, the connection's read
-        task, `handle_in`); True if any did.  For a window whose
+        publishes in the lanes (`data_received` and its `handle_in`;
+        where a coroutine reads, the reader's wake-up and the read
+        task between them); True if any did.  For a window whose
         deadline ran out while the loop was elsewhere (a predecessor's
         dispatch holds it for a whole window's writes): no socket was
         read meanwhile, so what the publishers sent since is still

@@ -17,6 +17,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ..access import ClientInfo, PUBLISH, SUBSCRIBE
+from ..aio import Gate
 from ..codec import mqtt as C
 from ..message import Message
 from .. import topic as T
@@ -127,7 +128,7 @@ class Channel:
         self._defer_tail = None
         self._defer_tasks: set = set()
         self._defer_depth = 0
-        self._defer_drained: Optional[asyncio.Event] = None
+        self._defer_drained: Optional[Gate] = None
         self.DEFER_HIGH = 256
         self.DEFER_LOW = 64
         # write coalescing: while corked (dispatch window / batched ack
@@ -266,7 +267,7 @@ class Channel:
     async def wait_defer_drain(self) -> None:
         while self._defer_depth > self.DEFER_LOW and not self._closing:
             if self._defer_drained is None:
-                self._defer_drained = asyncio.Event()
+                self._defer_drained = Gate()
             self._defer_drained.clear()
             # depth transitions happen in done-callbacks on this same
             # loop: no await between the check and the wait, so no
@@ -274,6 +275,18 @@ class Channel:
             if self._defer_depth <= self.DEFER_LOW:
                 return
             await self._defer_drained.wait()
+
+    def when_defer_drained(self, resume) -> None:
+        """`wait_defer_drain` for a reader with no coroutine to park
+        (`Connection.data_received`): ``resume()`` runs once the
+        chain is down to ``DEFER_LOW``, at once if it is."""
+        if self._defer_depth <= self.DEFER_LOW or self._closing:
+            resume()
+            return
+        if self._defer_drained is None:
+            self._defer_drained = Gate()
+        self._defer_drained.clear()
+        self._defer_drained.call(resume)
 
     def _defer(self, coro) -> None:
         """Chain an async continuation behind any previously deferred

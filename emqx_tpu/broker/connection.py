@@ -1,19 +1,30 @@
-"""Asyncio TCP connection: the owning loop for one client socket.
+"""Asyncio TCP connection: the owner of one client socket.
 
 Re-creates `emqx_connection` (/root/reference/apps/emqx/src/
 emqx_connection.erl:371-386 run_loop, :750-777 parse_incoming): reads
 socket chunks into the incremental `StreamParser`, feeds packets to the
 channel FSM, serializes outgoing packets, and drives the keepalive /
 retry timers that the reference hangs off its process timers.
+
+A socket's bytes reach `Connection._read` one of two ways, chosen once
+from what the connection is.  On a plain TCP or TLS listener the
+`Connection` is the transport's protocol: `data_received` keeps a read
+and tells the listener's `ReadTurn`, which handles the reads of one
+loop turn together once the turn's last ``recv`` is back, with no
+task, future or coroutine a read, and what the coroutine awaits is
+reading paused and resumed (a limiter's pause between two packets of
+one read among them).  A WebSocket stream is no transport and keeps
+the coroutine, `run`, over a reader / writer pair.
 """
 
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import socket
 import time
-from typing import List, Optional
+from typing import Callable, Iterator, List, Optional
 
 from .. import failpoints
 from ..codec import mqtt as C
@@ -26,32 +37,107 @@ _TIMER_TICK = 5.0  # keepalive/retry check cadence
 _ACKS = frozenset((C.PUBACK, C.PUBREC, C.PUBREL, C.PUBCOMP))
 
 
-class Connection:
+class ReadTurn:
+    """The reads of one loop turn on a direct listener, handled one
+    after the other once the turn's last ``recv`` is back: one
+    ``call_soon`` a turn, nothing a read.  Why not inside
+    `data_received`: the same parse and `handle_in` cost twice as
+    much right after a ``recv`` as in a row (the chip's host, PR 34:
+    a PUBLISH read 29 us in a row, 57 between system calls, and a
+    turn of a loaded broker holds a hundred reads; the cause was not
+    witnessed: that a system call leaves the caches and the address
+    translations cold is a hypothesis that fits the two orders'
+    readings).  The run is queued during the turn, ahead of whatever
+    the next poll finds readable, so a pause that a read asks for
+    still takes effect before the connection's next ``recv``; and a
+    transport that ends the connection in the turn of its last read
+    (an EOF or a TLS ``close_notify`` behind the data) has that read
+    handled first, by the connection."""
+
+    def __init__(self) -> None:
+        self._conns: List["Connection"] = []
+
+    def add(self, conn: "Connection") -> None:
+        if not self._conns:
+            asyncio.get_running_loop().call_soon(self._run)
+        self._conns.append(conn)
+
+    def _run(self) -> None:
+        conns, self._conns = self._conns, []
+        for conn in conns:
+            try:
+                conn._handle_reads()
+            except Exception:
+                # (a boundary that must keep running: the turn's other
+                # reads are off their sockets already)
+                log.exception("read turn: a connection failed")
+
+
+class Connection(asyncio.Protocol):
+    """With a reader / writer pair: the coroutine path, `run`.  With
+    neither: a protocol for ``loop.create_server``, whose transport
+    is the writer from `connection_made` on; the listener's
+    ``admit(conn)`` is then asked whether it has room, its ``turn``
+    told of every read and ``on_lost(conn)`` told once the connection
+    has ended and the transport is gone."""
+
     def __init__(
         self,
         broker: Broker,
-        reader: asyncio.StreamReader,
-        writer: asyncio.StreamWriter,
+        reader: Optional[asyncio.StreamReader] = None,
+        writer: Optional[asyncio.StreamWriter] = None,
         mountpoint: Optional[str] = None,
         limiter=None,
+        admit: Optional[Callable[["Connection"], bool]] = None,
+        on_lost: Optional[Callable[["Connection"], None]] = None,
+        turn: Optional[ReadTurn] = None,
     ) -> None:
         self.broker = broker
         self.reader = reader
         self.writer = writer
+        self.mountpoint = mountpoint
         self.limiter = limiter
+        self._admit = admit
+        self._on_lost = on_lost
+        self._turn = turn
+        self.channel: Optional[Channel] = None
+        self._closed = asyncio.Event()
+        self._congested = False
+        self._failed = False  # a send failed on the sender thread
+        self._torn = False  # `_teardown` ran
+        self._timer: Optional[asyncio.Task] = None
+        # the direct path: what was received and is not handled yet
+        # (the turn's read; further ones only behind a limiter's
+        # pause), and the stand-ins for what `run` awaits: why
+        # reading is paused ("lane", "defer", "write", "limiter";
+        # it resumes when no reason is left), the CONNECTING idle
+        # timeout, a limiter's pause, why the transport ended inside
+        # that pause (the close waits until it is paid) and whether
+        # the transport is gone
+        self._reads: List[bytes] = []
+        self._paused: set = set()
+        self._idle: Optional[asyncio.TimerHandle] = None
+        self._owed: Optional[asyncio.TimerHandle] = None
+        self._ended: Optional[str] = None
+        self._lost = False
+        if writer is not None:
+            self._attach(writer, getattr(writer, "transport", None))
+
+    def _attach(self, writer, transport) -> None:
+        """The socket is known: the channel, and where writes go."""
+        self.writer = writer
         peername = writer.get_extra_info("peername")
         peer = f"{peername[0]}:{peername[1]}" if peername else "?"
         self.channel = Channel(
-            broker,
+            self.broker,
             send=self._send_packets,
             close=self._close,
             peer=peer,
-            mountpoint=mountpoint,
+            mountpoint=self.mountpoint,
         )
         # outbound high-watermark input: the transport's write buffer
         # is where a stalled subscriber's bytes pile up (WS streams
         # that can't report simply leave the watermark inactive)
-        transport = getattr(writer, "transport", None)
         if transport is not None and hasattr(
             transport, "get_write_buffer_size"
         ):
@@ -67,7 +153,7 @@ class Connection:
         self._slot = -1
         self._handed = False  # the sender may still hold bytes of ours
         self._parked = False  # the transport took parked bytes back
-        snd = broker.sender
+        snd = self.broker.sender
         if snd is not None and self._tbuf is not None and (
             transport.get_extra_info("ssl_object") is None
             and transport.get_extra_info("sslcontext") is None
@@ -84,12 +170,9 @@ class Connection:
                     self._sender = snd
         # (a read's PUBACKs come as one `AckRun`, not as k packets)
         self.parser = C.StreamParser(
-            max_packet_size=broker.config.mqtt.max_packet_size,
+            max_packet_size=self.broker.config.mqtt.max_packet_size,
             ack_runs=True,
         )
-        self._closed = asyncio.Event()
-        self._congested = False
-        self._failed = False  # a send failed on the sender thread
 
     # -------------------------------------------------------- output
 
@@ -214,6 +297,19 @@ class Connection:
             self.broker.alarms.deactivate(name)
 
     def _close(self, reason: str) -> None:
+        self._clear_alarm()
+        self._release_slot()
+        if not self.writer.is_closing():
+            self.writer.close()
+        self._closed.set()
+        if self.reader is None and not self._torn:
+            # (the direct path) no read loop wakes to find the flag:
+            # the teardown runs as it would there, after the caller's
+            # own work (a takeover registers the new channel first)
+            # and whatever the transport still has to flush
+            asyncio.get_running_loop().call_soon(self._teardown, "closed")
+
+    def _clear_alarm(self) -> None:
         if self._congested:
             # a congestion alarm must not outlive its connection
             self._congested = False
@@ -223,10 +319,6 @@ class Connection:
                 else self.channel.peer
             )
             self.broker.alarms.deactivate(f"conn_congestion/{cid}")
-        self._release_slot()
-        if not self.writer.is_closing():
-            self.writer.close()
-        self._closed.set()
 
     def _release_slot(self) -> None:
         """Nothing more goes to the sender thread: it sends what it
@@ -237,15 +329,100 @@ class Connection:
             self._slot = -1
             self._handed = False
 
+    def _teardown(self, reason: str) -> None:
+        """The connection ends, once, whichever path read it and
+        whoever noticed first; a direct listener is told once it has
+        ended and its transport is gone, whichever comes last."""
+        if not self._torn:
+            self._torn = True
+            if self._timer is not None:
+                self._timer.cancel()
+            for handle in (self._idle, self._owed):
+                if handle is not None:
+                    handle.cancel()
+            self._idle = self._owed = None
+            if self._failed:
+                reason = "peer_reset"
+            self.channel.connection_lost(reason)
+            self._clear_alarm()
+            self._release_slot()
+            if not self.writer.is_closing():
+                self.writer.close()
+            self._closed.set()
+        if self._lost and self._on_lost is not None:
+            on_lost, self._on_lost = self._on_lost, None
+            on_lost(self)
+
     # --------------------------------------------------------- input
 
-    async def run(self) -> None:
-        """The connection's receive loop (emqx_connection:run_loop)."""
-        timer = asyncio.get_running_loop().create_task(self._timers())
-        reason = "closed"
+    def _read(self, data: bytes, t_in: float,
+              direct: bool = False) -> Iterator[float]:
+        """One socket read's work on the loop, the same on both read
+        paths: count, parse, hand each packet to the channel, clock
+        (``t_in``: when the read came back).  A generator only for
+        the limiter, whose pauses sit between packets of one read: it
+        yields the seconds owed and goes on when they are paid.  With
+        no limiter it never yields."""
         # the loop's own clock: two reads a socket read, none a packet
         lc = self.broker.profiler.loop
-        t_in = 0.0
+        limiter = self.limiter
+        channel = self.channel
+        closed = self._closed
+        self.broker.metrics.inc("bytes.received", len(data))
+        # enforcement sits INSIDE the packet loop: one large TCP read
+        # can carry a whole flood, so pausing only future reads would
+        # let the burst straight through.  The pause throttles
+        # processing (and the client, via the unread socket) without
+        # disconnecting — the reference hibernates the socket the same
+        # way.  The FULL deficit is paid: shared listener/zone buckets
+        # hand out long waits under contention and cutting them short
+        # would let the aggregate rate scale with the number of
+        # connections.  (A pause is no work of the loop's: it moves
+        # the read's start forward by what it took.)
+        if limiter is not None:
+            delay = limiter.consume(len(data), 0)
+            if delay > 0:
+                t_in += yield from self._owe(delay)
+        # (an `AckRun` counts as the PUBACKs it carries)
+        n_pubs = n_acks = n_run = n_other = 0
+        for pkt in self.parser.feed(data):
+            t = pkt.type
+            if t == C.ACK_RUN:
+                # (acks cost a limiter nothing: bytes are charged a
+                # read, messages a PUBLISH)
+                n_run += len(pkt.packet_ids)
+            elif t == C.PUBLISH:
+                n_pubs += 1
+                if limiter is not None:
+                    delay = limiter.consume(0, 1)
+                    if delay > 0:
+                        t_in += yield from self._owe(delay)
+            elif t in _ACKS:
+                n_acks += 1
+            else:
+                n_other += 1
+            channel.handle_in(pkt)
+            if closed.is_set():
+                break
+        if lc is not None:
+            n_acks += n_run
+            lc.ingress(t_in, len(data), n_pubs + n_acks + n_other,
+                       n_pubs, n_acks, n_run, direct)
+
+    def _owe(self, delay: float) -> Iterator[float]:
+        """A limiter's pause inside `_read`: yields the seconds owed,
+        returns the seconds it took to pay them."""
+        self.broker.metrics.inc("connection.rate_limited")
+        t0 = time.perf_counter()
+        yield delay
+        return time.perf_counter() - t0
+
+    async def run(self) -> None:
+        """The connection's receive loop (emqx_connection:run_loop),
+        where the bytes come from a reader."""
+        self._timer = asyncio.get_running_loop().create_task(self._timers())
+        reason = "closed"
+        lc = self.broker.profiler.loop
         try:
             idle = self.broker.config.mqtt.idle_timeout
             while not self._closed.is_set():
@@ -259,68 +436,9 @@ class Connection:
                     break
                 if not data:
                     break
-                if lc is not None:
-                    t_in = time.perf_counter()
-                self.broker.metrics.inc("bytes.received", len(data))
-                # (an `AckRun` counts as the PUBACKs it carries)
-                n_pubs = n_acks = n_run = n_other = 0
-                if self.limiter is None:
-                    for pkt in self.parser.feed(data):
-                        t = pkt.type
-                        if t == C.ACK_RUN:
-                            n_run += len(pkt.packet_ids)
-                        elif t == C.PUBLISH:
-                            n_pubs += 1
-                        elif t in _ACKS:
-                            n_acks += 1
-                        else:
-                            n_other += 1
-                        self.channel.handle_in(pkt)
-                        if self._closed.is_set():
-                            break
-                else:
-                    # enforcement sits INSIDE the packet loop: one large
-                    # TCP read can carry a whole flood, so pausing only
-                    # future reads would let the burst straight through.
-                    # The pause throttles processing (and the client,
-                    # via the unread socket) without disconnecting —
-                    # the reference hibernates the socket the same way.
-                    # The FULL deficit is slept (in 1s slices so close
-                    # stays responsive): shared listener/zone buckets
-                    # hand out long waits under contention and cutting
-                    # them short would let the aggregate rate scale
-                    # with the number of connections.
-                    # (a pause is no work of the loop's: it moves the
-                    # read's start forward by what was slept)
-                    delay = self.limiter.consume(len(data), 0)
-                    if delay > 0:
-                        self.broker.metrics.inc("connection.rate_limited")
-                        t_in += await self._pause(delay)
-                    for pkt in self.parser.feed(data):
-                        t = pkt.type
-                        if t == C.ACK_RUN:
-                            # (acks cost nothing here: bytes are
-                            # charged a read, messages a PUBLISH)
-                            n_run += len(pkt.packet_ids)
-                        elif t == C.PUBLISH:
-                            n_pubs += 1
-                            delay = self.limiter.consume(0, 1)
-                            if delay > 0:
-                                self.broker.metrics.inc(
-                                    "connection.rate_limited"
-                                )
-                                t_in += await self._pause(delay)
-                        elif t in _ACKS:
-                            n_acks += 1
-                        else:
-                            n_other += 1
-                        self.channel.handle_in(pkt)
-                        if self._closed.is_set():
-                            break
-                if lc is not None:
-                    n_acks += n_run
-                    lc.ingress(t_in, len(data), n_pubs + n_acks + n_other,
-                               n_pubs, n_acks, n_run)
+                t_in = time.perf_counter() if lc is not None else 0.0
+                for delay in self._read(data, t_in):
+                    await self._pause(delay)
                 await self._drain()
                 batcher = self.broker.batcher
                 if batcher is not None and batcher.congested(self.channel):
@@ -343,29 +461,20 @@ class Connection:
         except asyncio.CancelledError:
             reason = "server_stopped"
         finally:
-            timer.cancel()
-            if self._failed:
-                reason = "peer_reset"
-            self.channel.connection_lost(reason)
-            self._release_slot()
-            if not self.writer.is_closing():
-                self.writer.close()
+            self._teardown(reason)
             try:
                 await self.writer.wait_closed()
             except (ConnectionError, asyncio.CancelledError):
                 pass
 
-    async def _pause(self, delay: float) -> float:
+    async def _pause(self, delay: float) -> None:
         """Sleep a limiter deficit in 1s slices, bailing early when
         the connection is closed (kick/stop must not wait out a long
-        shared-bucket debt).  Returns the seconds slept."""
-        slept = 0.0
+        shared-bucket debt)."""
         while delay > 0 and not self._closed.is_set():
             step = min(delay, 1.0)
             await asyncio.sleep(step)
             delay -= step
-            slept += step
-        return slept
 
     async def _drain(self) -> None:
         try:
@@ -382,4 +491,159 @@ class Connection:
                 self.channel.close("keepalive_timeout")
                 return
             self.channel.retry_deliveries()
-            await self._drain()
+            if self.reader is not None:
+                # (a transport tells its protocol: `pause_writing`)
+                await self._drain()
+
+    # ---------------------------------------- input, the direct path
+    #
+    # Every await of `run` is reading paused and resumed here, with
+    # the same bound on memory and the same order: a pause takes
+    # effect between reads, as the awaits sit after the packet loop,
+    # and a paused connection's bytes wait in the kernel's socket
+    # buffer, as they do for a `reader.read` nobody has awaited.
+
+    def connection_made(self, transport: asyncio.Transport) -> None:
+        if self._admit is not None and not self._admit(self):
+            transport.close()  # the listener is full
+            self._closed.set()
+            return
+        self._attach(transport, transport)
+        loop = asyncio.get_running_loop()
+        self._timer = loop.create_task(self._timers())
+        # one handle a connection, nothing a read
+        self._idle = loop.call_later(
+            self.broker.config.mqtt.idle_timeout, self._idle_expired
+        )
+
+    def data_received(self, data: bytes) -> None:
+        if not self._reads:
+            self._turn.add(self)
+        self._reads.append(data)
+
+    def _handle_reads(self) -> None:
+        """Handle what was received, in order, as far as a limiter's
+        pause lets it: the turn's run, and first whoever ends the
+        connection in the turn of its last read."""
+        reads = self._reads
+        while reads and self._owed is None:
+            if self._closed.is_set():
+                reads.clear()
+                return
+            lc = self.broker.profiler.loop
+            t_in = time.perf_counter() if lc is not None else 0.0
+            self._step(self._read(reads.pop(0), t_in, True))
+
+    def _step(self, body: Iterator[float]) -> None:
+        """Run a read's body to its end, or to a limiter's pause:
+        the rest of the read's packets wait in ``body``, in order,
+        and no further read is handled meanwhile.  Then what `run`
+        awaits after a read.  Nothing raised here escapes: one
+        connection's fault may not cost the turn's others their
+        reads."""
+        try:
+            delay = next(body, None)
+            if delay is not None:
+                self._pause_reading("limiter")
+                self._owed = asyncio.get_running_loop().call_later(
+                    delay, self._paid, body
+                )
+                return
+            if self._closed.is_set():
+                return
+            if self._idle is not None and self.channel.state != CONNECTING:
+                self._idle.cancel()
+                self._idle = None
+            batcher = self.broker.batcher
+            if batcher is not None and batcher.congested(self.channel):
+                # (see `run`) resumed by the release `wait_uncongested`
+                # waits for
+                self._pause_reading("lane")
+                batcher.when_uncongested(
+                    self.channel,
+                    functools.partial(self._resume_reading, "lane"),
+                )
+            if self.channel.defer_saturated:
+                self._pause_reading("defer")
+                self.channel.when_defer_drained(
+                    functools.partial(self._resume_reading, "defer")
+                )
+        except C.MqttError as exc:
+            log.debug("codec error from %s: %s", self.channel.peer, exc)
+            self._teardown("frame_error")
+        except (ConnectionResetError, BrokenPipeError):
+            self._teardown("peer_reset")
+        except Exception:
+            log.exception("read from %s failed", self.channel.peer)
+            self._teardown("closed")
+
+    def _paid(self, body: Iterator[float]) -> None:
+        self._owed = None
+        self._step(body)
+        self._handle_reads()
+        if self._owed is not None:
+            return  # the next pause
+        if self._ended is not None:
+            self._teardown(self._ended)
+        else:
+            self._resume_reading("limiter")
+
+    def _pause_reading(self, why: str) -> None:
+        if not self._paused:
+            self.writer.pause_reading()
+        self._paused.add(why)
+
+    def _resume_reading(self, why: str) -> None:
+        self._paused.discard(why)
+        if not self._paused and not self._closed.is_set():
+            self.writer.resume_reading()
+
+    def pause_writing(self) -> None:
+        # the write buffer is over its high-water mark: what
+        # `writer.drain()` waits out
+        self._pause_reading("write")
+
+    def resume_writing(self) -> None:
+        self._resume_reading("write")
+
+    def _idle_expired(self) -> None:
+        self._idle = None
+        if self.channel.state == CONNECTING:
+            self._teardown("idle_timeout")
+
+    # The transport may end the connection in the turn of its last
+    # read, before the turn's run: an EOF behind the data, a TLS
+    # ``close_notify`` in the record after it (a one-shot publisher's
+    # PUBLISH, DISCONNECT, close).  That read is handled first.  If a
+    # limiter pauses it, the transport is gone before the pause is
+    # paid (so too where a write fails inside one): the read's other
+    # packets are handled when it is, as `run` would, and the
+    # connection ends after them (`_paid`).
+
+    def eof_received(self) -> bool:
+        self._handle_reads()
+        if self._owed is None:
+            self._teardown("closed")
+        return False  # the transport closes itself
+
+    def connection_lost(self, exc: Optional[Exception]) -> None:
+        if self.channel is None:
+            return  # never admitted
+        self._handle_reads()
+        self._lost = True
+        reason = (
+            "peer_reset"
+            if isinstance(exc, (ConnectionResetError, BrokenPipeError))
+            else "closed"
+        )
+        if self._owed is not None:
+            self._ended = reason
+        else:
+            self._teardown(reason)
+
+    def stop(self, reason: str) -> Optional[asyncio.Task]:
+        """The listener's stop, on the direct path what cancelling
+        `run` is on the other; the timer task, for the caller to
+        wait for."""
+        self._teardown(reason)
+        return self._timer

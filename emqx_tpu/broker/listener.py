@@ -19,7 +19,7 @@ from typing import Dict, List, Optional
 from ..aio import cancel_and_wait
 from ..config import BrokerConfig, ListenerConfig
 from .broker import Broker
-from .connection import Connection
+from .connection import Connection, ReadTurn
 
 log = logging.getLogger("emqx_tpu.listener")
 
@@ -29,11 +29,18 @@ class Listener:
     (the four transports emqx_listeners starts via esockd/cowboy,
     emqx_listeners.erl:430-447)."""
 
+    # the listener types whose `Connection` is its transport's protocol
+    DIRECT = ("tcp", "ssl")
+
     def __init__(self, broker: Broker, cfg: ListenerConfig) -> None:
         self.broker = broker
         self.cfg = cfg
         self._server: Optional[asyncio.AbstractServer] = None
+        # what counts against `max_connections`: `_on_client` tasks,
+        # or (a direct listener) the connections themselves
         self._conns: set = set()
+        self._direct = False
+        self._turn = ReadTurn()  # a direct listener's reads, a turn
         # listener-aggregate buckets shared by ALL this listener's
         # connections (the hierarchical limiter's middle level)
         self._shared_limiter = None
@@ -155,10 +162,22 @@ class Listener:
         ssl_ctx = (
             self._ssl_context() if self.cfg.type in ("ssl", "wss") else None
         )
-        self._server = await asyncio.start_server(
-            self._on_client, self.cfg.bind, self.cfg.port, ssl=ssl_ctx,
-            reuse_port=self.cfg.reuse_port or None,
-        )
+        # who reads a socket's bytes, chosen from what the listener
+        # is: plain TCP and TLS -> the connection is the transport's
+        # protocol and its reads are handled a loop turn; a WebSocket
+        # (a stream over the reader, no transport) -> a reader, a
+        # writer and the `Connection.run` coroutine
+        self._direct = self.cfg.type in self.DIRECT
+        if self._direct:
+            self._server = await asyncio.get_running_loop().create_server(
+                self._protocol, self.cfg.bind, self.cfg.port, ssl=ssl_ctx,
+                reuse_port=self.cfg.reuse_port or None,
+            )
+        else:
+            self._server = await asyncio.start_server(
+                self._on_client, self.cfg.bind, self.cfg.port, ssl=ssl_ctx,
+                reuse_port=self.cfg.reuse_port or None,
+            )
         log.info(
             "listener %s (%s) started on %s:%d",
             self.cfg.name,
@@ -173,13 +192,37 @@ class Listener:
         # order deadlocks while any client is still connected
         if self._server is not None:
             self._server.close()
-        for task in list(self._conns):
-            task.cancel()
-        if self._conns:
-            await asyncio.gather(*self._conns, return_exceptions=True)
+        if self._direct:
+            tasks = [
+                conn.stop("server_stopped") for conn in list(self._conns)
+            ]
+        else:
+            tasks = list(self._conns)
+            for task in tasks:
+                task.cancel()
+        if tasks:
+            await asyncio.gather(*tasks, return_exceptions=True)
         if self._server is not None:
             await self._server.wait_closed()
             self._server = None
+
+    def _protocol(self) -> Connection:
+        """A direct listener's protocol factory: asked an accepted
+        socket, before its transport (and a TLS handshake) is made."""
+        return Connection(
+            self.broker,
+            mountpoint=self.cfg.mountpoint,
+            limiter=self._make_limiter(),
+            admit=self._admit,
+            on_lost=self._conns.discard,
+            turn=self._turn,
+        )
+
+    def _admit(self, conn: Connection) -> bool:
+        if len(self._conns) >= self.cfg.max_connections:
+            return False
+        self._conns.add(conn)
+        return True
 
     async def _on_client(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
@@ -209,26 +252,18 @@ class Listener:
                 ):
                     writer.close()
                     return
-                stream = WsServerStream(
+                reader = writer = WsServerStream(
                     reader,
                     writer,
                     max_size=self.broker.config.mqtt.max_packet_size * 2,
                 )
-                conn = Connection(
-                    self.broker,
-                    stream,
-                    stream,
-                    mountpoint=self.cfg.mountpoint,
-                    limiter=self._make_limiter(),
-                )
-            else:
-                conn = Connection(
-                    self.broker,
-                    reader,
-                    writer,
-                    mountpoint=self.cfg.mountpoint,
-                    limiter=self._make_limiter(),
-                )
+            conn = Connection(
+                self.broker,
+                reader,
+                writer,
+                mountpoint=self.cfg.mountpoint,
+                limiter=self._make_limiter(),
+            )
             await conn.run()
         finally:
             self._conns.discard(task)

@@ -425,7 +425,7 @@ class WindowRecord(Laps):
 
 class LoopClock:
     """What the event loop does between the windows' stages: every
-    socket read (parse + channel, `Connection.run`) and every socket
+    socket read (parse + channel, `Connection._read`) and every socket
     write (`Connection._send_packets`) adds its interval and counts
     here, two ``perf_counter`` reads each and none a packet.  A write
     handed to the native sender thread (``egress_writes_sender``,
@@ -449,6 +449,7 @@ class LoopClock:
         "egress_s", "egress_writes", "egress_packets", "egress_bytes",
         "egress_in_window_s", "egress_in_window_writes",
         "egress_writes_sender", "egress_bytes_sender", "egress_parked",
+        "ingress_reads_direct",
     )
     BURST_GAP_S = 200e-6
     BURSTS_CAP = 65536
@@ -470,10 +471,13 @@ class LoopClock:
         self._open: Dict[str, List[float]] = {}
 
     def ingress(self, t0: float, n_bytes: int, packets: int,
-                publishes: int, acks: int, acks_run: int = 0) -> None:
+                publishes: int, acks: int, acks_run: int = 0,
+                direct: bool = False) -> None:
         """One socket read's parse + channel work, begun at ``t0``:
         ``acks_run`` of its ``acks`` crossed as `AckRun`s (a run
-        counts as the packets it carries, everywhere here).  A read
+        counts as the packets it carries, everywhere here);
+        ``direct``: handled in the transport's own callback
+        (``ingress_reads_direct``), no task woken for it.  A read
         that held PUBLISH packets alone, or acknowledgements alone,
         is also the cost of its packet type (``ingress_publish_*``,
         ``ingress_ack_*``); a mixed or partial one is in neither."""
@@ -483,6 +487,8 @@ class LoopClock:
         dt = now - t0
         self.ingress_s += dt
         self.ingress_reads += 1
+        if direct:
+            self.ingress_reads_direct += 1
         if publishes == packets:
             if packets:
                 self.ingress_publish_s += dt

@@ -23,7 +23,11 @@ class SysTopics:
         self.broker = broker
         self.node = node_name or broker.config.node_name
         self.started_at = time.time()
-        self._last = 0.0
+        # the first heartbeat comes one interval after boot, as the
+        # reference's: emqx_sys:init/1 only starts the heartbeat and
+        # tick timers (`heartbeat(tick(State))`, each a `start_timer`
+        # of its interval) and publishes nothing itself
+        self._last = self.started_at
 
     def _msg(self, suffix: str, value) -> Message:
         payload = (

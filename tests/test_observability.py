@@ -610,6 +610,26 @@ def test_sys_heartbeat_includes_profiler_summary():
     assert not any(m.topic.endswith("/profiler") for m in msgs2)
 
 
+def test_sys_first_heartbeat_comes_one_interval_after_boot():
+    """As `emqx_sys`, whose ``init/1`` only starts its timers: nothing
+    is published at boot, the first heartbeat when the interval has
+    passed, and one an interval from then on."""
+    from emqx_tpu.sys_topics import SysTopics
+
+    b, _sink = _fanout_broker()
+    b.config.sys.interval = 60.0
+    sys_t = SysTopics(b, node_name="n1")
+    boot = sys_t.started_at
+    assert sys_t.tick(boot + 1.0) == 0  # (the first housekeeping tick)
+    assert sys_t.tick(boot + 59.0) == 0
+    n = sys_t.tick(boot + 60.0)
+    assert n >= 8
+    assert sys_t.tick(boot + 61.0) == 0
+    assert sys_t.tick(boot + 120.0) == n
+    b.config.sys.enable = False
+    assert sys_t.tick(boot + 600.0) == 0
+
+
 # ------------------------------------------------- slow subs / config
 
 
