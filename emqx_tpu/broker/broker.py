@@ -2960,6 +2960,7 @@ class PublishBatcher:
         serializing on it; `_dispatch_loop` consumes results strictly
         in window order (session/publisher ordering)."""
         loop = asyncio.get_running_loop()
+        clock = time.perf_counter
         inflight: asyncio.Queue = asyncio.Queue(
             maxsize=self.pipeline_windows
         )
@@ -2979,6 +2980,14 @@ class PublishBatcher:
                 # flight-recorder entry opens at collection start so
                 # the accumulation wait shows up as its own stage
                 rec = self.broker.profiler.begin(0, source="batcher")
+                # ``collect``: the collector's own work between its
+                # awaits (the pops and the batch list), a sub-stage of
+                # ``batch_wait``.  The stretch running since the record
+                # began ends at the lateness reading before an await
+                # (or at the lap), one more reading after each await:
+                # a flood's window, filled in one stretch, adds none
+                collect = 0.0
+                t_on = rec.t0 if rec is not None else 0.0
                 batch = [self._rr_pop()]
                 # adaptive window: with nothing else queued and the
                 # pipeline idle, flush IMMEDIATELY — a lone publish on
@@ -2987,19 +2996,26 @@ class PublishBatcher:
                 if not (
                     self._total == 0 and self._inflight_count == 0
                 ):
-                    deadline = loop.time() + self.window
+                    deadline = clock() + self.window
                     while len(batch) < limit:
                         if self._total:
                             batch.append(self._rr_pop())
                             continue
-                        late = loop.time() - deadline
+                        now = clock()
+                        late = now - deadline
+                        collect += now - t_on
                         if late >= 0:
                             # a deadline the loop answered more than
                             # a window LATE has not been waited out:
                             # see `_landed`
-                            if late > self.window and await self._landed():
-                                deadline = loop.time() + self.window
-                                continue
+                            if late > self.window:
+                                landed = await self._landed()
+                                now = clock()
+                                if landed:
+                                    t_on = now
+                                    deadline = now + self.window
+                                    continue
+                            t_on = now
                             break
                         self._arrival.clear()
                         try:
@@ -3008,10 +3024,11 @@ class PublishBatcher:
                             )
                         except asyncio.TimeoutError:
                             pass
+                        t_on = clock()
                 msgs = [m for m, _fut, _src in batch]
                 if rec is not None:
                     rec.n_msgs = len(batch)
-                    rec.lap("batch_wait")
+                    rec.sub("collect", collect + rec.lap("batch_wait") - t_on)
                 self._inflight_count += len(batch)
                 # throughput-mode hint for the engine's auto policy:
                 # another window's worth already queued means windows
@@ -3161,27 +3178,36 @@ class PublishBatcher:
                 # its futures: set_result schedules the PUBACK/PUBREC
                 # callbacks via call_soon, and the uncork scheduled
                 # AFTER them (FIFO) flushes a window's worth of acks as
-                # one transport.write per connection
+                # one transport.write per connection.  The turn clock's
+                # ``acks`` is this pass and, in the next iteration, the
+                # callbacks and the uncork between two marks queued
+                # around them (what runs in between is not the acks')
                 corked: List = []
                 seen: Set[int] = set()
-                for _m, fut, src in batch:
-                    if fut is None or src is None or id(src) in seen:
-                        continue
-                    cork = getattr(src, "cork", None)
-                    if cork is None:
-                        continue
-                    seen.add(id(src))
-                    cork()
-                    corked.append(src)
+                soon = asyncio.get_running_loop().call_soon
+                lc = self.broker.profiler.loop
+                if lc is not None:
+                    lc.mark(lc.ACKS)
+                    soon(lc.mark, lc.ACKS)
                 try:
+                    for _m, fut, src in batch:
+                        if fut is None or src is None or id(src) in seen:
+                            continue
+                        cork = getattr(src, "cork", None)
+                        if cork is None:
+                            continue
+                        seen.add(id(src))
+                        cork()
+                        corked.append(src)
                     for (_, fut, _src), n in zip(batch, counts):
                         if fut is not None and not fut.done():
                             fut.set_result(n)
                 finally:
                     if corked:
-                        asyncio.get_running_loop().call_soon(
-                            self._uncork_all, corked, self.broker.sender
-                        )
+                        soon(self._uncork_all, corked, self.broker.sender)
+                    if lc is not None:
+                        soon(lc.mark, lc.TAIL)  # behind the uncork
+                        lc.mark(lc.TAIL)  # the pass ends here
                 self._maybe_release()
             except asyncio.CancelledError:
                 raise

@@ -54,15 +54,24 @@ class ReadTurn:
     (an EOF or a TLS ``close_notify`` behind the data) has that read
     handled first, by the connection."""
 
-    def __init__(self) -> None:
+    def __init__(self, clock=None) -> None:
         self._conns: List["Connection"] = []
+        # the profiler's `LoopClock`, or None: the turn's first read
+        # opens its ``recv`` phase, the run is its ``reads`` phase (a
+        # clock read a turn each, none a read)
+        self._clock = clock
 
     def add(self, conn: "Connection") -> None:
         if not self._conns:
             asyncio.get_running_loop().call_soon(self._run)
+            if self._clock is not None:
+                self._clock.recv()
         self._conns.append(conn)
 
     def _run(self) -> None:
+        clock = self._clock
+        if clock is not None:
+            clock.mark(clock.READS)
         conns, self._conns = self._conns, []
         for conn in conns:
             try:
@@ -71,6 +80,8 @@ class ReadTurn:
                 # (a boundary that must keep running: the turn's other
                 # reads are off their sockets already)
                 log.exception("read turn: a connection failed")
+        if clock is not None:
+            clock.mark(clock.TAIL)
 
 
 class Connection(asyncio.Protocol):
@@ -203,7 +214,7 @@ class Connection(asyncio.Protocol):
         if not handed:
             self.writer.write(data)
         if lc is not None:
-            lc.egress(t_out, len(data), n, handed)
+            lc.egress(t_out, n, handed)
         self._note_buffered()
 
     def _hand_over(self, data: bytes) -> bool:
@@ -406,7 +417,7 @@ class Connection(asyncio.Protocol):
                 break
         if lc is not None:
             n_acks += n_run
-            lc.ingress(t_in, len(data), n_pubs + n_acks + n_other,
+            lc.ingress(t_in, n_pubs + n_acks + n_other,
                        n_pubs, n_acks, n_run, direct)
 
     def _owe(self, delay: float) -> Iterator[float]:

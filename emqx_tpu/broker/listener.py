@@ -40,7 +40,8 @@ class Listener:
         # or (a direct listener) the connections themselves
         self._conns: set = set()
         self._direct = False
-        self._turn = ReadTurn()  # a direct listener's reads, a turn
+        # a direct listener's reads, a turn
+        self._turn = ReadTurn(broker.profiler.loop)
         # listener-aggregate buckets shared by ALL this listener's
         # connections (the hierarchical limiter's middle level)
         self._shared_limiter = None
@@ -319,6 +320,12 @@ class BrokerServer:
         # no-op when the env var is unset — the production default)
         failpoints.load_env()
         self.broker._loop = asyncio.get_running_loop()
+        # the turn clock, from where the served path starts: the loop
+        # is whoever's (`asyncio.run`'s), so its selector is hooked,
+        # not its class chosen; off again in `stop`
+        turn_clock = self.broker.profiler.loop
+        if turn_clock is not None:
+            turn_clock.install(self.broker._loop)
         eng_cfg = self.broker.config.engine
         engine = self.broker.router.engine
         if engine.use_device is not False:
@@ -704,6 +711,9 @@ class BrokerServer:
         )
 
     async def stop(self) -> None:
+        turn_clock = self.broker.profiler.loop
+        if turn_clock is not None:
+            turn_clock.uninstall()  # first: whatever fails below
         # elastic-ops agents first: their loops kick sessions and must
         # not keep firing against a half-torn-down broker
         await self.broker.eviction.stop_evacuation()

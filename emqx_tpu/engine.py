@@ -33,7 +33,7 @@ import numpy as np
 
 from . import failpoints
 from . import topic as T
-from .observability import NO_LAPS, Laps
+from .observability import NO_LAPS, CpuLaps
 from .tp import tp
 from .ops.automaton import Automaton, build_automaton
 from .ops.dictionary import SENTINEL, TokenDict, encode_topics
@@ -2372,18 +2372,22 @@ class MatchEngine:
 
     def _laps(self, seq: int = 0):
         """A lap clock for one submit or finish while the profiler is
-        on, else the no-op: the engine times its own sections and hands
-        them back in the pending handle and in ``info``; the caller
-        lays them on its window's record."""
+        on, else the no-op: the engine times its own sections, wall
+        and (they run on one executor thread) that thread's CPU, and
+        hands them back in the pending handle and in ``info``; the
+        caller lays them on its window's record."""
         prof = self.profiler
-        return Laps(seq) if prof is not None and prof.enabled else NO_LAPS
+        return (
+            CpuLaps(seq) if prof is not None and prof.enabled else NO_LAPS
+        )
 
     @staticmethod
     def submit_timings(pending) -> Sequence[Tuple[str, float, float]]:
         """``(name, start, dur)`` of the sections `match_batch_submit`
         timed (``tokenize``, ``encode``, ``kernel_dispatch``): the last
         element of every pending handle, a subclass's too; ``start``
-        is on the perf_counter clock."""
+        is on the perf_counter clock, and None for a section's CPU
+        seconds (``<name>_cpu``, behind the wall spans)."""
         return pending[-1]
 
     def _flat_submit(self, snap: Tuple, words: Sequence[T.Words],
@@ -2412,8 +2416,10 @@ class MatchEngine:
         ``timings``: ``(name, start, dur)`` of the sections timed here
         (``device_wait``, ``expand_codes``, ``dense_rematch`` once a
         compact clip, ``overlay_lock_wait``, ``overlay``, and inside
-        it ``overlay_host`` where a row went to the host trie); the
-        caller's ``seq`` in it tags their trace annotations.  A ``dev``
+        it ``overlay_host`` where a row went to the host trie; each
+        working section's CPU seconds as ``<name>_cpu`` with no
+        start); the caller's ``seq`` in it tags their trace
+        annotations.  A ``dev``
         window also leaves ``host_rows`` there: the rows a kernel
         flagged, which `_overlay` matched on the host."""
         if pending[0] != "dev":

@@ -230,6 +230,10 @@ class FlightRecorder:
         self._wd_stop: Optional[threading.Event] = None
         self._gc_t0 = 0.0
         self._gc_registered = False
+        # every collection since the callback was armed: seconds
+        # paused, collections (`gc_clock`)
+        self._gc_s = 0.0
+        self._gc_n = 0
         # cross-process broadcast hook: called as on_trigger(id, reason)
         # AFTER the local dump lands (matchclient.flight_broadcast /
         # MatchService relay)
@@ -412,8 +416,10 @@ class FlightRecorder:
         if not self.armed or self._wd_thread is not None:
             return
         if self.gc_stall_ms > 0 and not self._gc_registered:
+            self._gc_s, self._gc_n = 0.0, 0
             gc.callbacks.append(self._gc_cb)
             self._gc_registered = True
+            self._attach_gc(self.gc_clock)
         if self.watchdog_stall_ms <= 0:
             return
         self._hb = time.monotonic()
@@ -440,6 +446,7 @@ class FlightRecorder:
             except ValueError:
                 pass
             self._gc_registered = False
+            self._attach_gc(None)
 
     def _wd_main(self) -> None:
         stall_s = self.watchdog_stall_ms / 1e3
@@ -460,12 +467,33 @@ class FlightRecorder:
                 stalled = False
 
     def _gc_cb(self, phase: str, info: Dict) -> None:
+        """The process's one ``gc.callbacks`` entry: every collection's
+        pause goes to the totals the window profiler takes the growth
+        of (`gc_clock`: ring fields ``gc_us`` / ``gc_collections``); a
+        pause over the threshold is an event of this ring and an
+        interval of the profiler's trace export (``gc_pause``)."""
         if phase == "start":
             self._gc_t0 = time.monotonic()
             return
-        dur_ms = (time.monotonic() - self._gc_t0) * 1e3
-        if dur_ms >= self.gc_stall_ms:
-            self.record(EV_GC, dur_ms, float(info.get("generation", 0)))
+        dur_s = time.monotonic() - self._gc_t0
+        self._gc_s += dur_s
+        self._gc_n += 1
+        if dur_s * 1e3 >= self.gc_stall_ms:
+            generation = info.get("generation", 0)
+            self.record(EV_GC, dur_s * 1e3, float(generation))
+            prof = self.profiler
+            if prof is not None:
+                prof.note("gc_pause", dur_s, generation=generation)
+
+    def gc_clock(self) -> Tuple[float, int]:
+        """Seconds paused in collections and their count since the
+        callback was armed; both only grow."""
+        return (self._gc_s, self._gc_n)
+
+    def _attach_gc(self, clock) -> None:
+        loop_clock = getattr(self.profiler, "loop", None)
+        if loop_clock is not None:
+            loop_clock.attach_gc(clock)
 
     # ----------------------------------------------------- triggers
 

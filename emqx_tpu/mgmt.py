@@ -684,7 +684,23 @@ class MgmtApi:
         """Window-pipeline profiler dump: stage-latency histogram
         summaries, the engine's gauge surface, and the flight
         recorder's most recent windows + engine lifecycle events
-        (``?windows=N`` bounds the dump)."""
+        (``?windows=N`` bounds the dump).  A window's record holds its
+        laps and sub-stages (``stages_us``: ``collect`` inside
+        ``batch_wait``; ``<name>_cpu``, the CPU seconds of an engine
+        section on its executor thread: wall far over CPU in
+        ``overlay``, which calls nothing that blocks, is GIL
+        contention) and what the loop thread did
+        since the record before: reads and writes
+        (``loop_ingress_*``, ``loop_egress_*``), its CPU
+        (``loop_cpu_us``), the phases of its turn, which add up to
+        the wall time between the two (``loop_poll_us``: inside
+        ``select``, a loop that never polls is saturated;
+        ``loop_recv_us``: the turns' ``recv`` calls;
+        ``loop_reads_us``: the reads handled in a row;
+        ``loop_acks_us``: the publishers' acknowledgements;
+        ``loop_tail_us``: the rest, the window's own laps among it;
+        ``loop_turns``, ``loop_recv_turns``), and the process's
+        collections (``gc_us``, ``gc_collections``)."""
         prof = self.broker.profiler
         try:
             limit = int(request.query.get("windows", 32))
@@ -703,7 +719,12 @@ class MgmtApi:
     async def get_profiler_trace(self, request: web.Request) -> web.Response:
         """The flight recorder as Chrome trace-event JSON — loads
         directly in Perfetto (ui.perfetto.dev) or chrome://tracing, so
-        a stall is diagnosable post-hoc without a reproducer."""
+        a stall is diagnosable post-hoc without a reproducer.  The
+        loop's track carries four kinds of burst: ``loop_poll_wait``
+        (inside ``select``), ``loop_recv`` (a turn's ``recv`` calls),
+        ``loop_ingress`` and ``loop_egress`` (reads handled, writes);
+        a collection over ``flight.gc_stall_ms`` is a ``gc_pause`` on
+        track 0."""
         prof = self.broker.profiler
         limit = None
         if "windows" in request.query:

@@ -25,8 +25,11 @@ pipeline a stall lives in.  Three pieces close that gap:
     folds) recorded from the builder threads.  Inside a span, named
     sub-spans with a start (the queue, device and host parts of the two
     match laps, the device round trips of ``decide`` and ``rules``) say
-    what the lap was made of; ``LoopClock`` counts what the event loop
-    does between the windows' stages: socket reads and writes.
+    what the lap was made of, and the engine's sections on the
+    executor threads carry their thread's CPU seconds beside the wall
+    time (``<name>_cpu``); ``LoopClock`` counts what the event loop
+    does between the windows' stages, socket reads and writes, and
+    cuts the loop thread's whole wall time into the phases of its turn.
 
 Flight recorder
     A fixed ring of the last N ``WindowRecord``s, always on and
@@ -225,17 +228,19 @@ class Laps:
             self._ann = ann
             ann.__enter__()
 
-    def lap(self, name: str, then: Optional[str] = None) -> None:
+    def lap(self, name: str, then: Optional[str] = None) -> float:
         """Close the span running since the previous lap (or since
         construction) under ``name`` — two perf_counter reads per
         stage, nothing else on the hot path.  ``then`` marks the
-        section that starts here."""
+        section that starts here.  Returns the reading that closed
+        the span."""
         now = time.perf_counter()
         self.spans.append((name, self._t_last - self.t0, now - self._t_last))
         self._t_last = now
         self.unmark()
         if then is not None:
             self.mark(then)
+        return now
 
     def unmark(self) -> None:
         """Close the open annotation, if any (a section that raised
@@ -258,10 +263,45 @@ class Laps:
             (name, start - self.t0, time.perf_counter() - start)
         )
 
-    def timings(self) -> List[Tuple[str, float, float]]:
+    def timings(self) -> List[Tuple[str, Optional[float], float]]:
         """The spans as ``(name, start, dur)`` with ``start`` on the
         perf_counter clock, for `WindowRecord.sub`."""
         return [(name, self.t0 + off, dur) for name, off, dur in self.spans]
+
+
+class CpuLaps(Laps):
+    """`Laps` of one thread's synchronous sections that reads that
+    thread's CPU clock (``time.thread_time``) beside ``perf_counter``
+    at every lap.  A lap that is work hands its CPU seconds back among
+    `timings` as ``<name>_cpu`` with no start: a sub-stage of the
+    window's record and a histogram, not an interval of the trace.  A
+    lap whose name ends in ``_wait`` has none.  Wall less CPU of a
+    section is the time its thread did not run: waiting for the GIL or
+    for a core, or asleep in a call that blocks (a transfer to the
+    device).  Made and lapped on one thread."""
+
+    __slots__ = ("_c_last", "cpu")
+
+    def __init__(self, seq: int) -> None:
+        super().__init__(seq)
+        self._c_last = time.thread_time()
+        self.cpu: List[Tuple[str, float]] = []  # (name, seconds)
+
+    def lap(self, name: str, then: Optional[str] = None) -> float:
+        # (read inside the wall span's two readings, as at the start)
+        cpu = time.thread_time()
+        now = super().lap(name, then)
+        if not name.endswith("_wait"):
+            self.cpu.append((name + "_cpu", cpu - self._c_last))
+        self._c_last = cpu
+        return now
+
+    def timings(self) -> List[Tuple[str, Optional[float], float]]:
+        # (the wall spans first: `WindowRecord.lap_parts` takes the
+        # first one's start as the instant the work was entered)
+        return super().timings() + [
+            (name, None, dur) for name, dur in self.cpu
+        ]
 
 
 class _NoLaps:
@@ -272,8 +312,8 @@ class _NoLaps:
     def mark(self, name: str) -> None:
         pass
 
-    def lap(self, name: str, then: Optional[str] = None) -> None:
-        pass
+    def lap(self, name: str, then: Optional[str] = None) -> float:
+        return 0.0
 
     def unmark(self) -> None:
         pass
@@ -302,7 +342,7 @@ class WindowRecord(Laps):
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
         "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
         "rules_firings", "rules_firings_run", "n_clients_plain",
-        "n_host_rows",
+        "n_host_rows", "gc",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -345,6 +385,10 @@ class WindowRecord(Laps):
         # the native sender thread's own clock over the same stretch:
         # (seconds inside send(2), send calls), None without a sender
         self.sender: Optional[Tuple[float, int]] = None
+        # the process's garbage collections over that stretch: (seconds
+        # paused, collections), None where no `gc.callbacks` entry is
+        # armed (`flightrec.FlightRecorder.arm_watchdog`)
+        self.gc: Optional[Tuple[float, int]] = None
 
     def sub(self, name: str, dur_s: float,
             start: Optional[float] = None) -> None:
@@ -372,6 +416,7 @@ class WindowRecord(Laps):
             entered = timings[0][1]
         self.sub(wait, entered - waited_from, waited_from)
         for part, start, dur in timings:
+            # (a part with no start: a section's CPU seconds, `CpuLaps`)
             self.sub(part, dur, start)
 
     def sub_totals(self) -> Dict[str, float]:
@@ -384,7 +429,10 @@ class WindowRecord(Laps):
     def to_dict(self) -> Dict[str, object]:
         loop = {}
         if self.loop is not None:
-            for field, v in zip(LoopClock.FIELDS, self.loop):
+            # (the turn fields follow where the loop's selector is
+            # hooked: `LoopClock.take`)
+            fields = LoopClock.FIELDS + LoopClock.TURN_FIELDS
+            for field, v in zip(fields, self.loop):
                 if field.endswith("_s"):
                     field, v = field[:-2] + "_us", round(v * 1e6, 1)
                 loop["loop_" + field] = v
@@ -392,6 +440,9 @@ class WindowRecord(Laps):
         if self.sender is not None:
             loop["sender_send_us"] = round(self.sender[0] * 1e6, 1)
             loop["sender_writes"] = self.sender[1]
+        if self.gc is not None:
+            loop["gc_us"] = round(self.gc[0] * 1e6, 1)
+            loop["gc_collections"] = self.gc[1]
         return {
             "seq": self.seq,
             "at": self.wall0,
@@ -423,33 +474,99 @@ class WindowRecord(Laps):
         }
 
 
+def _growth(clock, base: Tuple[float, int]):
+    """An outside clock's ``(seconds, count)`` since ``base`` and the
+    reading that is the next base; ``(None, base)`` without a clock."""
+    if clock is None:
+        return None, base
+    now = clock()
+    return (now[0] - base[0], now[1] - base[1]), now
+
+
 class LoopClock:
-    """What the event loop does between the windows' stages: every
-    socket read (parse + channel, `Connection._read`) and every socket
-    write (`Connection._send_packets`) adds its interval and counts
-    here, two ``perf_counter`` reads each and none a packet.  A write
-    handed to the native sender thread (``egress_writes_sender``,
-    ``egress_bytes_sender``) costs the loop its serialize and one
-    list append, and each flush scope one hand-over (`egress_submit`):
-    the ``send`` itself is on the thread's clock, `take_sender`.
-    ``egress_parked`` counts the hand-backs after a socket would not
-    take a write.  The totals only grow; `take` hands the growth
-    since the previous take to the window being committed, and
-    `stamp_cpu` the loop thread's CPU since the previous window began
-    to the one beginning.  For the trace, intervals less than
-    ``BURST_GAP_S`` apart merge into one burst.  Loop thread only, so
-    no lock."""
+    """What the event loop does between the windows' stages.
+
+    **Reads and writes.**  Every socket read (parse + channel,
+    `Connection._read`) and every socket write
+    (`Connection._send_packets`) adds its interval and counts here,
+    two ``perf_counter`` reads each and none a packet.  A write handed
+    to the native sender thread (``egress_writes_sender``) costs the
+    loop its serialize and one list append, and each flush scope one
+    hand-over (`egress_submit`): the ``send`` itself is on the
+    thread's clock, `take_sender`.  ``egress_parked`` counts the
+    hand-backs after a socket would not take a write.
+
+    **The turn clock.**  Once `install` has wrapped the ``select`` of
+    the loop's selector (`BrokerServer.start`; `uninstall` in
+    ``stop``), the loop thread's wall time is cut, exactly, into five
+    phases: at every instant one is running, and `mark` is one
+    ``perf_counter`` read that closes the running phase into its total
+    and opens the next, so the phases of a stretch add up to its wall
+    time by construction.  A clock read a turn or a window, never a
+    read, a packet or a message:
+
+    ``poll``   inside ``select``: blocked in the poll, or paying for
+               it.  A loop that never waits there is saturated.
+    ``recv``   from the turn's first ``data_received`` (`ReadTurn.add`:
+               a test a read, a clock read a turn) to the next
+               ``select``: the turn's ``recv`` calls and
+               ``data_received``s.  The first ``recv`` of a turn is
+               before the edge, in ``tail`` (``recv_turns`` counts the
+               turns that had the phase, so a reader can correct by a
+               ``recv`` a turn); a timer or a write-ready callback
+               that the turn runs after its first read is billed here.
+    ``reads``  `ReadTurn._run`: the previous turn's reads handled in a
+               row.  ``ingress_s`` is inside it (but for a read that an
+               EOF or a limiter's pay-off flushed); the difference is
+               the run's own loop.
+    ``acks``   a window's publisher acknowledgements, in two parts
+               (`PublishBatcher._dispatch_loop` marks both): the cork
+               and ``set_result`` pass, and, an iteration later, the
+               futures' done-callbacks (`Channel._publish_acked`) and
+               the uncork, from a handle queued just ahead of the
+               first callback to one queued just behind `_uncork_all`.
+               What the loop runs between the two parts (the rest of
+               the dispatch task's step, the handles queued before the
+               pass: the collector's wake-up among them) keeps its
+               own phase.
+    ``tail``   everything else: tasks (the collector,
+               `_dispatch_loop` and the laps it runs on the loop),
+               timers, write-ready callbacks, executor wake-ups, and a
+               WebSocket connection's reads (the coroutine path has no
+               `ReadTurn`).
+
+    ``turns`` counts the ``select`` calls.  On a loop with no selector
+    to wrap (uvloop, a proactor loop) nothing is installed and a
+    record carries no turn field, never a zero.  For the trace,
+    ``poll`` and ``recv`` intervals are the bursts ``loop_poll_wait``
+    and ``loop_recv`` beside ``loop_ingress`` / ``loop_egress``.
+
+    **Collections.**  `attach_gc` takes the clock of the process's one
+    ``gc.callbacks`` entry (`flightrec.FlightRecorder`), as
+    `attach_sender` takes the sender thread's.
+
+    The totals only grow; `take` hands the growth since the previous
+    take to the window being committed, and `stamp_cpu` the loop
+    thread's CPU since the previous window began to the one beginning.
+    For the trace, intervals less than ``BURST_GAP_S`` apart merge
+    into one burst.  Loop thread only, so no lock."""
 
     FIELDS = (
-        "ingress_s", "ingress_reads", "ingress_packets",
+        "ingress_s", "ingress_reads",
         "ingress_publishes", "ingress_acks", "ingress_acks_run",
-        "ingress_bytes",
         "ingress_publish_s", "ingress_publish_reads",
         "ingress_ack_s", "ingress_ack_reads",
-        "egress_s", "egress_writes", "egress_packets", "egress_bytes",
-        "egress_in_window_s", "egress_in_window_writes",
-        "egress_writes_sender", "egress_bytes_sender", "egress_parked",
+        "egress_s", "egress_writes", "egress_packets",
+        "egress_in_window_s",
+        "egress_writes_sender", "egress_parked",
         "ingress_reads_direct",
+    )
+    # the turn clock's phases, in the order of `_spent`, and its two
+    # counters: in a record only while the selector is hooked
+    POLL, RECV, READS, ACKS, TAIL = range(5)
+    TURN_FIELDS = (
+        "poll_s", "recv_s", "reads_s", "acks_s", "tail_s",
+        "turns", "recv_turns",
     )
     BURST_GAP_S = 200e-6
     BURSTS_CAP = 65536
@@ -457,21 +574,32 @@ class LoopClock:
     def __init__(self) -> None:
         for field in self.FIELDS:
             setattr(self, field, 0)
-        self._base = (0,) * len(self.FIELDS)
-        self.tid: Optional[int] = None  # the loop thread, once it read
+        self._base = (0,) * (len(self.FIELDS) + len(self.TURN_FIELDS))
+        self.tid: Optional[int] = None  # the loop thread, once known
         self.cpu_s = 0.0  # that thread's CPU clock, as last read
         # a write inside a window's deliver / flush laps is inside
         # those laps too: the broker raises this around them
         self.in_window = False
-        # the native sender's clock, while one runs: () -> (seconds
-        # inside send(2), send calls), both only growing
+        # clocks kept elsewhere, while they run, each with the reading
+        # of the previous take: the native sender thread's and the
+        # process's collections'
         self.sender_clock = None
         self._sender_base = (0.0, 0)
+        self.gc_clock = None
+        self._gc_base = (0.0, 0)
         self._bursts: deque = deque(maxlen=self.BURSTS_CAP)
         self._open: Dict[str, List[float]] = {}
+        # the turn clock: the selector whose ``select`` is wrapped, the
+        # running phase and when it opened, the seconds by phase
+        self._sel = None
+        self._phase = self.TAIL
+        self._t = 0.0
+        self._spent = [0.0] * 5
+        self.turns = 0
+        self.recv_turns = 0
 
-    def ingress(self, t0: float, n_bytes: int, packets: int,
-                publishes: int, acks: int, acks_run: int = 0,
+    def ingress(self, t0: float, packets: int, publishes: int,
+                acks: int, acks_run: int = 0,
                 direct: bool = False) -> None:
         """One socket read's parse + channel work, begun at ``t0``:
         ``acks_run`` of its ``acks`` crossed as `AckRun`s (a run
@@ -496,14 +624,12 @@ class LoopClock:
         elif acks == packets:
             self.ingress_ack_s += dt
             self.ingress_ack_reads += 1
-        self.ingress_packets += packets
         self.ingress_publishes += publishes
         self.ingress_acks += acks
         self.ingress_acks_run += acks_run
-        self.ingress_bytes += n_bytes
         self._burst("loop_ingress", t0, now)
 
-    def egress(self, t0: float, n_bytes: int, packets: int,
+    def egress(self, t0: float, packets: int,
                sender: bool = False) -> None:
         """One socket write, begun at ``t0``: serialize + write, or
         (``sender``) serialize + the append to the scope's batch."""
@@ -511,13 +637,10 @@ class LoopClock:
         self.egress_s += now - t0
         self.egress_writes += 1
         self.egress_packets += packets
-        self.egress_bytes += n_bytes
         if sender:
             self.egress_writes_sender += 1
-            self.egress_bytes_sender += n_bytes
         if self.in_window:
             self.egress_in_window_s += now - t0
-            self.egress_in_window_writes += 1
         self._burst("loop_egress", t0, now)
 
     def egress_submit(self, t0: float) -> None:
@@ -529,21 +652,114 @@ class LoopClock:
             self.egress_in_window_s += now - t0
         self._burst("loop_egress", t0, now)
 
+    # ------------------------------------------------ outside clocks
+
+    def attach_sender(self, clock) -> None:
+        """A sender thread started (its clock begins at zero):
+        ``clock() -> (seconds inside send(2), send calls)``, both only
+        growing; or (None) stopped."""
+        self.sender_clock = clock
+        self._sender_base = (0.0, 0)
+
     def take_sender(self) -> Optional[Tuple[float, int]]:
         """The sender thread's clock, its growth since the previous
         take; None while no sender runs."""
-        clock = self.sender_clock
-        if clock is None:
-            return None
-        now = clock()
-        base, self._sender_base = self._sender_base, now
-        return (now[0] - base[0], now[1] - base[1])
+        grown, self._sender_base = _growth(
+            self.sender_clock, self._sender_base
+        )
+        return grown
 
-    def attach_sender(self, clock) -> None:
-        """A sender thread started (its clock begins at zero), or
-        (None) stopped."""
-        self.sender_clock = clock
-        self._sender_base = (0.0, 0)
+    def attach_gc(self, clock) -> None:
+        """The process's ``gc.callbacks`` entry is armed (its clock
+        begins at zero): ``clock() -> (seconds paused, collections)``;
+        or (None) taken off."""
+        self.gc_clock = clock
+        self._gc_base = (0.0, 0)
+
+    def take_gc(self) -> Optional[Tuple[float, int]]:
+        """The collections' clock, its growth since the previous take;
+        None while no callback is armed."""
+        grown, self._gc_base = _growth(self.gc_clock, self._gc_base)
+        return grown
+
+    # ------------------------------------------------ the turn clock
+
+    def install(self, loop) -> bool:
+        """Wrap ``select`` of ``loop``'s selector, on the loop thread:
+        the turn clock runs from here.  One wrapper a selector, however
+        many clocks (two servers on one loop); False where the loop has
+        no selector to wrap, and the turn fields stay out of every
+        record."""
+        sel = getattr(loop, "_selector", None)
+        select = getattr(sel, "select", None)
+        if select is None or self._sel is not None:
+            return False
+        clocks = getattr(select, "turn_clocks", None)
+        if clocks is None:
+            clocks = []
+
+            def hooked(timeout=None, _inner=select):
+                for clock in clocks:
+                    clock._poll()
+                try:
+                    return _inner(timeout)
+                finally:
+                    for clock in clocks:
+                        clock._polled()
+
+            hooked.turn_clocks = clocks
+            try:
+                sel.select = hooked
+            except AttributeError:
+                return False
+        clocks.append(self)
+        self._sel = sel
+        self.tid = threading.get_ident()
+        self._phase = self.TAIL
+        self._t = time.perf_counter()
+        return True
+
+    def uninstall(self) -> None:
+        """Stop the turn clock; the last clock of a selector takes the
+        wrapper off."""
+        sel, self._sel = self._sel, None
+        if sel is None:
+            return
+        clocks = sel.select.turn_clocks
+        clocks.remove(self)
+        if not clocks:
+            del sel.select  # the class's own method again
+
+    def mark(self, phase: int) -> None:
+        """Close the running phase into its total and open ``phase``:
+        one clock read.  Nothing while no selector is hooked."""
+        if self._sel is None:
+            return
+        now = time.perf_counter()
+        was = self._phase
+        self._spent[was] += now - self._t
+        if was == self.RECV:
+            self._burst("loop_recv", self._t, now)
+        self._phase = phase
+        self._t = now
+
+    def recv(self) -> None:
+        """A ``data_received``, the first of its listener in this
+        turn."""
+        if self._phase != self.RECV and self._sel is not None:
+            self.recv_turns += 1
+            self.mark(self.RECV)
+
+    def _poll(self) -> None:
+        self.turns += 1
+        self.mark(self.POLL)
+
+    def _polled(self) -> None:
+        t0 = self._t
+        self.mark(self.TAIL)
+        self._burst("loop_poll_wait", t0, self._t)
+
+    # ---------------------------------------------------------------
 
     def _burst(self, name: str, t0: float, t1: float) -> None:
         cur = self._open.get(name)
@@ -566,14 +782,23 @@ class LoopClock:
         return grown
 
     def take(self) -> Tuple:
-        """The totals' growth since the previous take."""
-        now = tuple(getattr(self, f) for f in self.FIELDS)
+        """The totals' growth since the previous take, in the order of
+        ``FIELDS``; while the selector is hooked the turn clock's, in
+        the order of ``TURN_FIELDS``, behind them.  Taken on the loop
+        thread it closes the running phase's part so far into its
+        total (one clock read), so the phases of a record add up to
+        the wall time since the record before."""
+        hooked = self._sel is not None
+        if hooked and threading.get_ident() == self.tid:
+            self.mark(self._phase)
+        now = tuple(getattr(self, f) for f in self.FIELDS) + tuple(
+            self._spent) + (self.turns, self.recv_turns)
         grown = tuple(a - b for a, b in zip(now, self._base))
         self._base = now
-        return grown
+        return grown if hooked else grown[:len(self.FIELDS)]
 
     def bursts(self) -> List[Tuple[str, float, float]]:
-        """``(name, start, end)`` on the perf_counter clock, the two
+        """``(name, start, end)`` on the perf_counter clock, the
         bursts still open included."""
         return list(self._bursts) + [
             (name, cur[0], cur[1]) for name, cur in self._open.items()
@@ -585,6 +810,7 @@ class LoopClock:
         self.stamp_cpu()
         self.take()
         self.take_sender()
+        self.take_gc()
         self._bursts.clear()
         self._open.clear()
 
@@ -648,8 +874,9 @@ class Profiler:
         self._seq = 0
         # engine lifecycle events: (kind, wall_ts, dur_s, meta)
         self._events: deque = deque(maxlen=max(events_cap, 1))
-        # the event loop's socket reads and writes; None when disabled
-        # (every call site guards, as for ``begin``)
+        # the event loop's socket reads and writes and the phases of
+        # its turn; None when disabled (every call site guards, as for
+        # ``begin``)
         self.loop: Optional[LoopClock] = LoopClock() if enabled else None
         # one pair of readings puts the perf_counter stamps of the
         # loop's bursts on the wall clock of the windows' ``wall0``
@@ -681,6 +908,7 @@ class Profiler:
         if lc is not None:
             rec.loop = lc.take()
             rec.sender = lc.take_sender()
+            rec.gc = lc.take_gc()
             lc.in_window = False
         hist = self._hist
         with self._hlock:
@@ -722,7 +950,16 @@ class Profiler:
         if not self.enabled:
             return
         self.stage("engine_" + kind, dur_s)
-        self._events.append((kind, time.time(), dur_s, meta))
+        self.note(kind, dur_s, **meta)
+
+    def note(self, kind: str, dur_s: float, **meta) -> None:
+        """An interval that ends now, for the trace export and
+        `events` alone: no histogram, so no lock.  What a
+        ``gc.callbacks`` entry may call (a ``gc_pause``): a collection
+        starts inside any allocation, one made under the histograms'
+        lock too."""
+        if self.enabled:
+            self._events.append((kind, time.time(), dur_s, meta))
 
     # ---------------------------------------------------- exposition
 
@@ -787,9 +1024,12 @@ class Profiler:
         its own thread track with paired B/E events per stage (windows
         pipeline, so tracks may overlap in time — per-track events
         stay strictly nested) and each sub-stage that has a start
-        nested inside its parent; engine lifecycle events ride tid 0
-        and the event loop's read / write bursts a track of their own,
-        both as complete ("X") events."""
+        nested inside its parent; engine lifecycle events and
+        collections over the stall threshold (``gc_pause``) ride tid 0
+        and the event loop's bursts a track of their own
+        (``loop_ingress`` / ``loop_egress``: reads and writes;
+        ``loop_poll_wait``: inside ``select``; ``loop_recv``: a turn's
+        ``recv`` calls), both as complete ("X") events."""
         recs = self._recent(limit if limit is not None else len(self._ring))
         recs.reverse()  # oldest first: ts ordering within each track
         wall_at, perf_at = self._wall_at
@@ -825,7 +1065,7 @@ class Profiler:
              "tid": 0, "args": {"sort_index": pid}},
             {"name": "thread_name", "ph": "M", "pid": pid,
              "tid": self.LOOP_TID,
-             "args": {"name": "event loop: socket reads and writes"}},
+             "args": {"name": "event loop: poll, reads and writes"}},
         ]
         for rec in recs:
             tid = rec.seq

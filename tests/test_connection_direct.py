@@ -178,8 +178,7 @@ async def play(monkeypatch, path, frames, cuts):
         counts = {
             f: getattr(lc, f) for f in LoopClock.FIELDS
             if not f.endswith("_s") and f not in (
-                "egress_writes_sender", "egress_bytes_sender",
-                "egress_parked",
+                "egress_writes_sender", "egress_parked",
             )
         }
         return s.handled, bytes(back), counts, [r for _ch, r in s.lost]

@@ -292,12 +292,12 @@ def test_ingress_split_by_packet_type(name, monkeypatch):
     lc = LoopClock()
     t0 = real() - 250e-6
     monkeypatch.setattr(observability.time, "perf_counter", counted)
-    lc.ingress(t0, 100, packets, publishes, acks, acks_run)
+    lc.ingress(t0, packets, publishes, acks, acks_run)
     monkeypatch.undo()
     assert len(ticks) == 1  # the split reads no clock of its own
     assert lc.ingress_reads == 1 and lc.ingress_s >= 250e-6
-    assert (lc.ingress_packets, lc.ingress_publishes, lc.ingress_acks,
-            lc.ingress_acks_run) == (packets, publishes, acks, acks_run)
+    assert (lc.ingress_publishes, lc.ingress_acks,
+            lc.ingress_acks_run) == (publishes, acks, acks_run)
     want = {"publish": (lc.ingress_s, 1, 0.0, 0),
             "ack": (0.0, 0, lc.ingress_s, 1),
             None: (0.0, 0, 0.0, 0)}[part]
@@ -310,7 +310,7 @@ def test_ingress_split_reaches_the_ring_and_adds_up():
     lc = prof.loop
     real = observability.time.perf_counter
     for (packets, publishes, acks, acks_run), _ in READS.values():
-        lc.ingress(real() - 100e-6, 64, packets, publishes, acks, acks_run)
+        lc.ingress(real() - 100e-6, packets, publishes, acks, acks_run)
     prof.commit(prof.begin(1))
     (win,) = prof.windows(1)
     assert win["loop_ingress_reads"] == len(READS)
