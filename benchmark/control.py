@@ -16,6 +16,11 @@ configuration broken underneath the timed path.  Each must read
     host_match    the same for the match step, by the program's own
                   failpoint ``engine.device_step=error`` (the windows are
                   then served by the host trie: right answers, wrong path)
+    share_lost    a delivery lost where it reaches the channel: once in
+                  500 QoS 0 publishes sent to a client that holds shared
+                  subscriptions alone, the frame never leaves, so a
+                  group misses that publish (however the member was
+                  picked)
 
 A fault takes the `BrokerServer` before `start()` and returns what
 undoes it (tests run several in one process).
@@ -27,6 +32,7 @@ import os
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+PUBLISH = 3                    # MQTT's control packet type
 sys.path.insert(0, HERE)
 
 
@@ -79,8 +85,53 @@ def host_match(server):
     return lambda: failpoints.clear("engine.device_step")
 
 
+def share_lost(server):
+    from emqx_tpu.broker.channel import Channel
+
+    real_wire, real_packets = Channel.send_wire, Channel.send_packets
+    calls = [0]
+
+    def lost(ch, qos: int) -> bool:
+        """A QoS 0 publish to a member of groups alone: every publish
+        it is sent is one of its groups' share."""
+        subs = ch.session.subscriptions if ch.session is not None else ()
+        if qos or not subs or not all(f.startswith("$share/") for f in subs):
+            return False
+        calls[0] += 1
+        return calls[0] % 500 == 250
+
+    def send_packets(self, packets):
+        packets = [p for p in packets
+                   if p.type != PUBLISH or not lost(self, p.qos)]
+        return real_packets(self, packets)
+
+    def send_wire(self, data, npub, count=True):
+        # the run's frames one by one: fixed header, remaining length
+        data, kept, at = bytes(data), bytearray(), 0
+        while at < len(data):
+            head, k, size, shift = data[at], at + 1, 0, 0
+            while True:
+                size |= (data[k] & 127) << shift
+                shift, k = shift + 7, k + 1
+                if data[k - 1] < 128:
+                    break
+            if head >> 4 == PUBLISH and lost(self, head >> 1 & 3):
+                npub = (npub[0] - 1, npub[1], npub[2])
+            else:
+                kept += data[at:k + size]
+            at = k + size
+        return real_wire(self, bytes(kept), npub, count)
+
+    Channel.send_wire, Channel.send_packets = send_wire, send_packets
+
+    def undo():
+        Channel.send_wire, Channel.send_packets = real_wire, real_packets
+    return undo
+
+
 FAULTS = {"lost_match": lost_match, "weak_ack": weak_ack,
-          "host_decide": host_decide, "host_match": host_match}
+          "host_decide": host_decide, "host_match": host_match,
+          "share_lost": share_lost}
 
 
 def main(argv=None) -> int:

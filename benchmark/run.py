@@ -268,8 +268,8 @@ def memory_peak(dev) -> int:
 
 # ------------------------------------------------------------------- run
 
-async def run_cell(args, cell, work, conf, metrics, devs, peak, compiles,
-                   fault=None, trace_dir=None):
+async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
+                   peak, compiles, fault=None, trace_dir=None):
     from emqx_tpu.broker.listener import BrokerServer
     from emqx_tpu.config import (
         BrokerConfig, ListenerConfig, apply_env_overrides, check_config,
@@ -332,8 +332,7 @@ async def run_cell(args, cell, work, conf, metrics, devs, peak, compiles,
         port = server.listeners[0].port
 
         # ---------------------------------------- subscribers, then fold
-        subs = traffic.generate("live", conf["live"])
-        n_live_filters = len({f for _, flts, _ in subs for f in flts})
+        n_live_filters = len(set(routed))
         t = time.monotonic()
         mark = compiles.mark()
         n_sub = work["subscriber_children"]
@@ -350,7 +349,7 @@ async def run_cell(args, cell, work, conf, metrics, devs, peak, compiles,
                 raise Refused("granted QoS differ from those asked for")
         subscribe_s = time.monotonic() - t
         t = time.monotonic()
-        wildcard = any("+" in f or "#" in f for _, fl, _ in subs for f in fl)
+        wildcard = any("+" in f or "#" in f for f in routed)
         if wildcard and n_live_filters >= eng.delta_aut_threshold:
             # the live filters crossed the fold threshold: the engine
             # folds them into the device's delta automaton in its own
@@ -585,7 +584,7 @@ async def run_cell(args, cell, work, conf, metrics, devs, peak, compiles,
             "matches_per_row": conf["shapes"]["matches_per_row"],
             # the automaton scans one level past its deepest filter body
             "kernel_levels": 1 + max(
-                [body_depth(f) for _, flts, _ in subs for f in flts]
+                [body_depth(f) for f in routed]
                 + [body_depth(f) for f in referee.RULE_FROM[:n_rules]]
                 + [table_depth]
             ),
@@ -670,7 +669,12 @@ def main(argv=None, fault=None, overrides=None) -> int:
         if soft < hard:
             resource.setrlimit(resource.RLIMIT_NOFILE, (hard, hard))
         cell, work, conf, metrics = load_cell(args.workload, overrides)
-        need = 2 * (work["publishers"] + conf["live"]["subscribers"]) + 256
+        # the live set and the filters the broker routes for it (a
+        # `$share` filter's own, once): one MQTT does not allow is
+        # refused here, before the chip is taken (`referee.Overlap`)
+        subs = traffic.generate("live", conf["live"])
+        routed = [referee.real_filter(f) for _, flts, _ in subs for f in flts]
+        need = 2 * (work["publishers"] + len(subs)) + 256
         if hard != resource.RLIM_INFINITY and hard < need:
             raise Refused(f"RLIMIT_NOFILE {hard} < {need} sockets")
         compiles = CompileLog()
@@ -680,8 +684,8 @@ def main(argv=None, fault=None, overrides=None) -> int:
             compile_cache=cache, failpoints_armed=armed,
             cell=cell["name"], seed=args.seed, seconds=args.seconds)
         result = asyncio.run(run_cell(
-            args, cell, work, conf, metrics, devs, peak, compiles, fault,
-            trace_dir,
+            args, cell, work, conf, metrics, subs, routed, devs, peak,
+            compiles, fault, trace_dir,
         ))
     except (Refused, traffic.BadGenerator, referee.Overlap) as e:
         print(f"refused: {e}", file=sys.stderr)

@@ -199,3 +199,158 @@ def test_every_cells_live_set_is_disjoint_on_its_whole_pool(cell):
                          work["publishers"])
     exp = R.Expected(pool, subs, 0, np.arange(len(pool)))
     assert exp.n_deliveries > 0
+
+
+# ------------------------------------- shared subscriptions (MQTT 5 4.8.2)
+
+# topics by seq % 4: two a group is owed, one plain, one nobody's; two
+# groups on one filter, and `m1` holds a plain filter beside its share
+SHARED_POOL = ["s/a", "s/b", "t/x", "u/1"]
+SHARED_SUBS = [
+    ("m0", ["$share/g/s/+"], 1), ("m1", ["$share/g/s/+", "t/x"], 0),
+    ("m2", ["$share/g/s/+"], 1), ("h0", ["$share/h/s/+"], 0),
+    ("h1", ["$share/h/s/+"], 1), ("p0", ["t/x"], 1),
+]
+M0, M1, M2, H0, H1, P0 = range(6)
+
+
+def _shared_run():
+    """40 publishes from 2 publishers, each group's share dealt round
+    the members in publish order, the plain parts as owed."""
+    exp = R.Expected(SHARED_POOL, SHARED_SUBS, 0, np.arange(40))
+    got = [list(s) for s in exp.sub_seqs]
+    for g, seqs in enumerate(exp.group_seqs):
+        members = [j for j, gs in enumerate(exp.groups_of) if g in gs]
+        for i, seq in enumerate(seqs):
+            got[members[i % len(members)]].append(seq)
+    got = [np.sort(np.asarray(r, dtype=np.int64)) for r in got]
+    return exp, got, [1 << min(q, 1) for _c, _f, q in SHARED_SUBS]
+
+
+def _give(got, j, seq):
+    got[j] = np.sort(np.append(got[j], seq))
+
+
+def _take(got, j, seq):
+    got[j] = got[j][got[j] != seq]
+
+
+def _swap_same_topic(got, j):
+    have = got[j]
+    a, b = [i for i in range(len(have)) if have[i] % 4 == have[0] % 4][:2]
+    have[[a, b]] = have[[b, a]]
+
+
+SHARED_CASES = {
+    # (what happens to the sound run, the numbers it reads, the seqs failed)
+    "served-once-by-different-members": (lambda got, q: None, {}, []),
+    "one-publish-to-two-members": (
+        lambda got, q: _give(got, M2, got[M0][0]),
+        {"deliveries_duplicated": 1}, []),
+    "one-publish-to-no-member": (
+        lambda got, q: _take(got, M1, got[M1][got[M1] % 4 < 2][0]),
+        {"deliveries_missing": 1}, [1]),
+    "a-topic-none-of-its-filters-match": (
+        lambda got, q: _give(got, P0, 4),
+        {"deliveries_unexpected": 1}, []),
+    "two-groups-on-one-filter-each-owed": (
+        lambda got, q: [_take(got, j, s) for j in (H0, H1)
+                        for s in list(got[j])],
+        {"deliveries_missing": 20}, list(range(0, 40, 4))
+        + list(range(1, 40, 4))),
+    "plain-beside-shared-is-the-members-own": (
+        lambda got, q: (_take(got, M1, 2), _give(got, M0, 2)),
+        {"deliveries_missing": 1, "deliveries_unexpected": 1}, [2]),
+    "out-of-order-at-one-member": (
+        lambda got, q: _swap_same_topic(got, M2),
+        {"deliveries_out_of_order": 1}, []),
+    "a-member-at-the-wrong-qos": (
+        lambda got, q: q.__setitem__(M2, 1),
+        {"subscribers_wrong_qos": 1}, []),
+}
+
+
+@pytest.mark.parametrize("case", [*SHARED_CASES, "overlap-plain-and-shared",
+                                  "malformed-wildcard-name", "bare-share"])
+def test_shared_subscriptions_are_judged_by_the_group_rule(case):
+    """A group (`<name>`, `<rest>`) is owed each publish `<rest>` matches
+    once, by any one member; the five delivery numbers keep their names."""
+    if case not in SHARED_CASES:
+        subs, says = {
+            "overlap-plain-and-shared": (
+                [("c", ["s/a", "$share/g/s/+"], 1)],
+                "matches more than one filter of subscriber 'c': "
+                "['s/a', '$share/g/s/+']"),
+            "malformed-wildcard-name": ([("c", ["$share/+/x"], 1)],
+                                        "'$share/+/x'"),
+            "bare-share": ([("c", ["$share/g"], 1)], "'$share/g'"),
+        }[case]
+        with pytest.raises(R.Overlap) as e:
+            R.Expected(SHARED_POOL, subs, 0, np.arange(4))
+        assert says in str(e.value)
+        malformed = case != "overlap-plain-and-shared"
+        assert ("$share/<name>/<filter>" in str(e.value)) is malformed
+        return
+    exp, got, qos = _shared_run()
+    assert exp.groups == [("g", "s/+"), ("h", "s/+")]
+    assert exp.groups_of == [[0], [0], [0], [1], [1], []]
+    # 20 publishes on s/+ to each group, 10 on t/x to each plain holder
+    assert exp.n_deliveries == 20 + 20 + 10 + 10
+    assert all(len(got[j]) for j in range(6))
+    break_it, want, failed = SHARED_CASES[case]
+    break_it(got, qos)
+    nums, bad = _numbers(exp, 2, np.arange(40), got, qos)
+    assert list(nums) == [
+        "pubacks_missing", "deliveries_missing", "deliveries_unexpected",
+        "deliveries_duplicated", "deliveries_out_of_order",
+        "subscribers_wrong_qos",
+    ]
+    assert {n: v for n, v in nums.items() if v} == want
+    assert sorted(bad.tolist()) == sorted(failed)
+
+
+# ------------------------------ a live set without `$share`, as it was
+
+def _digest(arrays) -> str:
+    import hashlib
+
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a, dtype=np.int64)
+        h.update(len(a).to_bytes(8, "little"))
+        h.update(a.tobytes())
+    return h.hexdigest()[:16]
+
+
+# computed on the parent's referee.py (commit 5db1955), before the group
+# rule: each configuration's live set and rules over its cell's pool
+# (seed 3000000032) for the publishes 0, 3, 6, ... 39999
+PINNED_EXPECTED = [
+    ("fleet-1m-rules", "flood-qos1", "eaddcfee95b688c8", "aeb57d3b893e738e",
+     331116),
+    ("exact-1k-fanout", "flood-qos1", "b7dfba40e647ddd1", "e3b0c44298fc1c14",
+     3333500),
+    ("p2p-1k", "flood-qos1", "9b93ff1c27b75ddf", "e3b0c44298fc1c14", 13334),
+    ("plus-100k", "flood-qos1", "84d46d551898a253", "e3b0c44298fc1c14",
+     23724),
+]
+
+
+@pytest.mark.parametrize("config,traffic,subs_pin,rules_pin,n",
+                         PINNED_EXPECTED, ids=[p[0] for p in PINNED_EXPECTED])
+def test_expected_without_share_is_what_it_was(config, traffic, subs_pin,
+                                               rules_pin, n):
+    import json
+
+    bench = os.path.join(REPO, "benchmark")
+    work = json.load(open(os.path.join(bench, "workloads",
+                                       f"{config}.{traffic}.json")))
+    conf = json.load(open(os.path.join(bench, "configs", config + ".json")))
+    _pairs, pops = TR.generate("table", conf["table"])
+    pool = TR.topic_pool(work["topics"], pops, 3000000032, work["publishers"])
+    exp = R.Expected(pool, TR.generate("live", conf["live"]),
+                     conf["rules"]["count"], np.arange(0, 40000, 3))
+    assert exp.groups == [] and exp.group_seqs == []
+    assert _digest(exp.sub_seqs) == subs_pin
+    assert _digest(exp.rule_seqs) == rules_pin
+    assert exp.n_deliveries == n
