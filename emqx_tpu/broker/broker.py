@@ -56,7 +56,7 @@ class Broker:
         self,
         config: Optional[BrokerConfig] = None,
         hooks: Optional[HookRegistry] = None,
-        shared_strategy: str = "random",
+        shared_strategy: Optional[str] = None,
     ) -> None:
         self.config = config or BrokerConfig()
         self.hooks = hooks or HookRegistry()
@@ -134,7 +134,10 @@ class Broker:
             engine = MatchEngine(**eng_kw)
         self.router = Router(
             engine=engine,
-            shared=SharedSubManager(strategy=shared_strategy),
+            shared=SharedSubManager(
+                strategy=shared_strategy
+                or self.config.mqtt.shared_subscription_strategy
+            ),
         )
         # engine lifecycle events (XLA compiles, device_put transfers,
         # delta folds) land in the same profiler as the window stages
@@ -1344,13 +1347,21 @@ class Broker:
         if rec is not None:
             rec.mark("expand")
         if preexpanded is None:
-            msg_idx, rows, opts_rows, rules, shared = (
+            msg_idx, rows, opts_rows, rules, s_msg, s_key = (
                 router.expand_window(matched)
             )
         else:
             msg_idx, rows, opts_rows = preexpanded
             rules = []
-            shared = []
+            s_key = ()
+        if len(s_key):
+            # shared-group columns: one live member per (msg, filter,
+            # group), picked for the whole window at once
+            s_msg, s_rows, s_opts_rows = self._shared_window(
+                msgs, s_msg, s_key, rec
+            )
+        else:
+            s_rows = ()
         if rec is not None:
             rec.lap("expand")
             # the socket writes from here to the flush lap are inside
@@ -1366,13 +1377,6 @@ class Broker:
                     rule_sink.append((msgs[i], rids))
                 else:
                     self.rules.apply(msgs[i], sorted(set(rids)))
-        # shared-group columns: one live member per (msg, filter, group)
-        s_msg: List[int] = []
-        s_rows: List[int] = []
-        s_opts_rows: List[int] = []
-        for i, real, group in shared:
-            self._shared_pick(msgs[i], i, real, group,
-                              s_msg, s_rows, s_opts_rows)
         n_direct = len(rows)
         mloc: Counter = Counter()  # batched counter deltas (one lock)
         touched = bytearray(n)
@@ -1395,17 +1399,11 @@ class Broker:
         ts_min = 0.0 if replay else min(
             (m.timestamp for m in msgs if m.timestamp), default=0.0
         )
-        if n_direct or s_rows:
-            if s_rows:
-                all_rows = np.concatenate(
-                    [rows, np.asarray(s_rows, dtype=np.int64)]
-                )
-                all_msg = np.concatenate(
-                    [msg_idx, np.asarray(s_msg, dtype=np.int64)]
-                )
-                all_opts_rows = np.concatenate(
-                    [opts_rows, np.asarray(s_opts_rows, dtype=np.int64)]
-                )
+        if n_direct or len(s_rows):
+            if len(s_rows):
+                all_rows = np.concatenate([rows, s_rows])
+                all_msg = np.concatenate([msg_idx, s_msg])
+                all_opts_rows = np.concatenate([opts_rows, s_opts_rows])
             else:
                 all_rows, all_msg = rows, msg_idx
                 all_opts_rows = opts_rows
@@ -2200,44 +2198,78 @@ class Broker:
             return all(g(clientid, msg) for g in self.delivery_guards)
         return True
 
-    def _shared_pick(
+    def _shared_window(
         self,
-        msg: Message,
-        msg_i: int,
-        real: str,
-        group: str,
-        s_msg: List[int],
-        s_rows: List[int],
-        s_opts_rows: List[int],
-    ) -> None:
-        """Pick one live group member, skipping dead ones
-        (redispatch, emqx_shared_sub.erl:144-166), appending the pick
-        to the window's shared delivery columns (the opts-TABLE slot,
-        so shared deliveries ride the decision columns like direct
-        ones).  With durable storage on, DETACHED persistent members
-        are skipped too: their share of the group's traffic arrives
-        via stream-assigned replay (durable shared subs) — queueing
-        here as well would double-deliver the offline interval."""
+        msgs: Sequence[Message],
+        s_msg: np.ndarray,
+        s_key: np.ndarray,
+        rec=None,
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """A window's shared rows to delivery columns ``(msg_idx,
+        client_rows, opts_rows)``, in the rows' order: one
+        `SharedSubManager.pick_window` for every key whose members are
+        all eligible, `_shared_pick` row by row for the rest; a row
+        with no eligible member delivers nothing."""
+        t0 = rec.now() if rec is not None else 0.0
+        shared = self.router.shared
+        p_rows, p_slots, served = shared.pick_window(
+            s_msg, s_key, msgs, self._shared_eligible
+        )
+        n_vector = int(served.sum())
+        if n_vector < len(s_key):
+            no_member = 0
+            for r in np.flatnonzero(~served).tolist():
+                group, real = shared.key_of(int(s_key[r]))
+                got = self._shared_pick(msgs[s_msg[r]], real, group)
+                if got is None:
+                    no_member += 1
+                else:
+                    p_rows[r], p_slots[r] = got
+            shared.picks_no_member += no_member
+            keep = p_rows >= 0
+            s_msg, p_rows, p_slots = s_msg[keep], p_rows[keep], p_slots[keep]
+        if rec is not None:
+            rec.nest("shared_pick", t0)
+            rec.n_shared += len(s_key)
+            rec.n_shared_vector += n_vector
+        return s_msg, p_rows, p_slots
+
+    def _shared_eligible(self, clientid: str) -> bool:
+        """A member may take a pick: its session exists and, with
+        durable storage on, it is attached or has no expiry (a
+        DETACHED persistent member's share of the group's traffic
+        arrives via stream-assigned replay, durable shared subs —
+        queueing here as well would double-deliver the offline
+        interval)."""
+        session = self.cm.lookup(clientid)
+        return session is not None and (
+            self.durable is None
+            or self.cm.channel(clientid) is not None
+            or session.expiry_interval <= 0
+        )
+
+    def _shared_pick(
+        self, msg: Message, real: str, group: str
+    ) -> Optional[Tuple[int, int]]:
+        """Pick one eligible group member, skipping the others
+        (redispatch, emqx_shared_sub.erl:144-166): its client row and
+        the opts-TABLE slot its delivery rides, so shared deliveries
+        ride the decision columns like direct ones; None where no
+        member is eligible.  The scalar referee of `pick_window`."""
+        shared = self.router.shared
         tried: Set[str] = set()
         while True:
-            picked = self.router.shared.pick(group, real, msg, exclude=tried)
+            picked = shared.pick(group, real, msg, exclude=tried)
             if picked is None:
-                return
-            session = self.cm.lookup(picked)
-            if session is not None and (
-                self.durable is None
-                or self.cm.channel(picked) is not None
-                or session.expiry_interval <= 0
-            ):
+                return None
+            if self._shared_eligible(picked):
                 slot = self.router.shared_slot_of(real, group, picked)
-                if slot is not None:
-                    row = self.router.row_of_client(picked)
-                    if row is None:  # defensive: intern on demand
-                        row = self.router._intern(picked)
-                    s_msg.append(msg_i)
-                    s_rows.append(row)
-                    s_opts_rows.append(slot)
-                return
+                if slot is None:
+                    return None
+                row = self.router.row_of_client(picked)
+                if row is None:  # defensive: intern on demand
+                    row = self.router._intern(picked)
+                return row, slot
             tried.add(picked)
 
     def _deliver_run(
@@ -2718,7 +2750,11 @@ class Broker:
         """Apply one dotted-path config update to the live config tree
         (the emqx_config_handler runtime-update role; cluster-wide
         ordering is the ClusterNode's conf-txn journal).  Raises
-        ValueError for any unknown path segment."""
+        ValueError for any unknown path segment, and for a shared-sub
+        strategy outside `shared.STRATEGIES` (switching to one starts
+        the manager's round-robin and sticky state over)."""
+        if path == "mqtt.shared_subscription_strategy":
+            self.router.shared.strategy = value
         parts = path.split(".")
         obj = self.config
         for part in parts[:-1]:

@@ -29,6 +29,10 @@ class MqttConfig:
     retain_available: bool = True
     wildcard_subscription: bool = True
     shared_subscription: bool = True
+    # how a $share group picks its member for a message
+    # (emqx_shared_sub's strategies, `broker.shared.STRATEGIES`);
+    # EMQX 5's shipped default
+    shared_subscription_strategy: str = "round_robin"
     exclusive_subscription: bool = False
     max_inflight: int = 32
     max_awaiting_rel: int = 100
@@ -681,6 +685,13 @@ def check_config(cfg: BrokerConfig) -> List[str]:
         bad(f"mqtt.max_qos_allowed: {cfg.mqtt.max_qos_allowed}")
     if cfg.mqtt.mqueue_default_priority not in ("lowest", "highest"):
         bad("mqtt.mqueue_default_priority must be lowest|highest")
+    from .broker.shared import STRATEGIES
+    if cfg.mqtt.shared_subscription_strategy not in STRATEGIES:
+        bad(
+            "mqtt.shared_subscription_strategy: "
+            f"{cfg.mqtt.shared_subscription_strategy!r} "
+            f"({'|'.join(STRATEGIES)})"
+        )
     if cfg.durable.layout not in ("lts", "hash"):
         bad(f"durable.layout: {cfg.durable.layout!r} (lts|hash)")
     if not 1 <= int(cfg.durable.n_shards) <= 64:

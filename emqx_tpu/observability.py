@@ -342,7 +342,7 @@ class WindowRecord(Laps):
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
         "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
         "rules_firings", "rules_firings_run", "n_clients_plain",
-        "n_host_rows", "gc",
+        "n_host_rows", "n_shared", "n_shared_vector", "gc",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -358,6 +358,11 @@ class WindowRecord(Laps):
         # rows of a ``dev`` window that a kernel flagged (frontier or
         # match cap passed) and the host trie matched instead
         self.n_host_rows = 0
+        # shared-subscription rows the window picked a member for, and
+        # those of them one window operation served (the rest went
+        # through the scalar redispatch path)
+        self.n_shared = 0
+        self.n_shared_vector = 0
         # delivery rows the device decide step was given, and the
         # bucket it ran them in (both 0 where the host decided)
         self.decide_rows = 0
@@ -453,6 +458,8 @@ class WindowRecord(Laps):
             "n_clients_plain": self.n_clients_plain,
             "n_clips": self.n_clips,
             "n_host_rows": self.n_host_rows,
+            "n_shared": self.n_shared,
+            "n_shared_vector": self.n_shared_vector,
             "decide_rows": self.decide_rows,
             "decide_rows_padded": self.decide_rows_padded,
             "rules_firings": self.rules_firings,

@@ -17,7 +17,7 @@ rule fids and shared-group fids split off as distinct columns feeding
 the rule sink and the shared-pick path.
 
 Shared subscriptions route through the same engine entry for the real
-filter; group membership and per-message picks live in
+filter; group membership and a window's picks live in
 `SharedSubManager`.
 """
 
@@ -222,18 +222,20 @@ class Router:
         if shared is not None:
             real = shared.topic
             opts.share_group = shared.group
-            self._intern(clientid)  # picks resolve to rows at dispatch
-            need_route = self.shared.join(shared.group, real, clientid)
+            row = self._intern(clientid)  # picks resolve to rows
             self._shared_opts.setdefault(real, {})[
                 (shared.group, clientid)
             ] = opts
             skey = (real, shared.group, clientid)
             sslot = self._shared_slot.get(skey)
             if sslot is None:
-                self._shared_slot[skey] = self._alloc_opts(opts)
+                sslot = self._shared_slot[skey] = self._alloc_opts(opts)
             else:  # options refresh of an existing shared subscription
                 self._opts_table[sslot] = opts
                 self._set_opts_attrs(sslot, opts)
+            need_route = self.shared.join(
+                shared.group, real, clientid, row, sslot
+            )
             if need_route and real not in self._subs:
                 self.engine.insert(real, real)
                 if self.on_route_added is not None:
@@ -382,29 +384,33 @@ class Router:
     def expand_window(
         self, matched: Sequence[Set]
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-               List[Tuple[int, List[str]]],
-               List[Tuple[int, str, str]]]:
+               List[Tuple[int, List[str]]], np.ndarray, np.ndarray]:
         """CSR-expand one window's matched fid sets to flat delivery
         columns.
 
-        Returns ``(msg_idx, client_rows, opts_rows, rules, shared)``:
-        the three aligned int64 arrays cover every DIRECT (non-shared)
-        delivery in the window — one vectorized concatenation over the
-        per-filter CSR columns — while rule fids come back grouped
-        per message as ``(msg_idx, [rule_id, ...])`` (RAW: unsorted,
-        a multi-filter rule may repeat; the rule engine's flatten
-        cache dedups vectorized) and shared-group fids as
-        ``(msg_idx, real_filter, group)`` for the rule-sink and
-        shared-pick paths.  Fids with no local state (e.g. raw engine
-        fids preloaded by benchmarks) cost one dict miss each."""
+        Returns ``(msg_idx, client_rows, opts_rows, rules, s_msg,
+        s_key)``: the three aligned int64 arrays cover every DIRECT
+        (non-shared) delivery in the window — one vectorized
+        concatenation over the per-filter CSR columns — while rule
+        fids come back grouped per message as ``(msg_idx, [rule_id,
+        ...])`` (RAW: unsorted, a multi-filter rule may repeat; the
+        rule engine's flatten cache dedups vectorized) and the shared
+        part as two aligned int64 columns, a row per (message, matched
+        filter, group): the message and the (group, filter) key id
+        (`SharedSubManager.pick_window`), concatenated from each
+        filter's key ids as the direct part is from its bucket.  Fids
+        with no local state (e.g. raw engine fids preloaded by
+        benchmarks) cost two dict misses each."""
         seg_rows: List[np.ndarray] = []
         seg_opts: List[np.ndarray] = []
         seg_msg: List[int] = []
         seg_len: List[int] = []
         rules: List[Tuple[int, List[str]]] = []
-        shared: List[Tuple[int, str, str]] = []
+        sh_keys: List[np.ndarray] = []
+        sh_msg: List[int] = []
+        sh_len: List[int] = []
         csr = self._csr
-        groups_for = self.shared.groups_for
+        keys_of = self.shared.keys_by_filter
         rule_i = -1
         rule_ids: List[str] = []
         for i, fids in enumerate(matched):
@@ -423,10 +429,18 @@ class Router:
                     seg_opts.append(o)
                     seg_msg.append(i)
                     seg_len.append(len(r))
-                for group in groups_for(fid):
-                    shared.append((i, fid, group))
+                kids = keys_of.get(fid)
+                if kids is not None:
+                    sh_keys.append(kids)
+                    sh_msg.append(i)
+                    sh_len.append(len(kids))
+        if sh_keys:
+            s_msg = np.repeat(np.asarray(sh_msg, dtype=np.int64), sh_len)
+            s_key = np.concatenate(sh_keys)
+        else:
+            s_msg = s_key = _EMPTY_I64
         if not seg_rows:
-            return _EMPTY_I64, _EMPTY_I64, _EMPTY_I64, rules, shared
+            return _EMPTY_I64, _EMPTY_I64, _EMPTY_I64, rules, s_msg, s_key
         if len(seg_rows) == 1:
             client_rows, opts_rows = seg_rows[0], seg_opts[0]
             msg_idx = np.full(seg_len[0], seg_msg[0], dtype=np.int64)
@@ -436,4 +450,4 @@ class Router:
             msg_idx = np.repeat(
                 np.asarray(seg_msg, dtype=np.int64), seg_len
             )
-        return msg_idx, client_rows, opts_rows, rules, shared
+        return msg_idx, client_rows, opts_rows, rules, s_msg, s_key

@@ -78,15 +78,15 @@ def _legacy_expand(router, matched):
                 continue
             for clientid, opts in router.subscribers(fid):
                 per_msg.append((clientid, id(opts)))
-            for group in router.shared.groups_for(fid):
-                shared.append((fid, group))
+            for kid in router.shared.keys_by_filter.get(fid, ()):
+                shared.append((fid, router.shared.key_of(int(kid))[0]))
         out.append((sorted(per_msg), sorted(rules), sorted(shared)))
     return out
 
 
 def _csr_expand(router, matched):
     """The batched expansion, regrouped to the legacy shape."""
-    msg_idx, rows, opts_rows, rules, shared = router.expand_window(
+    msg_idx, rows, opts_rows, rules, s_msg, s_key = router.expand_window(
         matched
     )
     n = len(matched)
@@ -101,7 +101,8 @@ def _csr_expand(router, matched):
     for i, rids in rules:
         rule_by[i].extend(rids)
     shared_by = [[] for _ in range(n)]
-    for i, real, group in shared:
+    for i, kid in zip(s_msg.tolist(), s_key.tolist()):
+        group, real = router.shared.key_of(kid)
         shared_by[i].append((real, group))
     return [
         (sorted(per_msg[i]), sorted(rule_by[i]), sorted(shared_by[i]))
@@ -161,10 +162,11 @@ def test_pure_rule_window_short_circuits_subscriber_expansion():
         {("rule", "r1", 0)},
         {("rule", "r1", 1), ("rule", "r2", 1)},
     ]
-    msg_idx, rows, opts_rows, rules, shared = b.router.expand_window(
-        matched
+    msg_idx, rows, opts_rows, rules, s_msg, s_key = (
+        b.router.expand_window(matched)
     )
-    assert len(rows) == 0 and len(msg_idx) == 0 and not shared
+    assert len(rows) == 0 and len(msg_idx) == 0
+    assert len(s_msg) == len(s_key) == 0
     assert [
         (i, sorted(ids)) for i, ids in sorted(rules)
     ] == [(0, ["r1"]), (1, ["r1", "r2"])]
