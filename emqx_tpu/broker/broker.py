@@ -244,9 +244,11 @@ class Broker:
         # whichever thread ran the match (batcher executor, probe
         # thread), so the alarm publish hops to the event loop.
         self._loop = None  # captured by BrokerServer.start
-        # the native sender thread (ops/sockwriter.SockSender) while a
-        # BrokerServer runs and the library is there; None otherwise
+        # the native sender and reader threads (ops/sockwriter
+        # .SockSender, ops/sockreader.SockReader) while a BrokerServer
+        # runs and the libraries are there; None otherwise
         self.sender = None
+        self.reader = None
         self.router.engine.on_breaker_trip = self._engine_breaker_trip
         self.router.engine.on_breaker_clear = self._engine_breaker_clear
         self.banned = BannedList()

@@ -7,7 +7,7 @@ channel FSM, serializes outgoing packets, and drives the keepalive /
 retry timers that the reference hangs off its process timers.
 
 A socket's bytes reach `Connection._read` one of two ways, chosen once
-from what the connection is.  On a plain TCP or TLS listener the
+from what the listener is.  On a plain TCP or TLS listener the
 `Connection` is the transport's protocol: `data_received` keeps a read
 and tells the listener's `ReadTurn`, which handles the reads of one
 loop turn together once the turn's last ``recv`` is back, with no
@@ -15,11 +15,20 @@ task, future or coroutine a read, and what the coroutine awaits is
 reading paused and resumed (a limiter's pause between two packets of
 one read among them).  A WebSocket stream is no transport and keeps
 the coroutine, `run`, over a reader / writer pair.
+
+Who does the ``recv`` on the first way is decided from what the socket
+is: a plain-TCP socket is read by the native reader thread
+(ops/sockreader.py), which hands the loop a batch of reads a wake-up
+and calls `data_received` for each, its transport kept paused for
+reading from `connection_made` on; TLS (``sslproto`` owns the bytes)
+and every socket where the library is absent are read by their
+transport.
 """
 
 from __future__ import annotations
 
 import asyncio
+import errno
 import functools
 import logging
 import socket
@@ -35,6 +44,24 @@ log = logging.getLogger("emqx_tpu.connection")
 
 _TIMER_TICK = 5.0  # keepalive/retry check cadence
 _ACKS = frozenset((C.PUBACK, C.PUBREC, C.PUBREL, C.PUBCOMP))
+
+
+def _plain_tcp_fd(transport) -> int:
+    """The descriptor of the plain TCP socket under ``transport`` (no
+    TLS on it, AF_INET / AF_INET6 stream), or -1."""
+    if (
+        transport.get_extra_info("ssl_object") is not None
+        or transport.get_extra_info("sslcontext") is not None
+    ):
+        return -1
+    sock = transport.get_extra_info("socket")
+    if (
+        sock is not None
+        and sock.family in (socket.AF_INET, socket.AF_INET6)
+        and sock.type == socket.SOCK_STREAM
+    ):
+        return sock.fileno()
+    return -1
 
 
 class ReadTurn:
@@ -73,13 +100,23 @@ class ReadTurn:
         if clock is not None:
             clock.mark(clock.READS)
         conns, self._conns = self._conns, []
-        for conn in conns:
+        for i, conn in enumerate(conns):
+            closed = False
             try:
-                conn._handle_reads()
+                closed = conn._handle_reads()
             except Exception:
                 # (a boundary that must keep running: the turn's other
                 # reads are off their sockets already)
                 log.exception("read turn: a connection failed")
+            if closed and i + 1 < len(conns):
+                # a read closed its connection and queued the teardown:
+                # the turn's later reads wait behind it, as they would
+                # behind the next poll (a client's DISCONNECT, then its
+                # reconnect's CONNECT, read in one batch)
+                if not self._conns:
+                    asyncio.get_running_loop().call_soon(self._run)
+                self._conns[:0] = conns[i + 1:]
+                break
         if clock is not None:
             clock.mark(clock.TAIL)
 
@@ -114,7 +151,9 @@ class Connection(asyncio.Protocol):
         self.channel: Optional[Channel] = None
         self._closed = asyncio.Event()
         self._congested = False
-        self._failed = False  # a send failed on the sender thread
+        # a send on the sender thread failed, or the reader thread's
+        # recv was reset: the connection ends as ``peer_reset``
+        self._failed = False
         self._torn = False  # `_teardown` ran
         self._timer: Optional[asyncio.Task] = None
         # the direct path: what was received and is not handled yet
@@ -131,6 +170,10 @@ class Connection(asyncio.Protocol):
         self._owed: Optional[asyncio.TimerHandle] = None
         self._ended: Optional[str] = None
         self._lost = False
+        # the native reader thread and this connection's slot there
+        # (from `connection_made`, where the socket is plain TCP)
+        self._reader = None
+        self._rslot = -1
         if writer is not None:
             self._attach(writer, getattr(writer, "transport", None))
 
@@ -164,21 +207,14 @@ class Connection(asyncio.Protocol):
         self._slot = -1
         self._handed = False  # the sender may still hold bytes of ours
         self._parked = False  # the transport took parked bytes back
+        self._plain_fd = (
+            _plain_tcp_fd(transport) if self._tbuf is not None else -1
+        )
         snd = self.broker.sender
-        if snd is not None and self._tbuf is not None and (
-            transport.get_extra_info("ssl_object") is None
-            and transport.get_extra_info("sslcontext") is None
-        ):
-            sock = transport.get_extra_info("socket")
-            if (
-                sock is not None
-                and sock.family in (socket.AF_INET, socket.AF_INET6)
-                and sock.type == socket.SOCK_STREAM
-                and sock.fileno() >= 0
-            ):
-                self._slot = snd.open(sock.fileno(), self)
-                if self._slot >= 0:
-                    self._sender = snd
+        if snd is not None and self._plain_fd >= 0:
+            self._slot = snd.open(self._plain_fd, self)
+            if self._slot >= 0:
+                self._sender = snd
         # (a read's PUBACKs come as one `AckRun`, not as k packets)
         self.parser = C.StreamParser(
             max_packet_size=self.broker.config.mqtt.max_packet_size,
@@ -333,12 +369,17 @@ class Connection(asyncio.Protocol):
 
     def _release_slot(self) -> None:
         """Nothing more goes to the sender thread: it sends what it
-        was handed, then closes its own descriptor (queue order)."""
+        was handed, then closes its own descriptor (queue order); and
+        no read of the reader thread's reaches the connection."""
         snd, self._sender = self._sender, None
         if snd is not None:
             snd.close(self._slot)
             self._slot = -1
             self._handed = False
+        rdr, self._reader = self._reader, None
+        if rdr is not None:
+            rdr.close(self._rslot)
+            self._rslot = -1
 
     def _teardown(self, reason: str) -> None:
         """The connection ends, once, whichever path read it and
@@ -366,8 +407,8 @@ class Connection(asyncio.Protocol):
 
     # --------------------------------------------------------- input
 
-    def _read(self, data: bytes, t_in: float,
-              direct: bool = False) -> Iterator[float]:
+    def _read(self, data: bytes, t_in: float, direct: bool = False,
+              native: bool = False) -> Iterator[float]:
         """One socket read's work on the loop, the same on both read
         paths: count, parse, hand each packet to the channel, clock
         (``t_in``: when the read came back).  A generator only for
@@ -418,7 +459,7 @@ class Connection(asyncio.Protocol):
         if lc is not None:
             n_acks += n_run
             lc.ingress(t_in, n_pubs + n_acks + n_other,
-                       n_pubs, n_acks, n_run, direct)
+                       n_pubs, n_acks, n_run, direct, native)
 
     def _owe(self, delay: float) -> Iterator[float]:
         """A limiter's pause inside `_read`: yields the seconds owed,
@@ -520,6 +561,14 @@ class Connection(asyncio.Protocol):
             self._closed.set()
             return
         self._attach(transport, transport)
+        rdr = self.broker.reader
+        if rdr is not None and self._plain_fd >= 0:
+            # (the transport starts reading after this callback: paused
+            # now, it never does, and keeps its writes and its close)
+            self._rslot = rdr.open(self._plain_fd, self)
+            if self._rslot >= 0:
+                self._reader = rdr
+                transport.pause_reading()
         loop = asyncio.get_running_loop()
         self._timer = loop.create_task(self._timers())
         # one handle a connection, nothing a read
@@ -532,18 +581,22 @@ class Connection(asyncio.Protocol):
             self._turn.add(self)
         self._reads.append(data)
 
-    def _handle_reads(self) -> None:
+    def _handle_reads(self) -> bool:
         """Handle what was received, in order, as far as a limiter's
         pause lets it: the turn's run, and first whoever ends the
-        connection in the turn of its last read."""
+        connection in the turn of its last read.  True where a read
+        closed the connection."""
         reads = self._reads
+        was_open = not self._closed.is_set()
         while reads and self._owed is None:
             if self._closed.is_set():
                 reads.clear()
-                return
+                break
             lc = self.broker.profiler.loop
             t_in = time.perf_counter() if lc is not None else 0.0
-            self._step(self._read(reads.pop(0), t_in, True))
+            self._step(self._read(reads.pop(0), t_in, True,
+                                  self._reader is not None))
+        return was_open and self._closed.is_set()
 
     def _step(self, body: Iterator[float]) -> None:
         """Run a read's body to its end, or to a limiter's pause:
@@ -600,14 +653,28 @@ class Connection(asyncio.Protocol):
             self._resume_reading("limiter")
 
     def _pause_reading(self, why: str) -> None:
+        # (the reader thread's slot: a flag, and no recv begins after)
         if not self._paused:
-            self.writer.pause_reading()
+            if self._reader is not None:
+                self._reader.pause(self._rslot)
+            else:
+                self.writer.pause_reading()
         self._paused.add(why)
 
     def _resume_reading(self, why: str) -> None:
+        # (the reader thread's slot: the flag off, and a re-arm)
         self._paused.discard(why)
         if not self._paused and not self._closed.is_set():
-            self.writer.resume_reading()
+            if self._reader is not None:
+                self._reader.resume(self._rslot)
+            else:
+                self.writer.resume_reading()
+
+    def is_reading(self) -> bool:
+        """Whether the socket is read, by whichever reads it."""
+        if self._reader is not None:
+            return self._reader.reading(self._rslot)
+        return self.writer.is_reading()
 
     def pause_writing(self) -> None:
         # the write buffer is over its high-water mark: what
@@ -636,6 +703,20 @@ class Connection(asyncio.Protocol):
         if self._owed is None:
             self._teardown("closed")
         return False  # the transport closes itself
+
+    # The reader thread's end of a stream and its failed ``recv``, as
+    # the selector transport handles its own: the same `eof_received`,
+    # and the close (an errno aborts, as ``_force_close`` does).
+
+    def on_reader_eof(self) -> None:
+        if not self.eof_received():
+            self.writer.close()
+
+    def on_reader_failed(self, err: int) -> None:
+        log.debug("read from %s failed: errno %d", self.channel.peer, err)
+        if err in (errno.ECONNRESET, errno.EPIPE, errno.ESHUTDOWN):
+            self._failed = True  # the transport's peer_reset errors
+        self.writer.abort()
 
     def connection_lost(self, exc: Optional[Exception]) -> None:
         if self.channel is None:

@@ -345,11 +345,15 @@ class BrokerServer:
             await self.broker._loop.run_in_executor(
                 None, engine.warmup, eng_cfg.batch_max
             )
-        # the native sender thread, before a listener accepts: every
-        # plain-TCP connection takes its slot as it is made
-        from ..ops import sockwriter
+        # the native sender and reader threads, before a listener
+        # accepts: every plain-TCP connection takes its slots as it is
+        # made
+        from ..ops import sockreader, sockwriter
 
         self.broker.sender = sockwriter.start(
+            self.broker._loop, self.broker.profiler.loop
+        )
+        self.broker.reader = sockreader.start(
             self.broker._loop, self.broker.profiler.loop
         )
         if eng_cfg.batch_publish:
@@ -760,6 +764,10 @@ class BrokerServer:
             # no flush scope is left; the thread drains and is joined
             self.broker.sender.stop()
             self.broker.sender = None
+        if self.broker.reader is not None:
+            # (likewise: every connection has closed its slot)
+            self.broker.reader.stop()
+            self.broker.reader = None
         if self.telemetry is not None:
             await self.telemetry.stop()
             self.telemetry = None

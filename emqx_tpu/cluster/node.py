@@ -566,20 +566,25 @@ class ClusterNode:
     def _apply_snapshot(
         self, node: str, filters: List[str], snap_seq: int
     ) -> None:
-        """Purge-and-replace `node`'s routes from a full-sync snapshot,
-        then re-apply any ops that raced past the snapshot cut (casts
+        """Replace `node`'s routes with a full-sync snapshot, with any
+        ops that raced past the snapshot cut applied over it (casts
         travel on a different connection than the sync reply, so a
         freshly added route may already be applied locally while absent
-        from the snapshot — a blind purge would silently drop it)."""
-        self.routes.purge_node(node)
-        for flt in filters:
-            self.routes.add_route(flt, node)
+        from the snapshot — a blind purge would silently drop it).
+        Adds first, then deletes what is gone: a route in both the old
+        and the new set never leaves the table, so a window matched
+        on an executor thread meanwhile still finds it."""
+        want = set(filters)
         for seq, op, flt in self._op_log.get(node, ()):
             if seq > snap_seq and op in ("add", "del"):
                 if op == "add":
-                    self.routes.add_route(flt, node)
+                    want.add(flt)
                 else:
-                    self.routes.delete_route(flt, node)
+                    want.discard(flt)
+        for flt in want:
+            self.routes.add_route(flt, node)
+        for flt in self.routes.routes_of(node) - want:
+            self.routes.delete_route(flt, node)
         self._peer_seq[node] = max(self._peer_seq.get(node, 0), snap_seq)
 
     async def _sync_with(self, peer: str) -> None:

@@ -340,7 +340,7 @@ class WindowRecord(Laps):
     __slots__ = (
         "wall0", "n_msgs", "n_deliveries", "n_clients", "n_clips",
         "path", "breaker_open", "source", "subs", "e2e_ms", "loop",
-        "loop_cpu", "decide_rows", "decide_rows_padded", "sender",
+        "loop_cpu", "decide_rows", "decide_rows_padded", "sender", "reader",
         "rules_firings", "rules_firings_run", "n_clients_plain",
         "n_host_rows", "n_shared", "n_shared_vector", "gc",
     )
@@ -390,6 +390,9 @@ class WindowRecord(Laps):
         # the native sender thread's own clock over the same stretch:
         # (seconds inside send(2), send calls), None without a sender
         self.sender: Optional[Tuple[float, int]] = None
+        # the native reader thread's: (seconds inside recv(2), recv
+        # calls, the loop's wake-ups for its batches), None without one
+        self.reader: Optional[Tuple[float, int, int]] = None
         # the process's garbage collections over that stretch: (seconds
         # paused, collections), None where no `gc.callbacks` entry is
         # armed (`flightrec.FlightRecorder.arm_watchdog`)
@@ -445,6 +448,10 @@ class WindowRecord(Laps):
         if self.sender is not None:
             loop["sender_send_us"] = round(self.sender[0] * 1e6, 1)
             loop["sender_writes"] = self.sender[1]
+        if self.reader is not None:
+            loop["reader_recv_us"] = round(self.reader[0] * 1e6, 1)
+            loop["reader_recvs"] = self.reader[1]
+            loop["reader_wakes"] = self.reader[2]
         if self.gc is not None:
             loop["gc_us"] = round(self.gc[0] * 1e6, 1)
             loop["gc_collections"] = self.gc[1]
@@ -481,13 +488,14 @@ class WindowRecord(Laps):
         }
 
 
-def _growth(clock, base: Tuple[float, int]):
-    """An outside clock's ``(seconds, count)`` since ``base`` and the
-    reading that is the next base; ``(None, base)`` without a clock."""
+def _growth(clock, base: Tuple):
+    """An outside clock's ``(seconds, count, ...)`` since ``base`` and
+    the reading that is the next base; ``(None, base)`` without a
+    clock."""
     if clock is None:
         return None, base
     now = clock()
-    return (now[0] - base[0], now[1] - base[1]), now
+    return tuple(a - b for a, b in zip(now, base)), now
 
 
 class LoopClock:
@@ -501,7 +509,10 @@ class LoopClock:
     loop its serialize and one list append, and each flush scope one
     hand-over (`egress_submit`): the ``send`` itself is on the
     thread's clock, `take_sender`.  ``egress_parked`` counts the
-    hand-backs after a socket would not take a write.
+    hand-backs after a socket would not take a write.  A read the
+    native reader thread did (``ingress_reads_native``) reached the
+    loop in a batch: its ``recv`` is on the thread's clock,
+    `take_reader`, with the loop's wake-ups for the batches.
 
     **The turn clock.**  Once `install` has wrapped the ``select`` of
     the loop's selector (`BrokerServer.start`; `uninstall` in
@@ -522,6 +533,11 @@ class LoopClock:
                turns that had the phase, so a reader can correct by a
                ``recv`` a turn); a timer or a write-ready callback
                that the turn runs after its first read is billed here.
+               Where the native reader thread reads
+               (`ops.sockreader.SockReader`), the phase is its wake-up
+               callback, from its start to the last read handed to a
+               `ReadTurn`, and the re-arm call behind the turn's run:
+               what the loop still pays to receive.
     ``reads``  `ReadTurn._run`: the previous turn's reads handled in a
                row.  ``ingress_s`` is inside it (but for a read that an
                EOF or a limiter's pay-off flushed); the difference is
@@ -566,7 +582,7 @@ class LoopClock:
         "egress_s", "egress_writes", "egress_packets",
         "egress_in_window_s",
         "egress_writes_sender", "egress_parked",
-        "ingress_reads_direct",
+        "ingress_reads_direct", "ingress_reads_native",
     )
     # the turn clock's phases, in the order of `_spent`, and its two
     # counters: in a record only while the selector is hooked
@@ -592,6 +608,8 @@ class LoopClock:
         # process's collections'
         self.sender_clock = None
         self._sender_base = (0.0, 0)
+        self.reader_clock = None
+        self._reader_base = (0.0, 0, 0)
         self.gc_clock = None
         self._gc_base = (0.0, 0)
         self._bursts: deque = deque(maxlen=self.BURSTS_CAP)
@@ -607,12 +625,14 @@ class LoopClock:
 
     def ingress(self, t0: float, packets: int, publishes: int,
                 acks: int, acks_run: int = 0,
-                direct: bool = False) -> None:
+                direct: bool = False, native: bool = False) -> None:
         """One socket read's parse + channel work, begun at ``t0``:
         ``acks_run`` of its ``acks`` crossed as `AckRun`s (a run
         counts as the packets it carries, everywhere here);
         ``direct``: handled in the transport's own callback
-        (``ingress_reads_direct``), no task woken for it.  A read
+        (``ingress_reads_direct``), no task woken for it;
+        ``native``: and its ``recv`` done on the reader thread
+        (``ingress_reads_native``).  A read
         that held PUBLISH packets alone, or acknowledgements alone,
         is also the cost of its packet type (``ingress_publish_*``,
         ``ingress_ack_*``); a mixed or partial one is in neither."""
@@ -624,6 +644,8 @@ class LoopClock:
         self.ingress_reads += 1
         if direct:
             self.ingress_reads_direct += 1
+            if native:
+                self.ingress_reads_native += 1
         if publishes == packets:
             if packets:
                 self.ingress_publish_s += dt
@@ -673,6 +695,21 @@ class LoopClock:
         take; None while no sender runs."""
         grown, self._sender_base = _growth(
             self.sender_clock, self._sender_base
+        )
+        return grown
+
+    def attach_reader(self, clock) -> None:
+        """A reader thread started (its clock begins at zero):
+        ``clock() -> (seconds inside recv(2), recv calls, wake-ups
+        taken)``, all only growing; or (None) stopped."""
+        self.reader_clock = clock
+        self._reader_base = (0.0, 0, 0)
+
+    def take_reader(self) -> Optional[Tuple[float, int, int]]:
+        """The reader thread's clock, its growth since the previous
+        take; None while no reader runs."""
+        grown, self._reader_base = _growth(
+            self.reader_clock, self._reader_base
         )
         return grown
 
@@ -817,6 +854,7 @@ class LoopClock:
         self.stamp_cpu()
         self.take()
         self.take_sender()
+        self.take_reader()
         self.take_gc()
         self._bursts.clear()
         self._open.clear()
@@ -915,6 +953,7 @@ class Profiler:
         if lc is not None:
             rec.loop = lc.take()
             rec.sender = lc.take_sender()
+            rec.reader = lc.take_reader()
             rec.gc = lc.take_gc()
             lc.in_window = False
         hist = self._hist

@@ -4,8 +4,9 @@
 (``native/build/`` is not committed) on the first `load` in a fresh
 checkout and again when the source is newer than the binary.  The
 bindings (``ops/*_native.py``, ``ops/dispatchasm.py``,
-``ops/sockwriter.py``, ``ds/native.py``) hand `load` their short name
-and a function that sets ``restype`` / ``argtypes``; a library that
+``ops/sockwriter.py``, ``ops/sockreader.py``, ``ds/native.py``) hand
+`load` their short name and a function that sets ``restype`` /
+``argtypes``; a library that
 does not build or load is logged once and `load` returns None for the
 life of the process: the caller's Python twin serves.
 

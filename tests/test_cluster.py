@@ -278,6 +278,40 @@ def test_sync_snapshot_does_not_lose_racing_route_add():
     run(t())
 
 
+def test_sync_snapshot_never_unroutes_a_route_it_keeps():
+    """A full sync that runs while windows are matched on executor
+    threads: a route in both the table and the snapshot is matched at
+    every step of the apply (no purge-then-re-add gap), a route the
+    snapshot dropped goes, a new one comes."""
+
+    async def t():
+        srv_a, a = await start_node("a")
+        srv_b, b = await start_node("b", seeds=[("a", "127.0.0.1", a.port)])
+        await settle(0.2)
+        for flt in ("kept/#", "gone/#"):
+            b.routes.add_route(flt, "a")
+        steps = []
+        add, delete = b.routes.add_route, b.routes.delete_route
+
+        def seen(fn):
+            def step(flt, node):
+                out = fn(flt, node)
+                steps.append(b.routes.match_nodes(["kept/x"])[0])
+                return out
+            return step
+
+        b.routes.add_route = seen(add)
+        b.routes.delete_route = seen(delete)
+        b._apply_snapshot("a", ["kept/#", "new/#"], b._peer_seq.get("a", 0))
+        assert steps and all("a" in nodes for nodes in steps)
+        assert b.routes.routes_of("a") == {"kept/#", "new/#"}
+        assert b.routes.match_nodes(["gone/x"])[0] == set()
+        await stop_node(srv_b, b)
+        await stop_node(srv_a, a)
+
+    run(t())
+
+
 def test_restart_epoch_resets_op_log():
     """A peer restart (new epoch) must invalidate the buffered op log so
     old-incarnation ops are not replayed over the new snapshot."""

@@ -1,9 +1,12 @@
-"""The two read paths of `Connection` on real loopback sockets: the
-transport's own callback (plain TCP and TLS listeners) against the
-`run` coroutine (what a WebSocket's stream is read by, here over the
-same plain sockets).  The same byte stream gives the same packets,
-the same bytes back and the same counts on both; every close reason ends a connection once; every await
-of the coroutine is reading paused and resumed on the direct path."""
+"""The read paths of `Connection` on real loopback sockets: the direct
+path, where a plain-TCP socket's ``recv`` is the native reader
+thread's (``native``) or, the library absent, the transport's own
+callback (``callback``, which TLS always takes), against the `run`
+coroutine (what a WebSocket's stream is read by, here over the same
+plain sockets).  The same byte stream gives the same packets, the same
+bytes back and the same counts on every path; every close reason ends
+a connection once; every await of the coroutine is reading paused and
+resumed on both direct paths, each case once a path."""
 
 import asyncio
 import random
@@ -20,11 +23,14 @@ from emqx_tpu.codec import mqtt as C
 from emqx_tpu.config import BrokerConfig, ListenerConfig
 from emqx_tpu.hooks import with_async
 from emqx_tpu.observability import LoopClock
+from emqx_tpu.ops import nativelib, sockreader
 from mqtt_client import TestClient
 from test_listeners import WsTestClient, _make_cert
 from tools.racesim import run_seeds
 
-PATHS = ("direct", "coroutine")
+# the direct path's two readers, and the coroutine
+DIRECT = ("native", "callback")
+PATHS = DIRECT + ("coroutine",)
 
 
 def run(coro):
@@ -36,11 +42,16 @@ class Served:
     says) that takes `path`, publishes handled inside the read that
     brought them (no batcher, no device), with every
     `Channel.handle_in` and `Channel.connection_lost` recorded.  For
-    the coroutine no listener type is direct: the socket is read
-    through a reader / writer pair, as a WebSocket's stream is."""
+    the callback the reader library is absent, as where it does not
+    build; for the coroutine no listener type is direct: the socket is
+    read through a reader / writer pair, as a WebSocket's stream is."""
 
     def __init__(self, monkeypatch, path, batcher=False, listen=None,
                  **mqtt):
+        if path == "native" and sockreader.load() is None:
+            pytest.skip("native sockreader not built")
+        if path == "callback":
+            monkeypatch.setitem(nativelib._libs, "sockreader", None)
         if path == "coroutine":
             monkeypatch.setattr(Listener, "DIRECT", ())
         cfg = BrokerConfig()
@@ -154,7 +165,7 @@ async def play(monkeypatch, path, frames, cuts):
     taken the one before (one read a piece, on either path)."""
     stream = b"".join(frames)
     async with Served(monkeypatch, path) as s:
-        assert s.listener._direct == (path == "direct")
+        assert s.listener._direct == (path != "coroutine")
         sock = socket.create_connection(("127.0.0.1", s.port))
         sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         sock.setblocking(False)
@@ -184,22 +195,28 @@ async def play(monkeypatch, path, frames, cuts):
         return s.handled, bytes(back), counts, [r for _ch, r in s.lost]
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 @pytest.mark.parametrize(
     "case", ["one_read", "a_frame_a_read", "every_byte"] + list(range(12))
 )
-def test_the_same_stream_reads_the_same_on_both_paths(case, monkeypatch):
+def test_the_same_stream_reads_the_same_on_both_paths(case, direct,
+                                                      monkeypatch):
     frames = the_stream()
     cuts = cuts_of(case, frames)
     got = {}
-    for path in PATHS:
+    for path in (direct, "coroutine"):
         with monkeypatch.context() as mp:
             got[path] = run(play(mp, path, frames, cuts))
-    handled, back, counts, lost = got["direct"]
+    handled, back, counts, lost = got[direct]
     assert len(handled) >= 12 and back  # the stream did something
     assert counts["ingress_reads"] == len(cuts) + 1
     assert counts.pop("ingress_reads_direct") == counts["ingress_reads"]
+    assert counts.pop("ingress_reads_native") == (
+        counts["ingress_reads"] if direct == "native" else 0
+    )
     handled_c, back_c, counts_c, lost_c = got["coroutine"]
     assert counts_c.pop("ingress_reads_direct") == 0
+    assert counts_c.pop("ingress_reads_native") == 0
     assert handled == handled_c
     assert back == back_c
     assert counts == counts_c
@@ -250,9 +267,10 @@ async def one_shot(monkeypatch, path, listen, ctx):
         )
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 @pytest.mark.parametrize("kind", ["tcp", "ssl"])
 def test_a_read_is_handled_before_the_close_that_came_with_it(
-    kind, tmp_path, monkeypatch
+    kind, direct, tmp_path, monkeypatch
 ):
     listen, ctx = {}, None
     if kind == "ssl":
@@ -260,23 +278,152 @@ def test_a_read_is_handled_before_the_close_that_came_with_it(
         listen = {"type": "ssl", "certfile": certfile, "keyfile": keyfile}
         ctx = ssl.create_default_context(cafile=certfile)
     got = {}
-    for path in PATHS:
+    for path in (direct, "coroutine"):
         with monkeypatch.context() as mp:
             got[path] = run(one_shot(mp, path, listen, ctx))
-    handled, delivered, lost = got["direct"]
+    handled, delivered, lost = got[direct]
     assert [h[0] for h in handled] == [C.PUBLISH, C.DISCONNECT]
     # the publish arrived, and no will: the broker saw the DISCONNECT
     assert delivered == [b"once"]
     assert lost == ["closed"]
-    assert got["coroutine"] == got["direct"]
+    assert got["coroutine"] == got[direct]
+
+
+async def eof_after_data(monkeypatch, path):
+    """A publisher with a will sends a PUBLISH and closes with no
+    DISCONNECT: its last read, then the end of its stream."""
+    v = C.MQTT_V5
+    async with Served(monkeypatch, path) as s:
+        sub = TestClient(s.port, "eof-sub")
+        await sub.connect()
+        await sub.subscribe("eof/#", qos=0)
+        _r, w = await asyncio.open_connection("127.0.0.1", s.port)
+        w.write(C.serialize(C.Connect(
+            client_id="eof", proto_ver=v,
+            will=C.Will(topic="eof/will", payload=b"gone")), v))
+        await settle(lambda: s.broker.cm.channel("eof") is not None)
+        w.write(C.serialize(C.Publish(topic="eof/x", payload=b"last"), v))
+        w.close()
+        got = [(await sub.expect(C.PUBLISH)).payload for _ in range(2)]
+        await settle(lambda: len(s.lost) == 1)
+        await sub.disconnect()
+        return got, [r for _ch, r in s.lost][:1]
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_an_end_of_stream_is_handled_after_the_last_read(path,
+                                                         monkeypatch):
+    """The bytes first, then the end: the publish is delivered ahead of
+    the will the unannounced close sends, on every path."""
+    got, lost = run(eof_after_data(monkeypatch, path))
+    assert got == [b"last", b"gone"]
+    assert lost == ["closed"]
+
+
+def metric(name):
+    """A per-layer metric as the benchmark reads it from a ring."""
+    import importlib.util
+    import json
+    import os
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "metrics", name + ".json")) as f:
+        how = json.load(f)
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + how["reader"],
+        os.path.join(bench, "readers", how["reader"] + ".py"),
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return lambda ring: mod.read({"ring": ring, "window_s": 1.0},
+                                 **how["args"])
+
+
+@pytest.mark.parametrize("kind,share", [("tcp", 100.0), ("ssl", 0.0)])
+def test_a_tls_listener_keeps_data_received_and_reads_no_native_read(
+    kind, share, tmp_path, monkeypatch
+):
+    """With the reader thread running, a TLS connection's bytes still
+    come through its transport's ``data_received`` (``sslproto`` owns
+    them) and none of its reads is native; a plain-TCP one's are all
+    the thread's: ``ingress_native_read_pct.flood`` reads 100.0 and
+    0.0, ``ingress_direct_read_pct.flood`` 100.0 on both."""
+    if sockreader.load() is None:
+        pytest.skip("native sockreader not built")
+    listen, ctx = {}, None
+    if kind == "ssl":
+        certfile, keyfile = _make_cert(tmp_path)
+        listen = {"type": "ssl", "certfile": certfile, "keyfile": keyfile}
+        ctx = ssl.create_default_context(cafile=certfile)
+    from_transport = []
+    real = Connection.data_received
+
+    def data_received(conn, data):
+        # (the reader thread's hand-off is `SockReader._on_event`)
+        import sys
+
+        caller = sys._getframe(1).f_code.co_name
+        from_transport.append(caller != "_on_event")
+        return real(conn, data)
+
+    async def main():
+        v = C.MQTT_V5
+        async with Served(monkeypatch, "native", listen=listen) as s:
+            assert s.broker.reader is not None
+            monkeypatch.setattr(Connection, "data_received", data_received)
+            sub_r, sub_w = await asyncio.open_connection(
+                "localhost", s.port, ssl=ctx
+            )
+            sub_w.write(C.serialize(C.Connect(
+                client_id="m-sub", proto_ver=v), v) + C.serialize(
+                    C.Subscribe(packet_id=1, subscriptions=[
+                        C.Subscription(topic_filter="m/#", qos=1)]), v))
+            _r, w = await asyncio.open_connection(
+                "localhost", s.port, ssl=ctx
+            )
+            w.write(C.serialize(C.Connect(client_id="m-pub",
+                                          proto_ver=v), v))
+            await settle(lambda: s.broker.cm.channel("m-pub") is not None
+                         and s.broker.cm.channel("m-sub") is not None)
+            conn = s.conn_of("m-pub")
+            assert (conn._reader is None) == (kind == "ssl")
+            assert conn.writer.is_reading() == (kind == "ssl")
+            for i in range(20):
+                w.write(C.serialize(C.Publish(
+                    topic="m/%d" % i, payload=b"x", qos=1,
+                    packet_id=i + 1), v))
+                await asyncio.sleep(0.002)
+            got, parser = 0, C.StreamParser(version=v)
+            while got < 20:
+                data = await asyncio.wait_for(sub_r.read(65536), 10)
+                assert data
+                got += sum(p.type == C.PUBLISH for p in parser.feed(data))
+            w.close()
+            sub_w.close()
+            await settle(lambda: not s.listener._conns)
+            return s.broker.profiler.windows(limit=256)
+
+    ring = run(main())
+    assert from_transport and all(from_transport) == (kind == "ssl")
+    assert not any(from_transport) == (kind == "tcp")
+    assert sum(r["loop_ingress_reads"] for r in ring) >= 20
+    assert metric("ingress_native_read_pct.flood")(ring) == share
+    assert metric("ingress_direct_read_pct.flood")(ring) == 100.0
 
 
 # ------------------------------------------------- who takes which path
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 def test_plain_tcp_and_tls_read_direct_limited_or_not_websocket_does_not(
-    tmp_path, monkeypatch
+    direct, tmp_path, monkeypatch
 ):
+    if direct == "native" and sockreader.load() is None:
+        pytest.skip("native sockreader not built")
+    if direct == "callback":
+        monkeypatch.setitem(nativelib._libs, "sockreader", None)
+
     async def main():
         certfile, keyfile = _make_cert(tmp_path)
         cfg = BrokerConfig()
@@ -337,12 +484,24 @@ def test_plain_tcp_and_tls_read_direct_limited_or_not_websocket_does_not(
             # each, four SUBSCRIBEs, five publishes)
             assert 14 <= lc.ingress_reads_direct < lc.ingress_reads
             assert len(tcp._conns) == 1 and len(tls._conns) == 2
+            assert (srv.broker.reader is None) == (direct == "callback")
+            # (the TLS clients' reads are direct and not native)
+            if direct == "native":
+                assert 0 < lc.ingress_reads_native < lc.ingress_reads_direct
+            else:
+                assert lc.ingress_reads_native == 0
             for lst in (tcp, tls, msgs, byts):
                 for conn in lst._conns:
                     assert isinstance(conn, Connection)
                     assert conn.reader is None
                     assert conn.writer.get_protocol() is conn
                     assert (conn.limiter is None) == (lst in (tcp, tls))
+                    # plain TCP's recv is the reader thread's, its
+                    # transport paused for good; TLS reads itself
+                    native = direct == "native" and lst is not tls
+                    assert (conn._reader is not None) == native
+                    assert conn.writer.is_reading() != native
+                    assert conn.is_reading()
             (task,) = ws._conns
             assert isinstance(task, asyncio.Task)
             for c in clients + [pub]:
@@ -353,11 +512,14 @@ def test_plain_tcp_and_tls_read_direct_limited_or_not_websocket_does_not(
     run(main())
 
 
-def test_a_direct_connection_costs_one_timer_task_and_no_other(monkeypatch):
+@pytest.mark.parametrize("direct", DIRECT)
+def test_a_direct_connection_costs_one_timer_task_and_no_other(
+    direct, monkeypatch
+):
     """No reader task, no `StreamReader`: a hundred plain-TCP clients
     are a hundred `_timers` tasks, before and after traffic."""
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             before = asyncio.all_tasks()
             loop = asyncio.get_running_loop()
             socks = []
@@ -388,12 +550,15 @@ def test_a_direct_connection_costs_one_timer_task_and_no_other(monkeypatch):
     run(main())
 
 
-def test_a_turns_reads_are_handled_in_one_run_after_its_recvs(monkeypatch):
+@pytest.mark.parametrize("direct", DIRECT)
+def test_a_turns_reads_are_handled_in_one_run_after_its_recvs(
+    direct, monkeypatch
+):
     """Fifty sockets readable in one poll: fifty `recv`s, then one
     `ReadTurn._run` handles the fifty reads in a row (one `call_soon`
     a turn, none a read)."""
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             loop = asyncio.get_running_loop()
             socks = [await a_client(s, f"t{i}") for i in range(50)]
             runs = []
@@ -421,14 +586,42 @@ def test_a_turns_reads_are_handled_in_one_run_after_its_recvs(monkeypatch):
     run(main())
 
 
+def test_a_close_in_a_turn_is_torn_down_before_the_turns_later_reads():
+    """Two reads of one turn (one batch of the reader thread's): the
+    first closes its connection, whose teardown is queued; the second
+    is handled after that teardown, as it would be after the next
+    poll, so a reconnect's CONNECT finds the old session gone."""
+    seen = []
+
+    class Closing:
+        def _handle_reads(self):
+            asyncio.get_running_loop().call_soon(seen.append, "teardown")
+            return True  # the read closed its connection
+
+    class Reading:
+        def _handle_reads(self):
+            seen.append("read")
+
+    async def main():
+        turn = ReadTurn()
+        for conn in (Closing(), Reading()):
+            turn.add(conn)
+        await asyncio.sleep(0.01)
+
+    run(main())
+    assert seen == ["teardown", "read"]
+
+
+@pytest.mark.parametrize("direct", DIRECT)
 @pytest.mark.parametrize("where", ["handle_in", "after_packets", "teardown"])
-def test_one_connections_fault_costs_the_others_no_read(where, monkeypatch):
+def test_one_connections_fault_costs_the_others_no_read(where, direct,
+                                                        monkeypatch):
     """A bug in a packet's handler, in what follows a read's packets
     (the congestion tests), in the teardown itself: the turn's other
     reads are off their sockets already and are handled all the
     same."""
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             loop = asyncio.get_running_loop()
             socks = {c: await a_client(s, c) for c in ("a", "bad", "z")}
 
@@ -585,11 +778,12 @@ def test_every_close_reason_ends_a_connection_once(
     run(main())
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 def test_a_congestion_alarm_does_not_outlive_a_direct_connection(
-    monkeypatch
+    direct, monkeypatch
 ):
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             sock = await a_client(s, "slow")
             conn = s.conn_of("slow")
             monkeypatch.setattr(conn, "_tbuf", lambda: 2 << 20)
@@ -625,7 +819,7 @@ async def a_pair(s):
     await sub.subscribe("bp/#", qos=0)
     pub = await a_client(s, "bp-pub")
     conn = s.conn_of("bp-pub")
-    assert conn.writer.is_reading() and not conn._paused
+    assert conn.is_reading() and not conn._paused
     return sub, pub, conn
 
 
@@ -635,11 +829,12 @@ async def in_order(sub, n):
         assert pkt.payload[:6] == b"%06d" % i, (i, pkt.payload[:6])
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 def test_a_congested_lane_pauses_reading_and_its_release_resumes_it(
-    monkeypatch
+    direct, monkeypatch
 ):
     async def main():
-        async with Served(monkeypatch, "direct", batcher=True) as s:
+        async with Served(monkeypatch, direct, batcher=True) as s:
             batcher = s.broker.batcher
             sub, pub, conn = await a_pair(s)
             # the collector stalls: lanes fill, nothing drains them
@@ -649,7 +844,7 @@ def test_a_congested_lane_pauses_reading_and_its_release_resumes_it(
             base = s.received()
             await loop.sock_sendall(pub, first)
             await settle(lambda: conn._paused == {"lane"})
-            assert not conn.writer.is_reading()
+            assert not conn.is_reading()
             await loop.sock_sendall(pub, publishes(10, 30))
             await asyncio.sleep(0.1)
             # the further publishes wait in the kernel, unread
@@ -659,7 +854,7 @@ def test_a_congested_lane_pauses_reading_and_its_release_resumes_it(
             batcher._inflight_drain.set()
             await in_order(sub, 30)
             await settle(lambda: not conn._paused)
-            assert conn.writer.is_reading()
+            assert conn.is_reading()
             assert sum(1 for t, _i, _p in s.handled
                        if t == C.PUBLISH) == 30
             pub.close()
@@ -688,11 +883,12 @@ class SlowVerdicts:
         )
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 def test_a_saturated_deferral_chain_pauses_reading_and_its_drain_resumes_it(
-    monkeypatch
+    direct, monkeypatch
 ):
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             sub, pub, conn = await a_pair(s)
             slow = SlowVerdicts(s.broker)
             conn.channel.DEFER_HIGH, conn.channel.DEFER_LOW = 8, 2
@@ -701,7 +897,7 @@ def test_a_saturated_deferral_chain_pauses_reading_and_its_drain_resumes_it(
             base = s.received()
             await loop.sock_sendall(pub, first)
             await settle(lambda: conn._paused == {"defer"})
-            assert not conn.writer.is_reading()
+            assert not conn.is_reading()
             await loop.sock_sendall(pub, publishes(10, 30))
             await asyncio.sleep(0.1)
             assert s.received() == base + len(first)
@@ -709,22 +905,23 @@ def test_a_saturated_deferral_chain_pauses_reading_and_its_drain_resumes_it(
             slow.open.set()
             await in_order(sub, 30)
             await settle(lambda: not conn._paused)
-            assert conn.writer.is_reading()
+            assert conn.is_reading()
             pub.close()
             await sub.disconnect()
 
     run(main())
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 def test_a_write_buffer_over_its_mark_pauses_reading_until_it_drains(
-    monkeypatch
+    direct, monkeypatch
 ):
     """The client sends and does not read what comes back to it: the
     transport's buffer passes its high-water mark (`pause_writing`,
     what `writer.drain()` waits out on the other path) and the broker
     stops reading that client."""
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
             sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
             sock.setblocking(False)
@@ -745,7 +942,7 @@ def test_a_write_buffer_over_its_mark_pauses_reading_until_it_drains(
             stream = publishes(0, n, size=size)
             send = loop.create_task(loop.sock_sendall(sock, stream))
             await settle(lambda: "write" in conn._paused)
-            assert not conn.writer.is_reading()
+            assert not conn.is_reading()
             stalled = s.received()
             await asyncio.sleep(0.1)
             assert s.received() == stalled < len(stream)
@@ -763,15 +960,18 @@ def test_a_write_buffer_over_its_mark_pauses_reading_until_it_drains(
             await send
             assert seen == [b"%06d" % i for i in range(n)]
             await settle(lambda: not conn._paused)
-            assert conn.writer.is_reading()
+            assert conn.is_reading()
             sock.close()
 
     run(main())
 
 
-def test_two_reasons_at_once_resume_only_when_both_are_gone(monkeypatch):
+@pytest.mark.parametrize("direct", DIRECT)
+def test_two_reasons_at_once_resume_only_when_both_are_gone(
+    direct, monkeypatch
+):
     async def main():
-        async with Served(monkeypatch, "direct") as s:
+        async with Served(monkeypatch, direct) as s:
             sub, pub, conn = await a_pair(s)
             slow = SlowVerdicts(s.broker)
             conn.channel.DEFER_HIGH, conn.channel.DEFER_LOW = 8, 2
@@ -788,10 +988,10 @@ def test_two_reasons_at_once_resume_only_when_both_are_gone(monkeypatch):
             await in_order(sub, 10)
             await settle(lambda: conn._paused == {"write"})
             await asyncio.sleep(0.05)
-            assert not conn.writer.is_reading()
+            assert not conn.is_reading()
             assert s.received() == base + len(first)
             conn.resume_writing()
-            assert not conn._paused and conn.writer.is_reading()
+            assert not conn._paused and conn.is_reading()
             for i in range(10, 20):
                 pkt = await sub.expect(C.PUBLISH, timeout=20)
                 assert pkt.payload[:6] == b"%06d" % i
@@ -799,9 +999,9 @@ def test_two_reasons_at_once_resume_only_when_both_are_gone(monkeypatch):
             conn.pause_writing()
             conn._pause_reading("lane")
             conn.resume_writing()
-            assert not conn.writer.is_reading()
+            assert not conn.is_reading()
             conn._resume_reading("lane")
-            assert conn.writer.is_reading()
+            assert conn.is_reading()
             pub.close()
             await sub.disconnect()
 
@@ -812,15 +1012,16 @@ def test_two_reasons_at_once_resume_only_when_both_are_gone(monkeypatch):
 LIMITED = {"messages_rate": 20.0}
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 def test_a_limiter_on_a_direct_connection_is_paid_between_packets(
-    monkeypatch
+    direct, monkeypatch
 ):
     """A listener with a rate is direct like any other: a limiter's
     pauses are reading paused and a timer, the rest of that read's
     packets (and a read the transport still hands over) wait in order
     and none passes unpaid."""
     async def main():
-        async with Served(monkeypatch, "direct", listen=LIMITED) as s:
+        async with Served(monkeypatch, direct, listen=LIMITED) as s:
             assert s.listener._direct
             sub, pub, conn = await a_pair(s)
             assert conn.limiter is not None and conn.reader is None
@@ -828,7 +1029,7 @@ def test_a_limiter_on_a_direct_connection_is_paid_between_packets(
             t0 = loop.time()
             await loop.sock_sendall(pub, publishes(0, 30))  # one read
             await settle(lambda: conn._paused == {"limiter"})
-            assert not conn.writer.is_reading()
+            assert not conn.is_reading()
             # (a TLS transport may hold a record it had decrypted)
             conn.data_received(publishes(30, 35))
             await asyncio.sleep(0.05)
@@ -856,11 +1057,12 @@ def test_a_limiter_on_a_direct_connection_is_paid_between_packets(
     run(main())
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 @pytest.mark.parametrize(
     "how", ["half_close", "close", "reset", "tls_close", "abort"]
 )
 def test_a_publisher_gone_inside_a_limiters_pause_loses_no_packet(
-    how, tmp_path, monkeypatch
+    how, direct, tmp_path, monkeypatch
 ):
     """The publisher is gone before its read's pauses are paid.  Plain
     TCP, reading paused, learns of it after them.  A TLS transport
@@ -876,7 +1078,7 @@ def test_a_publisher_gone_inside_a_limiters_pause_loses_no_packet(
             listen.update(type="ssl", certfile=certfile, keyfile=keyfile)
             ctx = ssl.create_default_context(cafile=certfile)
         v = C.MQTT_V5
-        async with Served(monkeypatch, "direct", listen=listen) as s:
+        async with Served(monkeypatch, direct, listen=listen) as s:
             sub_r, sub_w = await asyncio.open_connection(
                 "localhost", s.port, ssl=ctx
             )
@@ -935,14 +1137,14 @@ def test_a_publisher_gone_inside_a_limiters_pause_loses_no_packet(
 # ------------------------------------------- forced interleavings
 
 
-def _release_before_pause():
+def _release_before_pause(direct):
     """The chain's verdicts come at once, so under a forced schedule
     the drain can land before, between and after the reads that test
     for saturation: every publish is handled once, in order, and the
     connection ends reading."""
     async def main():
         with pytest.MonkeyPatch.context() as mp:
-            async with Served(mp, "direct") as s:
+            async with Served(mp, direct) as s:
                 sub, pub, conn = await a_pair(s)
                 SlowVerdicts(s.broker, held=False)
                 conn.channel.DEFER_HIGH, conn.channel.DEFER_LOW = 4, 1
@@ -955,7 +1157,7 @@ def _release_before_pause():
                         conn.resume_writing()
                 await in_order(sub, 40)
                 await settle(lambda: not conn._paused)
-                assert conn.writer.is_reading()
+                assert conn.is_reading()
                 assert sum(1 for t, _i, _p in s.handled
                            if t == C.PUBLISH) == 40
                 pub.close()
@@ -963,13 +1165,13 @@ def _release_before_pause():
     return main()
 
 
-def _pause_during_close():
+def _pause_during_close(direct):
     """The connection is kicked while reading is paused for two
     reasons; the releases come after: nothing resumes, nothing
     raises, and the teardown ran once."""
     async def main():
         with pytest.MonkeyPatch.context() as mp:
-            async with Served(mp, "direct") as s:
+            async with Served(mp, direct) as s:
                 sub, pub, conn = await a_pair(s)
                 slow = SlowVerdicts(s.broker)
                 conn.channel.DEFER_HIGH, conn.channel.DEFER_LOW = 4, 1
@@ -987,16 +1189,20 @@ def _pause_during_close():
                 assert [r for ch, r in s.lost
                         if ch is conn.channel] == ["closed"]
                 assert conn.writer.is_closing()
-                assert not conn.writer.is_reading()
+                assert not conn.is_reading()
                 pub.close()
                 await sub.disconnect()
     return main()
 
 
+@pytest.mark.parametrize("direct", DIRECT)
 @pytest.mark.parametrize(
     "workload", [_release_before_pause, _pause_during_close]
 )
-def test_pauses_and_releases_under_forced_interleavings(workload):
-    outcomes = run_seeds(workload, seeds=range(8), timeout=60.0)
+def test_pauses_and_releases_under_forced_interleavings(workload, direct):
+    if direct == "native" and sockreader.load() is None:
+        pytest.skip("native sockreader not built")
+    outcomes = run_seeds(lambda: workload(direct), seeds=range(8),
+                         timeout=60.0)
     bad = [o for o in outcomes if o.failed]
     assert not bad, f"{bad[0].label}: {bad[0].error!r}"

@@ -219,17 +219,19 @@ def test_the_compiler_and_its_flags_are_named_once():
 
 
 def test_every_binding_loads_through_the_loader():
-    """The six bindings keep their module-level ``load()`` (the
+    """The seven bindings keep their module-level ``load()`` (the
     benchmark and chip_smoke.py call them by name) and hand back the
     loader's own object."""
     from emqx_tpu.ds import native as dslog
-    from emqx_tpu.ops import dispatchasm, sockwriter, sortutil_native
+    from emqx_tpu.ops import dispatchasm, sockreader, sockwriter
+    from emqx_tpu.ops import sortutil_native
     from emqx_tpu.ops import tokdict_native, trie_native
 
     for name, mod in (
         ("hosttrie", trie_native), ("sortutil", sortutil_native),
         ("tokdict", tokdict_native), ("dispatchasm", dispatchasm),
         ("dslog", dslog), ("sockwriter", sockwriter),
+        ("sockreader", sockreader),
     ):
         lib = mod.load()
         assert lib is not None, name

@@ -771,11 +771,13 @@ async def _served_windows(enable=True, rounds=6, trace_dir=None,
         ))
     try:
         for k in range(rounds):
-            for i in range(8):
-                await pub.send(C.Publish(
-                    topic=f"f/{i}/x", payload=b'{"v": 3}', qos=1,
-                    packet_id=k * 8 + i + 1,
-                ))
+            # (a round's eight in one write: one read on either read
+            # path, so a window a round, however eagerly it is read)
+            pub.writer.write(b"".join(C.serialize(C.Publish(
+                topic=f"f/{i}/x", payload=b'{"v": 3}', qos=1,
+                packet_id=k * 8 + i + 1,
+            ), pub.version) for i in range(8)))
+            await pub.writer.drain()
             for _ in range(8):
                 await sub.recv_publish(timeout=20)
             for _ in range(8):
