@@ -21,6 +21,17 @@ configuration broken underneath the timed path.  Each must read
                   subscriptions alone, the frame never leaves, so a
                   group misses that publish (however the member was
                   picked)
+    churn_sub_lost   a churned SUBSCRIBE acknowledged and never routed:
+                  the session keeps the subscription and the SUBACK
+                  grants it, but the broker inserts no route, so what
+                  it was owed is missing
+    churn_unsub_kept an UNSUBSCRIBE acknowledged and its route kept: the
+                  session drops the subscription and the UNSUBACK
+                  reports success, but the route stays, so later
+                  publishes reach a subscription that has ended
+    churn_unsub_ignored an UNSUBSCRIBE ignored whole: no route or session
+                  change and no UNSUBACK, so the subscription's end is
+                  never answered (an unanswered life, `client_errors`)
 
 A fault takes the `BrokerServer` before `start()` and returns what
 undoes it (tests run several in one process).
@@ -129,9 +140,49 @@ def share_lost(server):
     return undo
 
 
+def _churned(real, skipped):
+    """``real`` for every call but a churn connection's (`loadgen`
+    names them ``churn<i>``), which gets ``skipped``."""
+    def call(clientid, *a, **kw):
+        if clientid.startswith("churn"):
+            return skipped
+        return real(clientid, *a, **kw)
+    return call
+
+
+def churn_sub_lost(server):
+    broker = server.broker
+    broker.subscribe = _churned(broker.subscribe, [])
+    return lambda: None
+
+
+def churn_unsub_kept(server):
+    broker = server.broker
+    broker.unsubscribe = _churned(broker.unsubscribe, True)
+    return lambda: None
+
+
+def churn_unsub_ignored(server):
+    from emqx_tpu.broker.channel import Channel
+
+    real = Channel._handle_unsubscribe
+
+    def handle(self, pkt):
+        if not self.client.clientid.startswith("churn"):
+            real(self, pkt)
+
+    Channel._handle_unsubscribe = handle
+
+    def undo():
+        Channel._handle_unsubscribe = real
+    return undo
+
+
 FAULTS = {"lost_match": lost_match, "weak_ack": weak_ack,
           "host_decide": host_decide, "host_match": host_match,
-          "share_lost": share_lost}
+          "share_lost": share_lost, "churn_sub_lost": churn_sub_lost,
+          "churn_unsub_kept": churn_unsub_kept,
+          "churn_unsub_ignored": churn_unsub_ignored}
 
 
 def main(argv=None) -> int:

@@ -4,11 +4,12 @@ speaks MQTT 5 over loopback TCP and stamps CLOCK_MONOTONIC, which every
 process of one host shares.
 
 It imports neither JAX nor `emqx_tpu`: the few packet types it needs
-(CONNECT/CONNACK, SUBSCRIBE/SUBACK, PUBLISH, PUBACK, DISCONNECT) are
-written out here, so a change to the program's codec cannot move the
-yardstick.  `tests/benchmark` holds it against `emqx_tpu.codec`.
+(CONNECT/CONNACK, SUBSCRIBE/SUBACK, UNSUBSCRIBE/UNSUBACK, PUBLISH,
+PUBACK, DISCONNECT) are written out here, so a change to the program's
+codec cannot move the yardstick.  `tests/benchmark` holds it against
+`emqx_tpu.codec`.
 
-One process is either subscribers or publishers.  The parent writes a
+One process is subscribers, publishers or churn.  The parent writes a
 plan (one JSON line) to stdin, then commands, one a line:
 
     subscribers   window T0 T1   stamp CPU time at both instants
@@ -18,12 +19,23 @@ plan (one JSON line) to stdin, then commands, one a line:
                   flood T0 T1    closed loop, ``inflight`` a connection
                   paced T0 T1 [[seq, due], ...]   open loop
                   stop           -> header line + raw arrays, then exit
+    churn         start T        subscribe -> hold -> unsubscribe cycles
+                                 from T, open loop, the plan's block of
+                                 due offsets repeated every ``period``
+                  window T0 T1   from T0 the block once more, then at T1
+                                 every live subscription ends; once each
+                                 SUBACK and UNSUBACK is in, or the plan's
+                                 ``ack_wait_s`` has passed ->
+                                 {"churn_done": ...}
+                  dump           -> header line + raw arrays
+                  stop           -> the same, then exit
 
-Replies are one JSON line each; ``stop`` follows its line with the raw
-bytes of the arrays it names, in order.
+Replies are one JSON line each; ``dump`` and ``stop`` follow their line
+with the raw bytes of the arrays it names, in order.
 """
 
 import asyncio
+import functools
 import json
 import os
 import resource
@@ -33,7 +45,7 @@ from array import array
 
 MQTT_V5 = 5
 CONNECT, CONNACK, PUBLISH, PUBACK = 1, 2, 3, 4
-SUBSCRIBE, SUBACK, DISCONNECT = 8, 9, 14
+SUBSCRIBE, SUBACK, UNSUBSCRIBE, UNSUBACK, DISCONNECT = 8, 9, 10, 11, 14
 
 now = time.monotonic
 
@@ -69,6 +81,13 @@ def subscribe(packet_id: int, filters, qos: int) -> bytes:
     for flt in filters:
         body += utf8(flt) + bytes([qos])
     return packet(SUBSCRIBE << 4 | 2, body)
+
+
+def unsubscribe(packet_id: int, filters) -> bytes:
+    body = packet_id.to_bytes(2, "big") + b"\x00"
+    for flt in filters:
+        body += utf8(flt)
+    return packet(UNSUBSCRIBE << 4 | 2, body)
 
 
 def publish_head(topic: str, qos: int, payload_len: int) -> bytes:
@@ -141,7 +160,8 @@ def parse_publish(buf: bytes, first: int, p: int, end: int):
 
 
 def parse_suback(buf: bytes, p: int, end: int):
-    """``(packet_id, reason_codes)`` of a v5 SUBACK."""
+    """``(packet_id, reason_codes)`` of a v5 SUBACK, or of an UNSUBACK,
+    which has the same layout (MQTT 5.0 sections 3.9, 3.11)."""
     pid = buf[p] << 8 | buf[p + 1]
     plen, p = read_varint(buf, p + 2)
     return pid, list(buf[p + plen:end])
@@ -162,6 +182,32 @@ def payload_of(seq: int) -> bytes:
 PAYLOAD_LEN = len(payload_of(0))
 
 
+# ---------------------------------------------------------- connections
+
+async def open_all(port: int, makers) -> list:
+    """One connection a protocol factory, 64 opened at a time; returns
+    the protocols once each has set its ``ready`` event."""
+    loop = asyncio.get_running_loop()
+    conns = []
+    for lo in range(0, len(makers), 64):
+        made = await asyncio.gather(*(
+            loop.create_connection(m, "127.0.0.1", port)
+            for m in makers[lo:lo + 64]
+        ))
+        conns += [proto for _, proto in made]
+    await asyncio.wait_for(asyncio.gather(*(
+        c.ready.wait() for c in conns
+    )), 300)
+    return conns
+
+
+def close_all(conns) -> None:
+    for c in conns:
+        if not c.closed:
+            c.transport.write(disconnect())
+            c.transport.close()
+
+
 # --------------------------------------------------------- subscribers
 
 class Subscriber(asyncio.Protocol):
@@ -175,6 +221,7 @@ class Subscriber(asyncio.Protocol):
         self.dups = 0          # deliveries with the DUP flag
         self.granted = None
         self.transport = None
+        self.ready = asyncio.Event()   # set at the SUBACK
         self.closed = False
 
     def connection_made(self, transport):
@@ -206,7 +253,7 @@ class Subscriber(asyncio.Protocol):
                 self.transport.write(subscribe(1, self.filters, self.qos))
             elif kind == SUBACK:
                 self.granted = parse_suback(buf, p, end)[1]
-                self.owner.subscribed()
+                self.ready.set()
         if acks:
             self.transport.write(b"".join(acks))
 
@@ -215,28 +262,14 @@ class Subscribers:
     def __init__(self, plan):
         self.plan = plan
         self.conns = []
-        self.n_subscribed = 0
-        self.all_subscribed = asyncio.Event()
         self.cpu = [0.0, 0.0]
-
-    def subscribed(self):
-        self.n_subscribed += 1
-        if self.n_subscribed == len(self.plan["conns"]):
-            self.all_subscribed.set()
 
     async def run(self, lines):
         loop = asyncio.get_running_loop()
-        port = self.plan["port"]
-        for lo in range(0, len(self.plan["conns"]), 64):
-            batch = self.plan["conns"][lo:lo + 64]
-            made = await asyncio.gather(*(
-                loop.create_connection(
-                    lambda i=lo + k, c=c: Subscriber(self, i, *c),
-                    "127.0.0.1", port,
-                ) for k, c in enumerate(batch)
-            ))
-            self.conns += [proto for _, proto in made]
-        await asyncio.wait_for(self.all_subscribed.wait(), 300)
+        self.conns = await open_all(self.plan["port"], [
+            functools.partial(Subscriber, self, i, *c)
+            for i, c in enumerate(self.plan["conns"])
+        ])
         reply({"ready": True, "granted": [c.granted for c in self.conns]})
         async for cmd in lines:
             if cmd[0] == "window":
@@ -258,10 +291,7 @@ class Subscribers:
         for c in self.conns:
             out.write(c.ts.tobytes())
         out.flush()
-        for c in self.conns:
-            if not c.closed:
-                c.transport.write(disconnect())
-                c.transport.close()
+        close_all(self.conns)
 
     def stamp_cpu(self, k):
         self.cpu[k] = time.process_time()
@@ -278,7 +308,7 @@ class Publisher(asyncio.Protocol):
         self.queue = []        # paced: publishes waiting for an inflight slot
         self.n = 0             # publishes this connection has sent
         self.transport = None
-        self.connected = asyncio.Event()
+        self.ready = asyncio.Event()   # set at the CONNACK
         self.closed = False
 
     def connection_made(self, transport):
@@ -334,7 +364,7 @@ class Publisher(asyncio.Protocol):
             elif kind == CONNACK:
                 if buf[p + 1] != 0:
                     raise RuntimeError(f"pub{self.conn}: CONNACK {buf[p + 1]}")
-                self.connected.set()
+                self.ready.set()
         if not o.outstanding:
             o.idle.set()
 
@@ -368,19 +398,11 @@ class Publishers:
 
     async def run(self, lines):
         loop = asyncio.get_running_loop()
-        port = self.plan["port"]
-        mine = self.plan["conns"]
-        for lo in range(0, len(mine), 64):
-            made = await asyncio.gather(*(
-                loop.create_connection(
-                    lambda c=c: Publisher(self, c), "127.0.0.1", port
-                ) for c in mine[lo:lo + 64]
-            ))
-            for _, proto in made:
-                self.conns[proto.conn] = proto
-        await asyncio.wait_for(asyncio.gather(*(
-            c.connected.wait() for c in self.conns.values()
-        )), 300)
+        self.conns = {c.conn: c for c in await open_all(
+            self.plan["port"],
+            [functools.partial(Publisher, self, c)
+             for c in self.plan["conns"]],
+        )}
         reply({"ready": True})
         async for cmd in lines:
             if cmd[0] == "warm":
@@ -436,10 +458,242 @@ class Publishers:
         for a in (self.seqs, self.dues, self.sends, self.acks):
             out.write(a.tobytes())
         out.flush()
-        for c in self.conns.values():
-            if not c.closed:
-                c.transport.write(disconnect())
-                c.transport.close()
+        close_all(self.conns.values())
+
+
+# ---------------------------------------------------------------- churn
+
+class ChurnConn(asyncio.Protocol):
+    """One churn connection: it holds whatever subscriptions its owner
+    gives it, and files every delivery it receives by its own index."""
+
+    def __init__(self, owner, idx):
+        self.owner, self.idx = owner, idx
+        self.buf = b""
+        self.pid = 0
+        self.pending = {}      # packet id -> the life a SUB/UNSUBACK ends
+        self.transport = None
+        self.ready = asyncio.Event()   # set at the CONNACK
+        self.closed = False
+
+    def connection_made(self, transport):
+        self.transport = transport
+        transport.write(connect(f"churn{self.idx}"))
+
+    def connection_lost(self, exc):
+        self.closed = True
+
+    def send(self, make, life, *args) -> None:
+        """``make(packet_id, *args)``, with a packet id no ack this
+        connection still waits for holds."""
+        self.pid = self.pid % 65535 + 1
+        while self.pid in self.pending:
+            self.pid = self.pid % 65535 + 1
+        self.pending[self.pid] = life
+        self.transport.write(make(self.pid, *args))
+
+    def data_received(self, data):
+        t = now()
+        buf = self.buf + data if self.buf else data
+        pkts, self.buf = split(buf)
+        o = self.owner
+        acks = []
+        for first, p, end in pkts:
+            kind = first >> 4
+            if kind == PUBLISH:
+                _, qos, _dup, pid, payload = parse_publish(buf, first, p, end)
+                if qos:
+                    acks.append(puback(pid))
+                o.r_conn.append(self.idx)
+                o.r_seq.append(int(payload[SEQ_AT:SEQ_AT + SEQ_W]))
+                o.r_t.append(t)
+                o.r_qos.append(qos)
+            elif kind in (SUBACK, UNSUBACK):
+                pid, codes = parse_suback(buf, p, end)
+                life = self.pending.pop(pid, None)
+                if life is None:
+                    o.stray += 1
+                elif kind == SUBACK:
+                    o.subacked(life, t, codes)
+                else:
+                    o.unsubacked(life, t, codes)
+            elif kind == CONNACK:
+                if buf[p + 1] != 0:
+                    raise RuntimeError(
+                        f"churn{self.idx}: CONNACK {buf[p + 1]}"
+                    )
+                self.ready.set()
+        if acks:
+            self.transport.write(b"".join(acks))
+
+
+class Churn:
+    """Subscribe -> hold -> unsubscribe cycles on a seeded open-loop
+    schedule: cycle ``i`` of this process subscribes its connection
+    ``i % clients`` to its filter ``i % len(filters)`` at QoS
+    ``qos[i % len(qos)]`` when it is due, and unsubscribes it
+    ``dwell_s`` after that, whatever the broker answered.  A filter is
+    not taken again before the UNSUBACK of its last life is in (a cycle
+    that would is counted in ``clashes`` and skipped); the plan's
+    filters are disjoint on the pool, so no topic matches two filters
+    that one connection holds, and each receipt belongs to one life.
+
+    For each life it keeps four instants on the clock the other roles
+    stamp (SUBSCRIBE sent, SUBACK in, UNSUBSCRIBE sent, UNSUBACK in;
+    0.0 where one never came) and for each delivery the connection, the
+    sequence number, the instant and the QoS."""
+
+    ARRAYS = ["conn:q", "filter:q", "qos:q", "sub:d", "suback:d",
+              "unsub:d", "unsuback:d", "r_conn:q", "r_seq:q", "r_t:d",
+              "r_qos:q"]
+
+    def __init__(self, plan):
+        self.plan = plan
+        self.fids = [g for g, _f in plan["filters"]]
+        self.flts = [f for _g, f in plan["filters"]]
+        self.dues, self.period = plan["dues"], plan["period"]
+        self.dwell, self.qos = plan["dwell_s"], plan["qos"]
+        self.conns = []
+        # a life: its connection and filter here, then the arrays
+        self.life_c, self.life_f = [], []
+        self.conn, self.filt, self.q = array("q"), array("q"), array("q")
+        self.sub, self.suback = array("d"), array("d")
+        self.unsub, self.unsuback = array("d"), array("d")
+        self.r_conn, self.r_seq = array("q"), array("q")
+        self.r_t, self.r_qos = array("d"), array("q")
+        self.live = {}         # life -> the timer of its UNSUBSCRIBE
+        self.holding = set()   # filters whose last UNSUBACK is not in
+        self.outstanding = 0   # SUBSCRIBEs and UNSUBSCRIBEs not acked
+        self.refused = self.stray = self.clashes = 0
+        self.idle = asyncio.Event()
+        self.t0 = self.t1 = None
+        self.cpu = [0.0, 0.0]
+
+    def stamp_cpu(self, k):
+        self.cpu[k] = time.process_time()
+
+    # ---------------------------------------------------------- a life
+
+    def begin(self, i: int, due: float) -> None:
+        f = i % len(self.flts)
+        if f in self.holding:
+            self.clashes += 1
+            return
+        self.holding.add(f)
+        life = len(self.life_c)
+        c = self.conns[i % len(self.conns)]
+        q = self.qos[i % len(self.qos)]
+        self.life_c.append(c)
+        self.life_f.append(f)
+        self.conn.append(c.idx)
+        self.filt.append(self.fids[f])
+        self.q.append(q)
+        for a in (self.suback, self.unsub, self.unsuback):
+            a.append(0.0)
+        self.outstanding += 1
+        self.sub.append(now())
+        c.send(subscribe, life, [self.flts[f]], q)
+        self.live[life] = asyncio.get_running_loop().call_at(
+            due + self.dwell, self.end, life
+        )
+
+    def end(self, life: int) -> None:
+        self.live.pop(life).cancel()
+        self.outstanding += 1
+        self.unsub[life] = now()
+        self.life_c[life].send(unsubscribe, life,
+                               [self.flts[self.life_f[life]]])
+
+    def subacked(self, life: int, t: float, codes) -> None:
+        self.suback[life] = t
+        self.refused += codes != [self.q[life]]
+        self.acked()
+
+    def unsubacked(self, life: int, t: float, codes) -> None:
+        self.unsuback[life] = t
+        self.refused += codes != [0]
+        self.holding.discard(self.life_f[life])
+        self.acked()
+
+    def acked(self) -> None:
+        self.outstanding -= 1
+        if not self.outstanding:
+            self.idle.set()
+
+    # -------------------------------------------------------- schedule
+
+    def warm_dues(self, start: float):
+        """The block's due instants from ``start`` on, a block every
+        ``period`` seconds, until the window opens."""
+        b = 0
+        while self.dues:
+            for d in self.dues:
+                yield start + b * self.period + d
+            b += 1
+
+    async def schedule(self, start: float) -> None:
+        i = 0
+        for at in self.warm_dues(start):
+            await asyncio.sleep(max(at - now(), 0))
+            if self.t0 is not None and at >= self.t0:
+                break
+            self.begin(i, at)
+            i += 1
+        while self.t0 is None:
+            await asyncio.sleep(0.05)
+        for d in self.dues:
+            await asyncio.sleep(max(self.t0 + d - now(), 0))
+            self.begin(i, self.t0 + d)
+            i += 1
+        await asyncio.sleep(max(self.t1 - now(), 0))
+        for life in list(self.live):
+            self.end(life)
+        if self.outstanding:
+            self.idle.clear()
+            try:
+                await asyncio.wait_for(self.idle.wait(),
+                                       self.plan["ack_wait_s"])
+            except asyncio.TimeoutError:
+                pass
+        reply({"churn_done": True, "outstanding": self.outstanding})
+
+    def dump(self) -> None:
+        reply({
+            "lives": len(self.conn), "receipts": len(self.r_seq),
+            "refused": self.refused, "stray": self.stray,
+            "clashes": self.clashes, "cpu_s": self.cpu[1] - self.cpu[0],
+            "closed": sum(c.closed for c in self.conns),
+            "arrays": self.ARRAYS,
+        })
+        out = sys.stdout.buffer
+        for a in (self.conn, self.filt, self.q, self.sub, self.suback,
+                  self.unsub, self.unsuback, self.r_conn, self.r_seq,
+                  self.r_t, self.r_qos):
+            out.write(a.tobytes())
+        out.flush()
+
+    async def run(self, lines):
+        loop = asyncio.get_running_loop()
+        self.conns = await open_all(self.plan["port"], [
+            functools.partial(ChurnConn, self, c) for c in self.plan["conns"]
+        ])
+        reply({"ready": True})
+        task = None
+        async for cmd in lines:
+            if cmd[0] == "start":
+                task = asyncio.ensure_future(self.schedule(float(cmd[1])))
+            elif cmd[0] == "window":
+                self.t0, self.t1 = float(cmd[1]), float(cmd[2])
+                loop.call_at(self.t0, self.stamp_cpu, 0)
+                loop.call_at(self.t1, self.stamp_cpu, 1)
+            elif cmd[0] == "dump":
+                self.dump()
+            elif cmd[0] == "stop":
+                break
+        if task is not None:
+            task.cancel()
+        self.dump()
+        close_all(self.conns)
 
 
 # ------------------------------------------------------------------ main
@@ -460,8 +714,8 @@ async def stdin_lines():
 
 
 async def amain(plan) -> None:
-    role = Subscribers if plan["role"] == "sub" else Publishers
-    await role(plan).run(stdin_lines())
+    role = {"sub": Subscribers, "pub": Publishers, "churn": Churn}
+    await role[plan["role"]](plan).run(stdin_lines())
 
 
 def main() -> None:

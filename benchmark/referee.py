@@ -1,13 +1,15 @@
 """The plain reference and the comparison that decides `correct`.
 
-MQTT topic matching, the group rule of shared subscriptions and the five
-`traffic.rule_sql` predicates written out directly: no `emqx_tpu.topic`,
-no `HostTrie`, no `rules.runtime`, nothing the program made.  It
-answers, for the publishes the window really sent, who must have
-received what and which rule must have fired, and `judge` holds the run
-to the configuration's guarantees.  Every number compared is exact, so
-every limit is 0.
+MQTT topic matching, the group rule of shared subscriptions, the
+lifetime of a subscription and the five `traffic.rule_sql` predicates
+written out directly: no `emqx_tpu.topic`, no `HostTrie`, no
+`rules.runtime`, nothing the program made.  It answers, for the
+publishes the window really sent, who must have received what and which
+rule must have fired, and `judge` holds the run to the configuration's
+guarantees.  Every number compared is exact, so every limit is 0.
 """
+
+from bisect import bisect_right
 
 import numpy as np
 
@@ -150,8 +152,10 @@ class Expected:
     subscriber.  ``sub_seqs[j]`` is subscriber j's plain part,
     ``group_seqs[g]`` group g's."""
 
-    def __init__(self, pool, subs, n_rules: int, seqs: np.ndarray):
+    def __init__(self, pool, subs, n_rules: int, seqs: np.ndarray,
+                 churn: "Churned" = None):
         self.seqs = np.sort(np.asarray(seqs, dtype=np.int64))
+        self.churn = churn
         self.subs = subs
         self.n_rules = n_rules
         self.n_pool = len(pool)
@@ -211,8 +215,163 @@ class Expected:
         ]
         self.n_deliveries = sum(len(s) for s in self.sub_seqs) + sum(
             len(s) for s in self.group_seqs
-        )
+        ) + (churn.n_owed if churn is not None else 0)
         self.n_firings = sum(len(s) for s in self.rule_seqs)
+
+
+
+class Churned:
+    """Subscriptions made and ended while the run was open, and what each
+    was owed and may have got (MQTT 5.0 sections 3.8.4 and 3.10.4).
+
+    A life holds one filter ``f`` on one connection, between four
+    instants: SUBSCRIBE sent ``s0``, SUBACK in ``s1``, UNSUBSCRIBE sent
+    ``u0``, UNSUBACK in ``u1`` (0.0: it never came).  A publish ``p``
+    whose topic ``f`` matches, sent at ``send(p)`` and acknowledged at
+    ``ack(p)`` (0.0: never), is
+
+    * **owed** to the life if ``send(p) > s1`` and ``0 < ack(p) < u0``.
+      The broker inserts the route before it writes the SUBACK
+      (`emqx_tpu/broker/channel.py` `_subscribe_body`: `_do_subscribe`
+      -> `Broker.subscribe` -> `Router.subscribe` ->
+      `MatchEngine.insert`, then `Suback`), so ``p`` is matched with
+      ``f`` in its window; and it acknowledges a QoS1 publish only once
+      that window has been dispatched to the sessions
+      (`emqx_tpu/broker/broker.py` `_dispatch_loop`: `publish_dispatch`,
+      then `fut.set_result`, whose callback writes the PUBACK), so ``p``
+      had been added for delivery before the UNSUBSCRIBE left, and
+      section 3.10.4 lets the server stop adding new messages only
+      (`Session.unsubscribe` drops no queued message);
+    * **permitted** if ``ack(p) > s0`` (or ``p`` was never acknowledged)
+      and ``send(p) < u1``: a delivery needs ``p`` matched after the
+      SUBSCRIBE was read and before the UNSUBSCRIBE was, and its PUBACK
+      follows that match;
+    * **unexpected** if received outside the permitted band, as is a
+      receipt on a topic that no filter its connection held matches.
+
+    A receipt belongs to the life of its connection whose filter matches
+    its topic and that subscribed last before the receipt came.  The
+    filters are disjoint on the pool (a topic on two of them is refused,
+    an `Overlap`), so one life at most can claim it.
+
+    Both acknowledgements are owed too (sections 3.8.4 and 3.10.4): a
+    life that its SUBACK or its UNSUBACK never answered, once the run
+    has waited for them, is ``unanswered``, which `judge` counts under
+    ``client_errors``.  Without that, an UNSUBSCRIBE ignored whole (the
+    route kept, no UNSUBACK) would leave the band open (``u1`` = inf)
+    and every later receipt permitted."""
+
+    def __init__(self, pool, filters, lives: dict, seqs, sends, acks):
+        self.n_pool = len(pool)
+        tree = FilterTree()
+        for k, flt in enumerate(filters):
+            tree.add(flt, k)
+        self.filter_of = np.full(len(pool), -1, np.int64)
+        for t, topic in enumerate(pool):
+            hit = tree.match(topic)
+            if len(hit) > 1:
+                raise Overlap(
+                    f"topic {topic!r} matches more than one churned "
+                    f"filter: {[filters[k] for k in hit]}"
+                )
+            if hit:
+                self.filter_of[t] = hit[0]
+        L = {k: np.asarray(v) for k, v in lives.items()}
+        self.conn, self.filt, self.qos = L["conn"], L["filter"], L["qos"]
+        self.s0, self.s1 = L["sub"], L["suback"]
+        self.u0 = np.where(L["unsub"] > 0, L["unsub"], np.inf)
+        self.u1 = np.where(L["unsuback"] > 0, L["unsuback"], np.inf)
+        self.unanswered = int(((self.s1 == 0) | (self.u1 == np.inf)).sum())
+        seqs = np.asarray(seqs, dtype=np.int64)
+        order = np.argsort(seqs, kind="stable")
+        self.p_seq = seqs[order]
+        self.p_send = np.asarray(sends)[order]
+        self.p_ack = np.asarray(acks)[order]
+        # the publishes each filter matches
+        p_f = self.filter_of[self.p_seq % self.n_pool]
+        by_f = np.argsort(p_f, kind="stable")
+        cuts = np.searchsorted(p_f[by_f], np.arange(len(filters) + 1))
+        owed_life, owed_seq = [], []
+        for life, f in enumerate(self.filt.tolist()):
+            if not self.s1[life]:
+                continue
+            mine = by_f[cuts[f]:cuts[f + 1]]
+            ack = self.p_ack[mine]
+            ok = ((self.p_send[mine] > self.s1[life]) & (ack > 0)
+                  & (ack < self.u0[life]))
+            owed_seq.append(self.p_seq[mine[ok]])
+            owed_life.append(np.full(int(ok.sum()), life, np.int64))
+        cat = (lambda a: np.concatenate(a) if a
+               else np.zeros(0, np.int64))
+        self.owed_life, self.owed_seq = cat(owed_life), cat(owed_seq)
+        self.n_owed = len(self.owed_seq)
+        # (connection, filter) -> its lives by SUBSCRIBE instant
+        self._lives: dict = {}
+        for life in np.lexsort((self.s0, self.filt, self.conn)).tolist():
+            at = self._lives.setdefault(
+                (int(self.conn[life]), int(self.filt[life])), ([], [])
+            )
+            at[0].append(float(self.s0[life]))
+            at[1].append(life)
+
+    def attribute(self, r_conn, r_seq, r_t) -> np.ndarray:
+        """The life each receipt belongs to; -1 where none can claim it."""
+        f = self.filter_of[np.asarray(r_seq, dtype=np.int64) % self.n_pool]
+        out = np.full(len(f), -1, np.int64)
+        for i, (c, fi, t) in enumerate(zip(np.asarray(r_conn).tolist(),
+                                           f.tolist(),
+                                           np.asarray(r_t).tolist())):
+            at = self._lives.get((c, fi))
+            if at is not None:
+                k = bisect_right(at[0], t)
+                if k:
+                    out[i] = at[1][k - 1]
+        return out
+
+    @staticmethod
+    def _pairs(life, seq) -> np.ndarray:
+        return np.asarray(life, np.int64) << 32 | np.asarray(seq, np.int64)
+
+    def missing(self, life, r_seq) -> np.ndarray:
+        """The owed (life, publish) pairs no receipt answers: a mask
+        over ``owed_seq``."""
+        got = self._pairs(life, r_seq)[np.asarray(life) >= 0]
+        return ~np.isin(self._pairs(self.owed_life, self.owed_seq), got)
+
+    def judge(self, publishers: int, r_conn, r_seq, r_t, r_qos) -> tuple:
+        """``(missing, unexpected, duplicated, out_of_order, wrong_qos,
+        the sequence numbers of the owed publishes that are missing)``
+        over the churned subscriptions."""
+        r_seq = np.asarray(r_seq, np.int64)
+        r_conn = np.asarray(r_conn, np.int64)
+        life = self.attribute(r_conn, r_seq, r_t)
+        miss = self.missing(life, r_seq)
+        mine = life >= 0
+        # nobody's receipts: unexpected once a (connection, publish)
+        stray = np.unique(self._pairs(r_conn[~mine], r_seq[~mine]))
+        lf, sq = life[mine], r_seq[mine]
+        pos = np.minimum(np.searchsorted(self.p_seq, sq),
+                         max(len(self.p_seq) - 1, 0))
+        sent = (len(self.p_seq) > 0) & (self.p_seq[pos] == sq)
+        ack, send = self.p_ack[pos], self.p_send[pos]
+        band = sent & ((ack > self.s0[lf]) | (ack == 0)) & (send < self.u1[lf])
+        pairs = self._pairs(lf, sq)
+        uniq = np.unique(pairs)
+        unexpected = len(stray) + len(np.unique(pairs[~band]))
+        dups = len(pairs) - len(uniq)
+        # publish order per publisher and topic within one life, as the
+        # session sees it: the receipts in arrival order
+        order = np.argsort(np.asarray(r_t)[mine], kind="stable")
+        key = ((lf * self.n_pool + sq % self.n_pool) * publishers
+               + sq % publishers)[order]
+        by = np.argsort(key, kind="stable")
+        hs, ks = sq[order][by], key[by]
+        disorder = int(((np.diff(hs) <= 0) & (np.diff(ks) == 0)).sum()) - dups
+        # granted QoS: min(publish QoS 1, subscription QoS)
+        bad = np.asarray(r_qos)[mine] != np.minimum(self.qos[lf], 1)
+        wrong_qos = len(np.unique(lf[bad]))
+        return (int(miss.sum()), unexpected, dups, max(disorder, 0),
+                wrong_qos, self.owed_seq[miss])
 
 
 def _diff(have: np.ndarray, want: np.ndarray):
@@ -226,7 +385,7 @@ def _diff(have: np.ndarray, want: np.ndarray):
 
 def judge(exp: Expected, publishers: int, acked: np.ndarray,
           received: list, qos_seen: list, fired_rule: np.ndarray,
-          fired_seq: np.ndarray, device: dict) -> tuple:
+          fired_seq: np.ndarray, device: dict, churn_got=None) -> tuple:
     """The numbers compared, each ``(name, value, limit)``, and the
     sequence numbers of the publishes that failed (a PUBACK, a delivery
     or a firing they owe is missing).
@@ -235,7 +394,10 @@ def judge(exp: Expected, publishers: int, acked: np.ndarray,
     ``received[j]``: subscriber j's deliveries in arrival order;
     ``qos_seen[j]``: bit mask of the QoS its deliveries came at;
     ``fired_*``: every rule firing the actions saw; ``device``: the
-    counts that say which steps the device served.
+    counts that say which steps the device served; ``churn_got``: the
+    churned connections' receipts ``(conn, seq, instant, qos)``, judged
+    by `Churned.judge` where ``exp`` has a churn, whose unanswered lives
+    add to ``device``'s ``client_errors``.
 
     A member's receipts on its groups' topics are pooled by group and
     held to the group's publishes (one given to two members is
@@ -276,6 +438,14 @@ def judge(exp: Expected, publishers: int, acked: np.ndarray,
         m, u, d = _diff(have, want)
         failed.append(m)
         missing, unexpected, dups = missing + len(m), unexpected + len(u), dups + d
+    if exp.churn is not None:
+        m, u, d, o, q, lost = exp.churn.judge(publishers, *churn_got)
+        failed.append(lost)
+        missing, unexpected, dups = missing + m, unexpected + u, dups + d
+        disorder, wrong_qos = disorder + o, wrong_qos + q
+        if exp.churn.unanswered:
+            device = dict(device, client_errors=exp.churn.unanswered
+                          + device.get("client_errors", 0))
     out += [
         ("deliveries_missing", missing, 0),
         ("deliveries_unexpected", unexpected, 0),

@@ -11,9 +11,11 @@ from child processes (`loadgen.py`) that import neither JAX nor the
 program; every end-to-end number is taken at their sockets.
 
 Order: table and rules -> start() -> subscribers -> the engine's own
-delta fold -> warm-up traffic of the cell's mix -> the measured window
--> drain -> read counters and memory -> stop() -> compare with the plain
-reference (`referee.py`) -> print.  Everything before the window is
+delta fold -> warm-up traffic of the cell's mix (and, where the cell
+has a ``churn`` group, subscriptions made and ended on a schedule from
+here to the window's end) -> the measured window -> drain -> read
+counters and memory -> stop() -> compare with the plain reference
+(`referee.py`) -> print.  Everything before the window is
 `setup_s`.  What belongs to one configuration, one cell or one metric
 is a data file found by its name in BENCHMARK.json; see README.md.
 
@@ -94,8 +96,10 @@ def load_cell(name: str, overrides=None):
                 target[k] = v
     # a generator nobody has is refused here, before the chip is taken
     for kind, group in (("table", conf["table"]), ("live", conf["live"]),
-                        ("pool", work["topics"])):
-        traffic.generator(kind, group["generator"])
+                        ("pool", work["topics"]),
+                        ("churn", work.get("churn"))):
+        if group is not None:
+            traffic.generator(kind, group["generator"])
     # which metrics the cell reports is BENCHMARK.json's to say; how
     # each is read is the metric's own file
     metrics = {}
@@ -256,9 +260,84 @@ def preflight(chips: int):
     return devs, peaks[devs[0].device_kind], cache, armed
 
 
+async def churn_dump(kids: list, cmd: str):
+    """Each churn child's header and its arrays, joined across the
+    children by name (``dump``: the child goes on; ``stop``: it ends)."""
+    heads, parts = [], {}
+    for kid in kids:
+        head = await kid.ask(cmd, 60)
+        n = [head["lives"]] * 7 + [head["receipts"]] * 4
+        for spec, a in zip(head["arrays"], await kid.arrays(head, n)):
+            parts.setdefault(spec.split(":")[0], []).append(a)
+        heads.append(head)
+    return heads, {k: np.concatenate(v) for k, v in parts.items()}
+
+
 def body_depth(flt: str) -> int:
     """Levels of a filter's body, its trailing ``#`` apart."""
     return len([w for w in flt.split("/") if w != "#"])
+
+
+def churn_plans(group: dict, pops, seed: int, seconds: float, port: int):
+    """The churned filters and one plan a churn child: connection ``c``,
+    filter ``g`` and arrival ``j`` of the seeded Poisson block go to
+    child ``c % n``, ``g % n`` and ``j % n``.  A child's filter comes
+    round again only after its other filters; a schedule on which it
+    could come round within two ``dwell_s`` (so before its last life's
+    UNSUBACK) is refused."""
+    filters = traffic.churn_filters(group, pops, seed)
+    n, dwell = group["churn_children"], group["dwell_s"]
+    block = traffic.poisson_schedule(group["rate"], seconds, seed)
+    if not 1 <= n <= min(group["clients"], len(block), len(filters)):
+        raise Refused(f"churn: {n} children for {group['clients']} "
+                      f"connections, {len(filters)} filters and "
+                      f"{len(block)} cycles a window")
+    plans = []
+    for k in range(n):
+        mine, dues = list(range(k, len(filters), n)), block[k::n]
+        reps = len(mine) // len(dues) + 2
+        t = np.concatenate([dues + b * seconds for b in range(reps)])
+        gap = float((t[len(mine):] - t[:-len(mine)]).min())
+        if gap < 2 * dwell:
+            raise Refused(f"churn: a filter comes round {gap:.2f} s after "
+                          f"its last cycle began, under 2 x dwell_s: "
+                          f"more filters or a lower rate")
+        plans.append({
+            "role": "churn", "port": port,
+            "conns": list(range(k, group["clients"], n)),
+            "filters": [[g, filters[g]] for g in mine],
+            "dues": dues.tolist(), "period": seconds, "dwell_s": dwell,
+            "qos": group["qos"], "ack_wait_s": DRAIN_S,
+        })
+    return filters, plans
+
+
+def churn_facts(ch: "referee.Churned", t0: float, t1: float,
+                cpu: list, seconds: float, folds: dict, heads: list) -> dict:
+    """What the result line says of the churn, beside what it compares:
+    subscriptions made and ended in the window, the delays of their
+    acknowledgements, the folds the engine made in it (the count and the
+    summed time of the ``engine_delta_fold`` histogram between the
+    window's two instants, ``folds``) and the churn children's CPU
+    share."""
+    def pct(a, q):
+        return float(np.percentile(a, q)) if len(a) else None
+
+    made = (ch.s0 >= t0) & (ch.s0 < t1) & (ch.s1 > 0)
+    ended = (ch.u0 >= t0) & (ch.u0 < t1) & (ch.u1 < np.inf)
+    sub_ms = (ch.s1 - ch.s0)[made] * 1e3
+    unsub_ms = (ch.u1 - ch.u0)[ended] * 1e3
+    return {
+        "subscribed": int(made.sum()), "unsubscribed": int(ended.sum()),
+        "lives": len(ch.s0), "owed": ch.n_owed,
+        "suback_ms_p50": pct(sub_ms, 50), "suback_ms_p99": pct(sub_ms, 99),
+        "unsuback_ms_p50": pct(unsub_ms, 50),
+        "unsuback_ms_p99": pct(unsub_ms, 99),
+        "folds": folds["t1"][0] - folds["t0"][0],
+        "fold_ms": (folds["t1"][1] - folds["t0"][1]) / 1e3,
+        "cpu_pct_busiest": 100.0 * max(cpu) / seconds,
+        "clashes": sum(h["clashes"] for h in heads),
+    }
 
 
 def memory_peak(dev) -> int:
@@ -289,6 +368,7 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
     # keep every window's record, not the last 256: the comparison reads
     # each window's match path, the readers each window's stages
     cfg.profiler.ring_size = 1 << 17
+    churn = work.get("churn")
     apply_env_overrides(cfg)
     if check_config(cfg):
         raise Refused(f"config: {check_config(cfg)}")
@@ -368,6 +448,16 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
             fold_s=fold_s, compiles=compiles.since(mark),
             index=eng.index_stats())
 
+        # ------------------------- churn connections, idle until warm-up
+        churn_kids = []
+        if churn is not None:
+            churn_flts, plans = churn_plans(churn, pops, args.seed,
+                                            args.seconds, port)
+            churn_kids = [await Child.spawn(plan) for plan in plans]
+            children += churn_kids
+            for kid in churn_kids:
+                await kid.hear(300)
+
         # ------------------------------------------ publishers, warm-up
         k_pub = work["publishers"]
         pool = traffic.topic_pool(work["topics"], pops, args.seed, k_pub)
@@ -384,6 +474,8 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
             await kid.hear(300)
         t = time.monotonic()
         mark = compiles.mark()
+        for kid in churn_kids:
+            kid.say(f"start {t + 0.05}")
         for kid in pub_kids:
             kid.say(f"warm {work['warmup_publishes']}")
         warm = [await kid.hear(900) for kid in pub_kids]
@@ -412,8 +504,15 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
         t0 = time.monotonic() + 0.25
         t1 = t0 + args.seconds
         wall0 = time.time() + 0.25  # the ring stamps the wall clock
-        for kid in sub_kids:
+        for kid in churn_kids + sub_kids:
             kid.say(f"window {t0} {t1}")
+        folds = {}
+        if churn_kids:
+            def fold_mark(at):
+                snap = broker.profiler.snapshots().get("engine_delta_fold")
+                folds[at] = (snap.count, snap.sum) if snap else (0, 0.0)
+            loop.call_at(t0, fold_mark, "t0")
+            loop.call_at(t1, fold_mark, "t1")
         if work["loop"] == "paced":
             # sequence numbers follow the warm-up's; connection
             # seq % publishers sends it, so each child gets its own
@@ -453,6 +552,8 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
             traced = {"dir": trace_dir, "wall_ns": w0, "seconds": m1 - m0}
         for kid in pub_kids:
             await kid.hear(args.seconds + DRAIN_S + 120)
+        for kid in churn_kids:
+            await kid.hear(DRAIN_S + 120)
         inside = compiles.since(mark, until=t1)
 
         # ----------------------------------------------------- drain
@@ -468,12 +569,25 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
             stray += head["stray_acks"] + head["closed"]
         sent, dues = np.concatenate(sent), np.concatenate(dues)
         sends, acks = np.concatenate(sends), np.concatenate(acks)
-        exp = referee.Expected(pool, subs, n_rules, sent)
+        churned = None
+        if churn_kids:
+            # every life has ended: its four instants are final
+            heads, lives = await churn_dump(churn_kids, "dump")
+            churned = referee.Churned(pool, churn_flts, lives, sent,
+                                      sends, acks)
+        exp = referee.Expected(pool, subs, n_rules, sent, churn=churned)
+        plain = exp.n_deliveries - (churned.n_owed if churned else 0)
         t = time.monotonic()
         while True:
             got = sum([(await kid.ask("count", 30))["count"]
                        for kid in sub_kids])
-            if got >= exp.n_deliveries or time.monotonic() - t > DRAIN_S:
+            if got >= plain or time.monotonic() - t > DRAIN_S:
+                break
+            await asyncio.sleep(0.1)
+        while churned is not None and time.monotonic() - t <= DRAIN_S:
+            _, r = await churn_dump(churn_kids, "dump")
+            life = churned.attribute(r["r_conn"], r["r_seq"], r["r_t"])
+            if not churned.missing(life, r["r_seq"]).any():
                 break
             await asyncio.sleep(0.1)
         await asyncio.sleep(0.5)  # anything nobody expects still arrives
@@ -495,6 +609,15 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
                 sub_closed += closed
                 at += cnt
             sub_cpu.append(head["cpu_s"])
+        churn_got = None
+        if churned is not None:
+            heads, r = await churn_dump(churn_kids, "stop")
+            churn_got = (r["r_conn"], r["r_seq"], r["r_t"], r["r_qos"])
+            received.append(r["r_seq"])
+            recv_t.append(r["r_t"])
+            sub_cpu += [h["cpu_s"] for h in heads]
+            sub_closed += sum(h["closed"] + h["refused"] + h["stray"]
+                              for h in heads)
 
         # ------------------------------- counters, memory, then stop()
         stats1 = eng.stats()
@@ -553,9 +676,9 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
             + rstats1["fallback_rule_evals"] - rstats0["fallback_rule_evals"]
         )
     numbers, failed_seqs = referee.judge(
-        exp, k_pub, sent[acks > 0], received, qos_seen,
+        exp, k_pub, sent[acks > 0], received[:len(subs)], qos_seen,
         np.frombuffer(fired_rule, dtype=np.int32),
-        np.frombuffer(fired_seq, dtype=np.int64), device,
+        np.frombuffer(fired_seq, dtype=np.int64), device, churn_got,
     )
     in_window = sent[sends >= t0]
     failed = len(np.intersect1d(in_window, failed_seqs))
@@ -637,6 +760,12 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
         device_out["busy_s"] = run["trace"]["busy_s"]
         device_out["window_s"] = run["trace"]["window_s"]
         result["breakdown"] = run["trace"]["breakdown"]
+    if churned is not None:
+        result["churn"] = churn_facts(
+            churned, t0, t1, sub_cpu[len(sub_kids):], args.seconds,
+            folds, heads,
+        )
+    churn_log = {"churn": result["churn"]} if churned is not None else {}
     log(phase="window", cell=cell["name"], seed=args.seed,
         platform=dev.platform, kind=dev.device_kind,
         publishes=run["publishes"], deliveries=run["deliveries"],
@@ -648,7 +777,7 @@ async def run_cell(args, cell, work, conf, metrics, subs, routed, devs,
         compare_s=compare_s, broker_drops=drops,
         setup={"insert_s": insert_s, "start_s": start_s,
                "subscribe_s": subscribe_s, "fold_s": fold_s,
-               "warm_s": warm_s})
+               "warm_s": warm_s}, **churn_log)
     result["compared"] = {n: [v, lim] for n, v, lim in numbers}
     for n, v, lim in numbers:
         print(f"compared {n} {v} limit {lim}", file=sys.stderr)
@@ -674,7 +803,8 @@ def main(argv=None, fault=None, overrides=None) -> int:
         # refused here, before the chip is taken (`referee.Overlap`)
         subs = traffic.generate("live", conf["live"])
         routed = [referee.real_filter(f) for _, flts, _ in subs for f in flts]
-        need = 2 * (work["publishers"] + len(subs)) + 256
+        churners = work["churn"]["clients"] if "churn" in work else 0
+        need = 2 * (work["publishers"] + len(subs) + churners) + 256
         if hard != resource.RLIM_INFINITY and hard < need:
             raise Refused(f"RLIMIT_NOFILE {hard} < {need} sockets")
         compiles = CompileLog()

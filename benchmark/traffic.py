@@ -1,13 +1,16 @@
 """The benchmark's one general generator: tables, rules, live
-subscribers, topic pools and schedules, all from parameters in the
-configuration's and the cell's data files and from ``--seed``.
+subscribers, topic pools, churned filters and schedules, all from
+parameters in the configuration's and the cell's data files and from
+``--seed``.
 
-A data file's ``table``, ``live`` or ``topics`` group names its
-``generator``; the group's other keys are that generator's arguments.
-`generator` finds it by name: the six built-ins below first (`TABLES`,
-`LIVE`, `POOLS`), then ``generators/<name>.py`` and its function
-``table``, ``live`` or ``pool`` (README.md, "A generator", has the
-contract).  So a later deployment brings its generators as a new file.
+A data file's ``table``, ``live``, ``topics`` or ``churn`` group names
+its ``generator``; the group's other keys are that generator's
+arguments (a ``churn`` group's schedule keys apart: `CHURN_KEYS`).
+`generator` finds it by name: the seven built-ins below first
+(`TABLES`, `LIVE`, `POOLS`, `CHURNS`), then ``generators/<name>.py``
+and its function ``table``, ``live``, ``pool`` or ``churn`` (README.md,
+"A generator" and "A churn group", has the contract).  So a later
+deployment brings its generators as a new file.
 
 Imports nothing of the program (`emqx_tpu`) and no JAX, and neither
 does a generator file.  The fleet generators are copies of
@@ -169,11 +172,42 @@ def pool_exact(rng, pool: int, pops):
 POOLS = {"fleet_zipf": pool_fleet_zipf, "exact_topics": pool_exact}
 
 
+# ----------------------------------------------------- churned filters
+
+def churn_fleet(rng, pops, filters: int, first_id: int):
+    """The fleet's own three families over ids from ``first_id`` on,
+    which the live set does not hold (`live_fleet`'s ids end at
+    ``(subscribers - 1) // 5 + 1 + (filters_each - 1) * (subscribers //
+    5 + 1)``, 243 for the fleet's 300 x 4): ``filters`` distinct
+    wildcard filters, a third each of
+    ``vehicles/v<k>/sensors/#``, ``dev/g<k>/+/d<k % 7>`` and
+    ``site/+/floor/f<k>/#``, disjoint on any pool.  The ids the pool
+    draws (`pool_fleet_zipf`: Zipf vehicle ids, device groups and sites
+    below the table's populations) are the only ones that receive."""
+    if filters < 1 or first_id < 0:
+        raise ValueError("filters must be >= 1 and first_id >= 0")
+    out = []
+    for n in range(filters):
+        k = first_id + n // 3
+        if n % 3 == 0:
+            out.append(f"vehicles/v{k}/sensors/#")
+        elif n % 3 == 1:
+            out.append(f"dev/g{k}/+/d{k % 7}")
+        else:
+            out.append(f"site/+/floor/f{k}/#")
+    return out
+
+
+CHURNS = {"fleet_churn": churn_fleet}
+# a churn group's keys that are the schedule's, not the generator's
+CHURN_KEYS = ("clients", "rate", "dwell_s", "qos", "churn_children")
+
+
 # ------------------------------------------------- generators by name
 
 GENERATORS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "generators")
-BUILT_IN = {"table": TABLES, "live": LIVE, "pool": POOLS}
+BUILT_IN = {"table": TABLES, "live": LIVE, "pool": POOLS, "churn": CHURNS}
 
 
 class BadGenerator(Exception):
@@ -182,11 +216,11 @@ class BadGenerator(Exception):
 
 
 def generator(kind: str, name: str):
-    """The ``kind`` (``table``, ``live`` or ``pool``) generator a data
-    file calls ``name``: the built-in of that name, else the function
-    ``kind`` of ``generators/<name>.py``.  A file may not bear a
-    built-in's name: a new file never changes what a cell that is there
-    runs."""
+    """The ``kind`` (``table``, ``live``, ``pool`` or ``churn``)
+    generator a data file calls ``name``: the built-in of that name,
+    else the function ``kind`` of ``generators/<name>.py``.  A file may
+    not bear a built-in's name: a new file never changes what a cell
+    that is there runs."""
     path = os.path.join(GENERATORS, f"{name}.py")
     on_file = re.fullmatch(r"\w+", str(name)) and os.path.exists(path)
     if on_file and any(name in d for d in BUILT_IN.values()):
@@ -239,6 +273,21 @@ def topic_pool(spec: dict, pops, seed: int, publishers: int):
         order = np.random.default_rng(seed).permutation(len(pool))
         pool = [pool[i] for i in order]
     return pool
+
+
+def churn_filters(spec: dict, pops, seed: int):
+    """A ``churn`` group's filters in this seed's order: the generator
+    draws from the group's fixed ``pool_seed`` (default 1) and
+    ``--seed`` only permutes the list, as `topic_pool` does, so every
+    seed churns the same filters."""
+    spec = {k: v for k, v in spec.items() if k not in CHURN_KEYS}
+    pool_seed = spec.pop("pool_seed", 1)
+    flts = generate("churn", spec, np.random.default_rng(pool_seed),
+                    pops=pops)
+    if len(set(flts)) != len(flts):
+        raise BadGenerator("churn generator: its filters are not distinct")
+    order = np.random.default_rng(seed).permutation(len(flts))
+    return [flts[i] for i in order]
 
 
 def poisson_schedule(rate: float, seconds: float, seed: int,

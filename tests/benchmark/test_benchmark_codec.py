@@ -41,6 +41,29 @@ def test_subscribe_is_read_by_the_program(qos):
     ]
 
 
+@pytest.mark.parametrize("filters", [["a/+/b"], ["vehicles/v7/sensors/#",
+                                                   "site/+/floor/f3/#"]])
+def test_unsubscribe_is_read_by_the_program(filters):
+    pkt = parse_one(L.unsubscribe(65535, filters))
+    assert pkt.type == C.UNSUBSCRIBE and pkt.packet_id == 65535
+    assert pkt.topic_filters == filters
+
+
+@pytest.mark.parametrize("filters", [["a/+/b"], ["c/#", "d/x"]])
+def test_program_unsubscribe_is_the_generators_bytes(filters):
+    wire = C.serialize(C.Unsubscribe(packet_id=300, topic_filters=filters),
+                       V5)
+    assert wire == L.unsubscribe(300, filters)
+
+
+@pytest.mark.parametrize("codes", [[0], [0, 0x11]])
+def test_program_unsuback_is_read_by_the_generator(codes):
+    wire = C.serialize(C.Unsuback(packet_id=4097, reason_codes=codes), V5)
+    (first, p, end), = L.split(wire)[0]
+    assert first >> 4 == L.UNSUBACK
+    assert L.parse_suback(wire, p, end) == (4097, codes)
+
+
 @pytest.mark.parametrize("seq", [0, 12345, 2 ** 31 + 5])
 def test_publish_is_read_by_the_program(seq):
     payload = L.payload_of(seq)
@@ -100,6 +123,19 @@ def test_hand_made_spec_bytes():
         [0x32, 0x07, 0x00, 0x01, 0x61, 0x00, 0x01, 0x00, 0x78]
     )
     assert L.varint(321) == bytes([0xC1, 0x02])
+    # section 3.10: UNSUBSCRIBE "a" id 1, no properties
+    assert L.unsubscribe(1, ["a"]) == bytes(
+        [0xA2, 0x06, 0x00, 0x01, 0x00, 0x00, 0x01, 0x61]
+    )
+    # section 3.11: UNSUBACK id 1, no properties, success; both codecs
+    # read the same answer from it
+    unsuback = bytes([0xB0, 0x04, 0x00, 0x01, 0x00, 0x00])
+    pkt = parse_one(unsuback)
+    assert pkt.type == C.UNSUBACK and pkt.packet_id == 1
+    assert pkt.reason_codes == [0]
+    (first, p, end), = L.split(unsuback)[0]
+    assert first >> 4 == L.UNSUBACK
+    assert L.parse_suback(unsuback, p, end) == (1, [0])
 
 
 def test_split_keeps_an_unfinished_tail():

@@ -161,10 +161,12 @@ GENERATOR_FILES = sorted(
 @pytest.mark.parametrize("path", DATA_FILES)
 def test_every_generator_a_data_file_names_resolves(path):
     data = load(BENCH, path)
-    groups = {"table": "table", "live": "live", "topics": "pool"}
+    groups = {"table": "table", "live": "live", "topics": "pool",
+              "churn": "churn"}
     named = [(kind, data[g]["generator"]) for g, kind in groups.items()
              if g in data]
-    assert len(named) == (2 if path.startswith("configs") else 1)
+    assert len(named) == (2 if path.startswith("configs")
+                          else 1 + ("churn" in data))
     for kind, name in named:
         assert callable(traffic.generator(kind, name))
 
@@ -172,7 +174,7 @@ def test_every_generator_a_data_file_names_resolves(path):
 @pytest.mark.parametrize("name", GENERATOR_FILES)
 def test_a_generator_file_keeps_the_contract_of_its_place(name):
     """Standard library and numpy only; not a built-in's name; has one of
-    the three functions; and something names it: a configuration, a cell
+    the four functions; and something names it: a configuration, a cell
     or a test (a generator nothing runs is dead weight in `paths`)."""
     assert NAME.match(name)
     assert not any(name in d for d in traffic.BUILT_IN.values())
@@ -187,7 +189,7 @@ def test_a_generator_file_keeps_the_contract_of_its_place(name):
             roots.add(node.module.split(".")[0])
     assert roots <= set(sys.stdlib_module_names) | {"numpy"}, roots
     defined = {n.name for n in tree.body if isinstance(n, ast.FunctionDef)}
-    assert defined & {"table", "live", "pool"}
+    assert defined & {"table", "live", "pool", "churn"}
     quoted = re.compile(r"""["']%s["']""" % re.escape(name))
     users = [
         os.path.join(root, f)

@@ -304,3 +304,67 @@ def test_filter_tree_equals_the_programs_matching_on_a_plus_tree(tree):
         assert want == sorted(f for f in flts if R.matches(t, f))
         n += len(want)
     assert n > 0
+
+
+# ------------------------------------------------------ churned filters
+
+FLEET_CHURN = {"generator": "fleet_churn", "filters": 6000, "first_id": 244,
+               "clients": 1000, "rate": 200, "dwell_s": 5.0, "qos": [0, 1],
+               "churn_children": 1}
+
+
+def test_fleet_churn_is_new_distinct_and_disjoint_on_the_fleets_pool():
+    """At the fleet's own size: no churned filter is one the live set
+    holds (each is a route the residual carries), no two are equal, and
+    no topic of the pool matches two (so a connection may hold any of
+    them at once); the pool publishes to some of them."""
+    conf = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "fleet-1m-rules.json")))
+    _pairs, pops = TR.generate("table", conf["table"])
+    live = {f for _c, flts, _q in TR.generate("live", conf["live"])
+            for f in flts}
+    flts = TR.churn_filters(FLEET_CHURN, pops, 3004100001)
+    assert len(flts) == len(set(flts)) == 6000 and not set(flts) & live
+    pool = TR.topic_pool({"generator": "fleet_zipf", "pool": 65536},
+                         pops, 1, 512)
+    ch = R.Churned(pool, flts, {k: np.zeros(0) for k in (
+        "conn", "filter", "qos", "sub", "suback", "unsub", "unsuback")},
+        np.zeros(0, np.int64), np.zeros(0), np.zeros(0))
+    assert (ch.filter_of >= 0).mean() > 0.04
+
+
+def test_churn_filters_are_the_same_set_for_every_seed_in_another_order():
+    pops = (62500, 25000, 25000, 12500)
+    a = TR.churn_filters(FLEET_CHURN, pops, 1)
+    b = TR.churn_filters(FLEET_CHURN, pops, 2 ** 31 + 7)
+    assert a != b and sorted(a) == sorted(b)
+    assert a == TR.churn_filters(FLEET_CHURN, pops, 1)
+    assert Counter(f.split("/")[0] for f in a) == {
+        "vehicles": 2000, "dev": 2000, "site": 2000}
+
+
+@pytest.mark.parametrize("group", [
+    {"generator": "fleet_churn", "filters": 10},
+    {"generator": "fleet_churn", "filters": 10, "first_id": 5, "fanout": 2},
+    {"generator": "fleet_churn", "filters": 0, "first_id": 5},
+    {"generator": "nobody", "filters": 10, "first_id": 5},
+])
+def test_a_churn_group_the_generator_cannot_take_is_refused(group):
+    with pytest.raises(TR.BadGenerator):
+        TR.churn_filters(group, (10, 10, 10, 10), 1)
+
+
+def test_a_churn_generator_comes_as_a_file(tmp_path, monkeypatch):
+    monkeypatch.setattr(TR, "GENERATORS", str(tmp_path))
+    (tmp_path / "pairs.py").write_text(
+        "def churn(rng, pops, n):\n"
+        "    return [f'p/{k}/#' for k in range(n)]\n"
+    )
+    (tmp_path / "twice.py").write_text(
+        "def churn(rng, pops):\n    return ['a/#', 'a/#']\n"
+    )
+    flts = TR.churn_filters({"generator": "pairs", "n": 5, **{
+        k: FLEET_CHURN[k] for k in TR.CHURN_KEYS}}, (), 9)
+    assert sorted(flts) == [f"p/{k}/#" for k in range(5)]
+    with pytest.raises(TR.BadGenerator, match="not distinct"):
+        TR.churn_filters({"generator": "twice"}, (), 9)

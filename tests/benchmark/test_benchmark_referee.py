@@ -354,3 +354,184 @@ def test_expected_without_share_is_what_it_was(config, traffic, subs_pin,
     assert _digest(exp.sub_seqs) == subs_pin
     assert _digest(exp.rule_seqs) == rules_pin
     assert exp.n_deliveries == n
+
+
+# -------------------- churned subscriptions (MQTT 5.0 3.8.4 and 3.10.4)
+
+# topics by seq % 4: two the churned `c/+` matches, one `d/#` does, one
+# no churned filter; two publishers
+CHURN_POOL = ["c/a", "c/b", "d/x", "e/1"]
+CHURN_FILTERS = ["c/+", "d/#"]
+# a life on connection 0 holds `c/+` at QoS 1: SUBSCRIBE sent at 10,
+# SUBACK in at 11, UNSUBSCRIBE sent at 20, UNSUBACK in at 21
+LIFE = {"conn": 0, "filter": 0, "qos": 1, "sub": 10.0, "suback": 11.0,
+        "unsub": 20.0, "unsuback": 21.0}
+
+
+def _lives(*lives):
+    return {k: np.asarray([life[k] for life in lives]) for k in LIFE}
+
+
+def _churned(pubs, lives=(LIFE,)):
+    """``pubs``: seq -> (sent, acked); the churned subscriptions'
+    reference over them."""
+    seqs = np.asarray(sorted(pubs), np.int64)
+    sends = np.asarray([pubs[s][0] for s in seqs.tolist()])
+    acks = np.asarray([pubs[s][1] for s in seqs.tolist()])
+    return R.Churned(CHURN_POOL, CHURN_FILTERS, _lives(*lives), seqs,
+                     sends, acks)
+
+
+def _got(*receipts):
+    """``(conn, seq, instant, qos)`` receipts as the harness hands them."""
+    cols = list(zip(*receipts)) or [(), (), (), ()]
+    return (np.asarray(cols[0], np.int64), np.asarray(cols[1], np.int64),
+            np.asarray(cols[2], float), np.asarray(cols[3], np.int64))
+
+
+NAMES = ("deliveries_missing", "deliveries_unexpected",
+         "deliveries_duplicated", "deliveries_out_of_order",
+         "subscribers_wrong_qos")
+
+
+def _churn_numbers(ch, *receipts, publishers=2):
+    *nums, lost = ch.judge(publishers, *_got(*receipts))
+    return {n: v for n, v in zip(NAMES, nums) if v}, sorted(lost.tolist())
+
+
+# (publish 4, on c/a: sent, acknowledged; received at 22 or never) ->
+# the numbers it reads
+EDGES = {
+    "owed-and-received": (12.0, 13.0, True, {}),
+    "owed-and-missing": (12.0, 13.0, False, {"deliveries_missing": 1}),
+    "sent-as-the-suback-came": (11.0, 13.0, False, {}),
+    "sent-just-after-the-suback": (11.001, 13.0, False,
+                                   {"deliveries_missing": 1}),
+    "acked-as-the-unsubscribe-left": (12.0, 20.0, False, {}),
+    "acked-just-before-the-unsubscribe": (12.0, 19.999, False,
+                                          {"deliveries_missing": 1}),
+    "permitted-before-the-suback": (10.5, 10.6, True, {}),
+    "permitted-after-the-unsubscribe": (19.5, 20.5, True, {}),
+    "acked-as-the-subscribe-left": (9.0, 10.0, True,
+                                    {"deliveries_unexpected": 1}),
+    "acked-just-after-the-subscribe-left": (9.0, 10.001, True, {}),
+    "sent-as-the-unsuback-came": (21.0, 21.5, True,
+                                  {"deliveries_unexpected": 1}),
+    "sent-just-before-the-unsuback": (20.999, 21.5, True, {}),
+    "never-acknowledged-yet-in-the-band": (12.0, 0.0, True, {}),
+    "never-acknowledged-and-sent-after": (21.5, 0.0, True,
+                                          {"deliveries_unexpected": 1}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EDGES))
+def test_a_churned_life_is_owed_its_band_and_no_more(case):
+    """Owed: sent after the SUBACK came and acknowledged before the
+    UNSUBSCRIBE left; permitted: acknowledged after the SUBSCRIBE left
+    and sent before the UNSUBACK came; the edges are strict."""
+    sent, acked, received, want = EDGES[case]
+    ch = _churned({4: (sent, acked)})
+    owed = "deliveries_missing" in want or (case == "owed-and-received")
+    assert ch.n_owed == int(owed)
+    nums, lost = _churn_numbers(ch, *([(0, 4, 22.0, 1)] if received else []))
+    assert nums == want
+    assert lost == ([4] if "deliveries_missing" in want else [])
+
+
+SECOND = {**LIFE, "sub": 30.0, "suback": 31.0, "unsub": 40.0,
+          "unsuback": 41.0, "qos": 0}
+
+
+@pytest.mark.parametrize("case,receipts,want,lost", [
+    ("each-life-its-own", [(0, 4, 14.0, 1), (0, 8, 35.0, 0)], {}, []),
+    # a receipt belongs to the life that subscribed last before it came:
+    # publish 4, acked at 13, cannot be the second life's
+    ("late-into-the-next-life", [(0, 4, 31.0, 0), (0, 8, 35.0, 0)],
+     {"deliveries_missing": 1, "deliveries_unexpected": 1}, [4]),
+    ("the-second-life-at-the-first-ones-qos", [(0, 4, 14.0, 1),
+                                              (0, 8, 35.0, 1)],
+     {"subscribers_wrong_qos": 1}, []),
+    ("one-owed-publish-twice", [(0, 4, 14.0, 1), (0, 4, 15.0, 1),
+                                (0, 8, 35.0, 0)],
+     {"deliveries_duplicated": 1}, []),
+    ("in-the-other-order", [(0, 12, 14.0, 1), (0, 4, 15.0, 1),
+                            (0, 8, 35.0, 0)],
+     {"deliveries_out_of_order": 1}, []),
+    # publish 2 is on d/x: the connection never held d/#
+    ("a-topic-the-filter-does-not-match", [(0, 4, 14.0, 1),
+                                           (0, 2, 15.0, 1),
+                                           (0, 8, 35.0, 0)],
+     {"deliveries_unexpected": 1}, []),
+    # publish 3 is on e/1: no churned filter matches it
+    ("a-topic-no-churned-filter-matches", [(0, 4, 14.0, 1),
+                                           (0, 3, 15.0, 1),
+                                           (0, 8, 35.0, 0)],
+     {"deliveries_unexpected": 1}, []),
+    ("on-a-connection-that-held-nothing", [(0, 4, 14.0, 1),
+                                           (1, 4, 14.0, 1),
+                                           (0, 8, 35.0, 0)],
+     {"deliveries_unexpected": 1}, []),
+])
+def test_two_lives_on_one_connection_are_judged_apart(case, receipts, want,
+                                                      lost):
+    # 4 and 12 on c/a from publisher 0, both owed to the first life, and
+    # 12 only where the case receives it; 8 on c/a owed to the second;
+    # 2 and 3 on topics the first may not have
+    pubs = {2: (12.0, 13.0), 3: (12.0, 13.0), 4: (12.0, 13.0),
+            8: (32.0, 33.0)}
+    if any(seq == 12 for _c, seq, _t, _q in receipts):
+        pubs[12] = (12.5, 13.5)
+    ch = _churned(pubs, (LIFE, SECOND))
+    assert ch.n_owed == 2 + (12 in pubs)
+    assert _churn_numbers(ch, *receipts) == (want, lost)
+
+
+def test_churned_filters_that_overlap_on_the_pool_are_refused():
+    with pytest.raises(R.Overlap) as e:
+        R.Churned(CHURN_POOL, ["c/+", "c/a"], _lives(LIFE),
+                  np.arange(4), np.zeros(4), np.zeros(4))
+    assert "'c/a'" in str(e.value) and "churned" in str(e.value)
+
+
+def test_the_judge_adds_the_churn_under_the_same_five_names():
+    exp, k, sent, received, qos = _exact_run(n=40)
+    plain, _ = _numbers(exp, k, sent, received, qos)
+    ch = _churned({4: (12.0, 13.0), 5: (12.0, 13.0)})
+    pool = TR.topic_pool({"generator": "exact_topics", "pool": 4},
+                         (1, 1, 1, 1), 1, k)
+    with_churn = R.Expected(pool, exp.subs, 0, sent, churn=ch)
+    assert with_churn.n_deliveries == exp.n_deliveries + 2
+    nums, failed = R.judge(with_churn, k, sent, received, qos,
+                           np.zeros(0, np.int32), np.zeros(0, np.int64),
+                           {}, _got((0, 4, 14.0, 1)))
+    nums = {n: v for n, v, _l in nums}
+    assert list(nums) == list(plain)
+    assert {n: v for n, v in nums.items() if v} == {"deliveries_missing": 1}
+    assert failed.tolist() == [5]
+
+
+@pytest.mark.parametrize("case,life,unanswered", [
+    ("both-answered", LIFE, 0),
+    ("no-suback", {**LIFE, "suback": 0.0}, 1),
+    ("no-unsuback", {**LIFE, "unsuback": 0.0}, 1),
+    ("neither", {**LIFE, "suback": 0.0, "unsuback": 0.0}, 1),
+])
+def test_a_life_an_ack_never_answered_is_a_client_error(case, life,
+                                                        unanswered):
+    """Without its UNSUBACK a life's band never closes, so a receipt long
+    after the UNSUBSCRIBE reads permitted: the missing ack itself is what
+    fails the run, under ``client_errors``."""
+    exp, k, sent, received, qos = _exact_run(n=40)
+    ch = _churned({4: (12.0, 13.0), 5: (50.0, 51.0)}, (life,))
+    assert ch.unanswered == unanswered
+    pool = TR.topic_pool({"generator": "exact_topics", "pool": 4},
+                         (1, 1, 1, 1), 1, k)
+    with_churn = R.Expected(pool, exp.subs, 0, sent, churn=ch)
+    receipts = [(0, 4, 14.0, 1)] if life["suback"] else []
+    if not life["unsuback"]:
+        receipts.append((0, 5, 52.0, 1))
+    nums, _failed = R.judge(with_churn, k, sent, received, qos,
+                            np.zeros(0, np.int32), np.zeros(0, np.int64),
+                            {"client_errors": 2}, _got(*receipts))
+    nums = {n: v for n, v, _l in nums if v}
+    assert nums == {"client_errors": 2 + unanswered}

@@ -85,6 +85,8 @@ def test_cell_runs_to_a_well_formed_correct_line(cell, on_cpu, capsys):
     assert list(res)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
     assert list(res)[-1] == "compared"
+    # no churn group, no churn key: the line is what it was
+    assert "churn" not in res and "churn" not in window
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] > 0
     # the device is named as JAX reports it: here a CPU, so nothing of
