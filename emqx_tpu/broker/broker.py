@@ -1149,6 +1149,7 @@ class Broker:
                 name == "dense_rematch" for name, _, _ in timings
             )
             rec.n_host_rows = info.get("host_rows", 0)
+            rec.residual_filters = info.get("residual")
             rec.path = path
             rec.breaker_open = self.router.engine.breaker_open
         remote: Optional[List[Set[str]]] = None

@@ -1014,6 +1014,7 @@ class Channel:
     def _subscribe_body(
         self, pkt: C.Subscribe, verdicts: Optional[List[Optional[bool]]]
     ) -> None:
+        t0 = time.perf_counter()
         m = self.broker.metrics
         m.inc("packets.subscribe.received")
         mqtt = self.broker.config.mqtt
@@ -1030,9 +1031,18 @@ class Channel:
         if self.version != C.MQTT_V5:
             rcs = [rc if rc <= 2 else 0x80 for rc in rcs]
         m.inc("packets.suback.sent")
-        self.send_packets([C.Suback(packet_id=pkt.packet_id, reason_codes=rcs)])
-        if retained_jobs:
-            self.send_packets(self.session.deliver(retained_jobs))
+        suback = [C.Suback(packet_id=pkt.packet_id, reason_codes=rcs)]
+
+        def ack() -> None:
+            # the SUBACK leaves once the routes are in the engine, and
+            # the retained replay after it [MQTT-3.8.4]
+            if self._closing or self.session is None:
+                return
+            self.send_packets(suback)
+            if retained_jobs:
+                self.send_packets(self.session.deliver(retained_jobs))
+
+        self.broker.router.after_routes(ack, t0, len(rcs))
 
     def _do_subscribe(
         self,
@@ -1116,6 +1126,7 @@ class Channel:
         return granted
 
     def _handle_unsubscribe(self, pkt: C.Unsubscribe) -> None:
+        t0 = time.perf_counter()
         m = self.broker.metrics
         m.inc("packets.unsubscribe.received")
         rcs: List[int] = []
@@ -1131,8 +1142,10 @@ class Channel:
                 self.broker.unsubscribe(self.client.clientid, full)
             rcs.append(RC_NORMAL if had else RC_NO_SUBSCRIPTION_EXISTED)
         m.inc("packets.unsuback.sent")
-        self.send_packets(
-            [C.Unsuback(packet_id=pkt.packet_id, reason_codes=rcs)]
+        unsuback = [C.Unsuback(packet_id=pkt.packet_id, reason_codes=rcs)]
+        # (and the UNSUBACK once the routes are out of the engine)
+        self.broker.router.after_routes(
+            lambda: self.send_packets(unsuback), t0, len(rcs)
         )
 
     # ---------------------------------------------------- disconnect

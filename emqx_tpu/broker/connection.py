@@ -81,12 +81,16 @@ class ReadTurn:
     (an EOF or a TLS ``close_notify`` behind the data) has that read
     handled first, by the connection."""
 
-    def __init__(self, clock=None) -> None:
+    def __init__(self, clock=None, router=None) -> None:
         self._conns: List["Connection"] = []
         # the profiler's `LoopClock`, or None: the turn's first read
         # opens its ``recv`` phase, the run is its ``reads`` phase (a
         # clock read a turn each, none a read)
         self._clock = clock
+        # the broker's `Router`, or None: the run is one route scope,
+        # so the turn's SUBSCRIBEs and UNSUBSCRIBEs change the engine's
+        # routes as one batch once its reads are handled
+        self._router = router
 
     def add(self, conn: "Connection") -> None:
         if not self._conns:
@@ -100,23 +104,31 @@ class ReadTurn:
         if clock is not None:
             clock.mark(clock.READS)
         conns, self._conns = self._conns, []
-        for i, conn in enumerate(conns):
-            closed = False
-            try:
-                closed = conn._handle_reads()
-            except Exception:
-                # (a boundary that must keep running: the turn's other
-                # reads are off their sockets already)
-                log.exception("read turn: a connection failed")
-            if closed and i + 1 < len(conns):
-                # a read closed its connection and queued the teardown:
-                # the turn's later reads wait behind it, as they would
-                # behind the next poll (a client's DISCONNECT, then its
-                # reconnect's CONNECT, read in one batch)
-                if not self._conns:
-                    asyncio.get_running_loop().call_soon(self._run)
-                self._conns[:0] = conns[i + 1:]
-                break
+        router = self._router
+        if router is not None:
+            router.begin_routes()
+        try:
+            for i, conn in enumerate(conns):
+                closed = False
+                try:
+                    closed = conn._handle_reads()
+                except Exception:
+                    # (a boundary that must keep running: the turn's
+                    # other reads are off their sockets already)
+                    log.exception("read turn: a connection failed")
+                if closed and i + 1 < len(conns):
+                    # a read closed its connection and queued the
+                    # teardown: the turn's later reads wait behind it,
+                    # as they would behind the next poll (a client's
+                    # DISCONNECT, then its reconnect's CONNECT, read in
+                    # one batch)
+                    if not self._conns:
+                        asyncio.get_running_loop().call_soon(self._run)
+                    self._conns[:0] = conns[i + 1:]
+                    break
+        finally:
+            if router is not None:
+                router.end_routes()
         if clock is not None:
             clock.mark(clock.TAIL)
 
@@ -437,25 +449,37 @@ class Connection(asyncio.Protocol):
                 t_in += yield from self._owe(delay)
         # (an `AckRun` counts as the PUBACKs it carries)
         n_pubs = n_acks = n_run = n_other = 0
-        for pkt in self.parser.feed(data):
-            t = pkt.type
-            if t == C.ACK_RUN:
-                # (acks cost a limiter nothing: bytes are charged a
-                # read, messages a PUBLISH)
-                n_run += len(pkt.packet_ids)
-            elif t == C.PUBLISH:
-                n_pubs += 1
-                if limiter is not None:
-                    delay = limiter.consume(0, 1)
-                    if delay > 0:
-                        t_in += yield from self._owe(delay)
-            elif t in _ACKS:
-                n_acks += 1
-            else:
-                n_other += 1
-            channel.handle_in(pkt)
-            if closed.is_set():
-                break
+        # the read is a route scope (within its turn's, on the direct
+        # path): its SUBSCRIBEs' and UNSUBSCRIBEs' route changes are one
+        # batch, and a limiter's pause ends the scope while it lasts
+        router = self.broker.router
+        router.begin_routes()
+        try:
+            for pkt in self.parser.feed(data):
+                t = pkt.type
+                if t == C.ACK_RUN:
+                    # (acks cost a limiter nothing: bytes are charged a
+                    # read, messages a PUBLISH)
+                    n_run += len(pkt.packet_ids)
+                elif t == C.PUBLISH:
+                    n_pubs += 1
+                    if limiter is not None:
+                        delay = limiter.consume(0, 1)
+                        if delay > 0:
+                            router.end_routes()
+                            try:
+                                t_in += yield from self._owe(delay)
+                            finally:
+                                router.begin_routes()
+                elif t in _ACKS:
+                    n_acks += 1
+                else:
+                    n_other += 1
+                channel.handle_in(pkt)
+                if closed.is_set():
+                    break
+        finally:
+            router.end_routes()
         if lc is not None:
             n_acks += n_run
             lc.ingress(t_in, n_pubs + n_acks + n_other,

@@ -41,7 +41,7 @@ class Listener:
         self._conns: set = set()
         self._direct = False
         # a direct listener's reads, a turn
-        self._turn = ReadTurn(broker.profiler.loop)
+        self._turn = ReadTurn(broker.profiler.loop, broker.router)
         # listener-aggregate buckets shared by ALL this listener's
         # connections (the hierarchical limiter's middle level)
         self._shared_limiter = None

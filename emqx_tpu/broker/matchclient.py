@@ -209,6 +209,18 @@ class ServiceMatchEngine(MatchEngine):
             self._route_send((), (fid,))
         return ok
 
+    def delete_many(self, fids: Sequence[Hashable]) -> int:
+        n = super().delete_many(fids)
+        if n:
+            self._route_send((), fids)
+        return n
+
+    def apply_routes(self, pairs, fids) -> None:
+        # the mirror's batch under `_mlock`, then the delta streamed
+        # with the lock released (`_lk` is never taken inside `_mlock`)
+        super().apply_routes(pairs, fids)
+        self._route_send(pairs, fids)
+
     def _route_send(self, add, delete) -> None:
         """Stream one route delta; a detached service just skips (the
         re-attach replay covers it from the mirror)."""

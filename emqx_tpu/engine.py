@@ -349,6 +349,29 @@ class _EncArena:
 
 _NO_DTIER = (None, None, None)  # no delta automaton folded yet
 
+# the delta fold's capacity class: at least these node rows and
+# buckets, and a step to this many times what a fold needs
+# (`MatchEngine._fold_class_for`)
+_FOLD_MIN_NODES = 4096
+_FOLD_MIN_BUCKETS = 2048
+_FOLD_ROOM = 4
+
+# `stats()` counters of the route batches: batches applied and their
+# ops, the applying thread's seconds waiting for ``_mlock`` and holding
+# it, and what the caller reported of its acknowledgements
+# (`note_route_acks`: seconds from a SUBSCRIBE / UNSUBSCRIBE to its
+# ack, and its filters)
+_ROUTE_STATS = ("route_batches", "route_ops", "route_wait_s",
+                "route_apply_s", "route_ack_s", "route_ack_filters")
+# the delta folds' phases, each timed on the fold's own thread (the
+# capture on the thread that started it, under ``_mlock``)
+_FOLD_PHASES = ("capture", "encode", "assemble", "upload", "warm",
+                "commit")
+# folds committed, all folds' wall seconds, the phases' seconds and the
+# bytes the folds handed to the device
+_FOLD_STATS = ("folds", "fold_s") + tuple(
+    "fold_" + p + "_s" for p in _FOLD_PHASES) + ("fold_upload_bytes",)
+
 
 class _ResidualView:
     """Read view of "wildcard filters inserted after the fold
@@ -450,13 +473,13 @@ class MatchEngine:
         self._dtier: Tuple = _NO_DTIER
         self._daut_fids: Set[Hashable] = set()
         self._fold_cache: Optional[_EncArena] = None  # fold encode arena
-        # STICKY fold capacity classes: each new (node, bucket) shape
-        # costs a compile or an executable load on the backend, behind
-        # which concurrent matches queue; never shrinking the ladder
-        # across rebuilds means each class loads once per process
-        # instead of once per rebuild cycle
-        self._fold_min_nodes = 4096
-        self._fold_min_buckets = 2048
+        # STICKY fold capacity class, (node rows, buckets) of the
+        # committed folds (None before the first): each new shape costs
+        # a compile or an executable load on the backend, behind which
+        # concurrent matches queue; never shrinking the ladder across
+        # rebuilds means each class loads once per process instead of
+        # once per rebuild cycle (`_fold_class_for`)
+        self._fold_class: Optional[Tuple[int, int]] = None
         # The residual ("delta since the last fold") is NOT a second
         # trie: `_wild` tags every insert with a monotonically
         # increasing sequence number, and the residual is simply the
@@ -638,6 +661,10 @@ class MatchEngine:
         # folds, rebuilds) + the tokenize stage histogram.  None =
         # zero-cost no-op (standalone engines, benches)
         self.profiler = None
+        # route batches (`apply_routes`) and folds, counted for
+        # ``stats()``: seconds here, whole microseconds there
+        self._route_stats = dict.fromkeys(_ROUTE_STATS, 0)
+        self._fold_stats = dict.fromkeys(_FOLD_STATS, 0)
 
     # ------------------------------------------------------------- mutation
 
@@ -658,6 +685,12 @@ class MatchEngine:
         the whole window's fresh wildcard entries, with replacements /
         exact / deep filters peeling off to the single-item path.
         Validation still runs per item BEFORE any mutation."""
+        with self._mlock:
+            # same _mlock-serializes-the-native-matrix design as
+            # `insert` # brokerlint: ignore[LOCK402]
+            self._insert_many_locked(pairs)
+
+    def _insert_many_locked(self, pairs) -> None:
         # last-wins within the window (same as per-item insert): a fid
         # listed twice must not have its FIRST filter batch-inserted
         # after the second took the replacement path
@@ -671,64 +704,78 @@ class MatchEngine:
         parsed = [
             (flt, fid, *_validate_filter(flt)) for flt, fid in pairs
         ]
-        with self._mlock:
-            if self._built is not None:
-                self._poll_swap()
-            batch: List[Tuple[str, Hashable, Tuple[str, ...]]] = []
-            for flt, fid, ws, wild, n_hash in parsed:
-                prev = self._by_fid.get(fid)
-                if prev is not None:
-                    if prev == flt:
-                        continue
-                    # same _mlock-serializes-the-native-matrix design
-                    # as `insert` # brokerlint: ignore[LOCK402]
-                    self._insert_locked(flt, fid)
+        if self._built is not None:
+            self._poll_swap()
+        batch: List[Tuple[str, Hashable, Tuple[str, ...]]] = []
+        for flt, fid, ws, wild, n_hash in parsed:
+            prev = self._by_fid.get(fid)
+            if prev is not None:
+                if prev == flt:
                     continue
-                if not wild:
-                    self._by_fid[fid] = flt
-                    self._exact.setdefault(flt, set()).add(fid)
-                    continue
-                if len(ws) - (1 if n_hash else 0) > self.max_levels:
-                    # same _mlock design # brokerlint: ignore[LOCK402]
-                    self._insert_locked(flt, fid)
-                    continue
+                self._insert_locked(flt, fid)
+                continue
+            if not wild:
                 self._by_fid[fid] = flt
-                batch.append((flt, fid, ws))
-            if not batch:
-                return
-            seqs = self._wild.insert_batch(batch)
-            delta = self._delta
-            dseq = self._delta_seq
-            log = self._residual_log
-            fresh = 0
-            for (flt, fid, ws), seq in zip(batch, seqs):
-                delta[fid] = ws
-                if seq:
-                    dseq[fid] = seq
-                    log.append((fid, seq))
-                    fresh += 1
-            self._residual_count += fresh
-            if self._building:
-                self._pending_inserts.extend(
-                    (flt, fid) for flt, fid, _ in batch
-                )
-            if len(delta) >= self.rebuild_threshold:
-                if self.background_rebuild:
-                    if self.defer_rebuild is None or \
-                            not self.defer_rebuild():
-                        self._start_background_rebuild()
-                else:
-                    # synchronous rebuild variant keeps _mlock across
-                    # the native sort on purpose: mutations must not
-                    # interleave with the table swap
-                    # brokerlint: ignore[LOCK402]
-                    self.rebuild()
-            if self.use_device is not False and (
-                self._residual_count
-                >= max(self.delta_aut_threshold,
-                       len(self._delta) // self.delta_fold_factor)
-            ):
-                self._fold_delta_aut()
+                self._exact.setdefault(flt, set()).add(fid)
+                continue
+            if len(ws) - (1 if n_hash else 0) > self.max_levels:
+                self._insert_locked(flt, fid)
+                continue
+            self._by_fid[fid] = flt
+            batch.append((flt, fid, ws))
+        if not batch:
+            return
+        seqs = self._wild.insert_batch(batch)
+        delta = self._delta
+        dseq = self._delta_seq
+        log = self._residual_log
+        fresh = 0
+        for (flt, fid, ws), seq in zip(batch, seqs):
+            delta[fid] = ws
+            if seq:
+                dseq[fid] = seq
+                log.append((fid, seq))
+                fresh += 1
+        self._residual_count += fresh
+        if self._building:
+            self._pending_inserts.extend(
+                (flt, fid) for flt, fid, _ in batch
+            )
+        self._grown()
+
+    def _grown(self) -> None:
+        """The delta grew: a rebuild past ``rebuild_threshold``, a
+        fold past the residual's trigger (`_maybe_fold`)."""
+        if len(self._delta) >= self.rebuild_threshold:
+            if self.background_rebuild:
+                if self.defer_rebuild is None or \
+                        not self.defer_rebuild():
+                    self._start_background_rebuild()
+            else:
+                # synchronous rebuild variant keeps _mlock across
+                # the native sort on purpose: mutations must not
+                # interleave with the table swap
+                # brokerlint: ignore[LOCK402]
+                self.rebuild()
+        self._maybe_fold()
+
+    def _maybe_fold(self) -> None:
+        """Fold once the residual reaches ``max(delta_aut_threshold,
+        len(delta) // delta_fold_factor)``, and never while a build is
+        in flight: the build covers every filter up to its snapshot
+        and its swap discards the delta automaton, so a fold then is
+        work thrown away (a boot's table of a million filters would be
+        folded whole beside its build).  What came after the snapshot
+        is the residual the swap leaves, and `_poll_swap` asks here
+        again."""
+        if (
+            self.use_device is not False
+            and not self._building
+            and self._residual_count
+            >= max(self.delta_aut_threshold,
+                   len(self._delta) // self.delta_fold_factor)
+        ):
+            self._fold_delta_aut()
 
     def _insert_locked(self, flt: str, fid: Hashable) -> None:
         if self._built is not None:
@@ -776,19 +823,7 @@ class MatchEngine:
                         ]
                 if self._building:
                     self._pending_inserts.append((flt, fid))
-                if len(self._delta) >= self.rebuild_threshold:
-                    if self.background_rebuild:
-                        if self.defer_rebuild is None or \
-                                not self.defer_rebuild():
-                            self._start_background_rebuild()
-                    else:
-                        self.rebuild()
-                if self.use_device is not False and (
-                    self._residual_count
-                    >= max(self.delta_aut_threshold,
-                           len(self._delta) // self.delta_fold_factor)
-                ):
-                    self._fold_delta_aut()
+                self._grown()
         else:
             self._exact.setdefault(flt, set()).add(fid)
 
@@ -796,35 +831,107 @@ class MatchEngine:
         with self._mlock:
             return self._delete_locked(fid)
 
+    def delete_many(self, fids: Sequence[Hashable]) -> int:
+        """Batch delete, the twin of `insert_many`: one lock
+        acquisition, and the wildcard trie's share of the batch in ONE
+        GIL-released native call.  Returns how many fids were held."""
+        with self._mlock:
+            # same _mlock design as `insert` # brokerlint: ignore[LOCK402]
+            return self._delete_many_locked(fids)
+
     def _delete_locked(self, fid: Hashable) -> bool:
         flt = self._by_fid.pop(fid, None)
         if flt is None:
             return False
         if T.is_wildcard(flt):
             self._wild.delete_id(fid)
-            self._delta.pop(fid, None)
-            seq = self._delta_seq.pop(fid, None)
-            if seq is not None and seq > self._fold_watermark:
-                self._residual_count -= 1
             self._deep.delete_id(fid)
-            # unconditional tombstones: membership checks against the
-            # base/daut fid sets would race the builder threads'
-            # in-place arena mutation; masking a fid no snapshot
-            # carries is harmless (set subtraction of an absent
-            # element), and both sets reset at the next build anyway
-            self._deleted_base.add(fid)
-            self._deleted_daut.add(fid)
-            if self._folding:
-                self._fold_deletes.add(fid)
-            if self._building:
-                self._pending_deletes.add(fid)
+            self._unseat(fid)
         else:
-            ids = self._exact.get(flt)
-            if ids is not None:
-                ids.discard(fid)
-                if not ids:
-                    del self._exact[flt]
+            self._drop_exact(flt, fid)
         return True
+
+    def _delete_many_locked(self, fids) -> int:
+        wild: List[Hashable] = []
+        n = 0
+        for fid in fids:
+            flt = self._by_fid.pop(fid, None)
+            if flt is None:
+                continue
+            n += 1
+            if T.is_wildcard(flt):
+                wild.append(fid)
+                self._unseat(fid)
+            else:
+                self._drop_exact(flt, fid)
+        if wild:
+            deep = self._deep
+            self._wild.delete_batch(wild)
+            if len(deep):
+                deep.delete_batch([f for f in wild if f in deep])
+        return n
+
+    def _unseat(self, fid: Hashable) -> None:
+        """A deleted wildcard fid leaves the delta and the residual,
+        and is tombstoned in both device tiers."""
+        self._delta.pop(fid, None)
+        seq = self._delta_seq.pop(fid, None)
+        if seq is not None and seq > self._fold_watermark:
+            self._residual_count -= 1
+        # unconditional tombstones: membership checks against the
+        # base/daut fid sets would race the builder threads'
+        # in-place arena mutation; masking a fid no snapshot
+        # carries is harmless (set subtraction of an absent
+        # element), and both sets reset at the next build anyway
+        self._deleted_base.add(fid)
+        self._deleted_daut.add(fid)
+        if self._folding:
+            self._fold_deletes.add(fid)
+        if self._building:
+            self._pending_deletes.add(fid)
+
+    def _drop_exact(self, flt: str, fid: Hashable) -> None:
+        ids = self._exact.get(flt)
+        if ids is not None:
+            ids.discard(fid)
+            if not ids:
+                del self._exact[flt]
+
+    def apply_routes(self, pairs: Sequence[Tuple[str, Hashable]],
+                     fids: Sequence[Hashable]) -> None:
+        """One batch of route changes: ``fids`` deleted, then ``pairs``
+        inserted (a batch names each fid once, so the order is the
+        caller's net one), under one ``_mlock`` acquisition
+        (`delete_many`, `insert_many`).  Counted in ``stats()``:
+        ``route_batches``, ``route_ops``, ``route_wait_us`` (waiting
+        for the lock, which a window's overlay may hold) and
+        ``route_apply_us`` (holding it)."""
+        st = self._route_stats
+        t0 = time.perf_counter()
+        self._mlock.acquire()
+        t1 = time.perf_counter()
+        try:
+            # the batch's native trie calls hold _mlock by the
+            # `insert` design # brokerlint: ignore[LOCK402]
+            if fids:
+                self._delete_many_locked(fids)
+            if pairs:
+                self._insert_many_locked(pairs)
+        finally:
+            self._mlock.release()
+        st["route_batches"] += 1
+        st["route_ops"] += len(pairs) + len(fids)
+        st["route_wait_s"] += t1 - t0
+        st["route_apply_s"] += time.perf_counter() - t1
+
+    def note_route_acks(self, seconds: float, filters: int) -> None:
+        """The caller's clock of one acknowledgement that waited for
+        its route batch: ``seconds`` from the SUBSCRIBE or UNSUBSCRIBE
+        to its ack, for ``filters`` filters (``stats()``:
+        ``route_ack_us``, ``route_ack_filters``)."""
+        st = self._route_stats
+        st["route_ack_s"] += seconds
+        st["route_ack_filters"] += filters
 
     def __len__(self) -> int:
         return len(self._by_fid)
@@ -938,6 +1045,10 @@ class MatchEngine:
         rows pad to a power-of-two capacity class (min 4096) and the
         hash table to a minimum bucket count, so successive folds reuse
         compiled kernel shapes; the scan length is pinned likewise.
+        The class is the delta's own (`_fold_class_for`): only a fold
+        that commits sets it, so a fold that a base swap discards (one
+        of the base's whole table) leaves no base-sized class behind
+        for every later fold to assemble and upload.
 
         Two-phase, called under ``_mlock``: only the O(residual)
         work-list capture runs inline; the encode, assemble, upload and
@@ -946,11 +1057,16 @@ class MatchEngine:
         Matching keeps using the old delta automaton + the live
         residual view (`match_since_words` past the old watermark)
         until the swap, so nothing stalls and nothing is missed; the
-        swap itself is a watermark bump, not a residual rebuild."""
+        swap itself is a watermark bump, not a residual rebuild.
+
+        Each phase is timed (`_FOLD_PHASES`): a profiler histogram
+        ``fold_<phase>`` and a ``stats()`` counter ``fold_<phase>_us``,
+        beside ``folds``, ``fold_us`` and ``fold_upload_bytes``."""
         from .ops.automaton import assemble_automaton
 
         if self._folding:
             return
+        t_cap = time.perf_counter()
         # under _mlock: capture the work-list only (no encoding here —
         # the O(residual) encode runs in the fold thread too, off the
         # insert path).  The log dedups in place: an entry is live iff
@@ -980,10 +1096,16 @@ class MatchEngine:
            n_new=len(new_items))
         self._folding = True
         self._fold_deletes = set()
+        phases = {"capture": time.perf_counter() - t_cap}
+
+        def timed(phase: str, t0: float) -> float:
+            t1 = time.perf_counter()
+            phases[phase] = t1 - t0
+            return t1
 
         def work():
             aut = None
-            t_fold = time.perf_counter()
+            t_fold = t = time.perf_counter()
             try:
                 with self._enc_lock:
                     if cache is None:
@@ -995,25 +1117,42 @@ class MatchEngine:
                     inputs = arena.views()
                     fid_view = arena.fid_view()
                     live_fids = set(arena.rows)
+                t = timed("encode", t)
                 if not live_fids:  # everything deleted since snapshot
                     with self._mlock:
                         self._folding = False
+                    self._fold_done(phases, t_fold, False)
                     return
+                cls = self._fold_class or (_FOLD_MIN_NODES,
+                                           _FOLD_MIN_BUCKETS)
                 aut = assemble_automaton(
                     *inputs, max_levels=self.max_levels,
-                    hash_buckets=self._fold_min_buckets,
+                    hash_buckets=cls[1],
                 )
-                _pad_nodes_pow2(aut, minimum=self._fold_min_nodes)
+                cls = self._fold_class_for(aut)
+                if len(aut.fp_rows) < cls[1]:
+                    # a hash table cannot be padded after the fact (and
+                    # a bucket that overflows still doubles it)
+                    aut = assemble_automaton(
+                        *inputs, max_levels=self.max_levels,
+                        hash_buckets=cls[1],
+                    )
+                    cls = (cls[0], len(aut.fp_rows))
+                _pad_nodes_pow2(aut, minimum=cls[0])
                 aut.kernel_levels = self.max_levels + 1
-                self._fold_min_nodes = aut.node_rows.shape[0]
-                self._fold_min_buckets = len(aut.fp_rows)
+                t = timed("assemble", t)
                 dev = None
                 if self.use_device is not False:
                     try:
                         dev = self._device_put(aut)
                     except Exception:
                         dev = None
+                    t = timed("upload", t)
                     if dev is not None:
+                        self._fold_stats["fold_upload_bytes"] += sum(
+                            a.nbytes for a in aut.device_arrays()
+                            if isinstance(a, np.ndarray)
+                        )
                         try:
                             # warm BEFORE the commit: a fold crossing
                             # a capacity class used to compile on the
@@ -1030,6 +1169,7 @@ class MatchEngine:
                                 "emqx_tpu.engine"
                             ).debug("delta shape warm failed",
                                     exc_info=True)
+                        t = timed("warm", t)
                 tp("fold_assemble_done", gen=gen)  # fault-inject point
             except Exception:
                 import logging
@@ -1040,6 +1180,7 @@ class MatchEngine:
                 )
                 with self._mlock:
                     self._folding = False
+                self._fold_done(phases, t_fold, False)
                 return
             # blocking tracepoint OUTSIDE the lock: force_ordering may
             # pin the adoption here while a match holds/needs _mlock.
@@ -1051,41 +1192,20 @@ class MatchEngine:
                 with self._mlock:
                     self._folding = False
                 raise
+            t = time.perf_counter()
             with self._mlock:
                 self._folding = False
                 if self._fold_gen != gen:
                     tp("fold_discard", gen=gen)
-                    return  # base swapped underneath: fold is stale
-                tp("fold_commit", gen=gen, watermark=snap_seq)
-                self._fold_cache = arena
-                self._dtier = (aut, dev, fid_view)
-                self._warn_frontier(aut, "delta")
-                self._daut_fids = live_fids
-                # tombstones for fids deleted while the fold assembled
-                # (fresh set: an in-flight match's captured snapshot
-                # keeps the old set + old automaton pair); a fid
-                # re-inserted during the fold stays tombstoned here but
-                # its new seq is past the watermark, so the residual
-                # view serves it — set union across tiers dedups
-                self._deleted_daut = {
-                    f for f in self._fold_deletes if f in self._daut_fids
-                }
-                self._fold_deletes = set()
-                # the fold swap IS the watermark bump: entries at or
-                # below snap_seq are covered by the new automaton
-                self._fold_watermark = snap_seq
-                self._residual_log = [
-                    (fid, seq)
-                    for fid, seq in self._residual_log
-                    if seq > snap_seq
-                ]
-                self._residual_count = sum(
-                    1
-                    for fid, seq in self._residual_log
-                    if self._delta_seq.get(fid) == seq
-                )
+                    committed = False
+                else:
+                    self._fold_commit(aut, dev, fid_view, arena,
+                                      live_fids, snap_seq, gen, cls)
+                    committed = True
+            timed("commit", t)
+            self._fold_done(phases, t_fold, committed)
             prof = self.profiler
-            if prof is not None:
+            if committed and prof is not None:
                 prof.event(
                     "delta_fold", time.perf_counter() - t_fold,
                     n_new=len(new_items),
@@ -1098,6 +1218,74 @@ class MatchEngine:
             self._fold_thread.start()
         else:
             work()  # _mlock is reentrant: safe from _insert_locked
+
+    def _fold_class_for(self, aut) -> Tuple[int, int]:
+        """The (node rows, buckets) class a fold's automaton takes: the
+        committed class where it fits, else a step to powers of two
+        `_FOLD_ROOM` times what it needs (the first fold is a step
+        too).  A step compiles every batch bucket's kernels in the fold
+        thread before the commit, seconds on a cold cache, so it buys
+        room for the delta to grow: a churning delta holds its class
+        (the fleet's live set and its churn need 1,632 and ~4,900 node
+        rows: one class of 8,192), and the table stays the delta's
+        size, not the base's."""
+        cls = self._fold_class
+        need = (aut.node_rows.shape[0], len(aut.fp_rows))
+        if cls is not None and need[0] <= cls[0] and need[1] <= cls[1]:
+            return cls
+        floor = cls or (_FOLD_MIN_NODES, _FOLD_MIN_BUCKETS)
+        return tuple(
+            _pow2_at_least(_FOLD_ROOM * n, lo) for n, lo in zip(need, floor)
+        )
+
+    def _fold_commit(self, aut, dev, fid_view, arena, live_fids,
+                     snap_seq: int, gen: int, cls: Tuple[int, int]) -> None:
+        """Adopt a fold (under ``_mlock``, its generation current)."""
+        tp("fold_commit", gen=gen, watermark=snap_seq)
+        # the sticky class follows a committed fold, never a discarded
+        # one (a boot's whole-table fold would leave it at the base's size)
+        self._fold_class = cls
+        self._fold_cache = arena
+        self._dtier = (aut, dev, fid_view)
+        self._warn_frontier(aut, "delta")
+        self._daut_fids = live_fids
+        # tombstones for fids deleted while the fold assembled
+        # (fresh set: an in-flight match's captured snapshot
+        # keeps the old set + old automaton pair); a fid
+        # re-inserted during the fold stays tombstoned here but
+        # its new seq is past the watermark, so the residual
+        # view serves it — set union across tiers dedups
+        self._deleted_daut = {
+            f for f in self._fold_deletes if f in self._daut_fids
+        }
+        self._fold_deletes = set()
+        # the fold swap IS the watermark bump: entries at or
+        # below snap_seq are covered by the new automaton
+        self._fold_watermark = snap_seq
+        self._residual_log = [
+            (fid, seq)
+            for fid, seq in self._residual_log
+            if seq > snap_seq
+        ]
+        self._residual_count = sum(
+            1
+            for fid, seq in self._residual_log
+            if self._delta_seq.get(fid) == seq
+        )
+
+    def _fold_done(self, phases: Dict[str, float], t_fold: float,
+                   committed: bool) -> None:
+        """Count one fold's phases (`_FOLD_STATS`) and histogram them
+        (``fold_<phase>``); the fold thread's own wall time is
+        ``fold_s`` whether or not the fold committed."""
+        st = self._fold_stats
+        st["folds"] += committed
+        st["fold_s"] += time.perf_counter() - t_fold
+        prof = self.profiler
+        for phase, dur in phases.items():
+            st["fold_" + phase + "_s"] += dur
+            if prof is not None:
+                prof.stage("fold_" + phase, dur)
 
     def _warm_built(self, aut, dev, batch: int = 16) -> None:
         """Compile the kernel for a freshly built automaton's table
@@ -1334,6 +1522,9 @@ class MatchEngine:
             self._pending_deletes = set()
             self._building = False
             tp("base_swap", pending=len(delta))
+        # what came after the build's snapshot may fold now (callers
+        # hold ``_mlock``, as `_fold_delta_aut` asks)
+        self._maybe_fold()
 
     def warmup(self, max_batch: int = 4096) -> int:
         """Pre-compile the kernel for every power-of-two batch bucket up
@@ -1591,6 +1782,14 @@ class MatchEngine:
         out["rules_host_us_ewma"] = self._rul_host_us
         out["rules_dev_us_ewma"] = self._rul_dev_us
         out["host_rows"] = self._host_rows
+        # the route batches' and the folds' counters, seconds as whole
+        # microseconds (a reader takes their growth over a window)
+        for st in (self._route_stats, self._fold_stats):
+            for key, v in st.items():
+                if key.endswith("_s"):
+                    out[key[:-2] + "_us"] = int(v * 1e6)
+                else:
+                    out[key] = v
         # of the base and the delta automaton, the wider (0: neither)
         out["frontier_need"] = max(
             (aut.frontier_need for aut in (self._aut, self._dtier[0])
@@ -1640,6 +1839,9 @@ class MatchEngine:
             self._deleted_base,
             self._dtier,
             self._deleted_daut,
+            # the residual's filters at the snapshot (a window's
+            # ``info["residual"]``)
+            self._residual_count,
         )
 
     def _auto_choose(self, n: int, congested: bool) -> bool:
@@ -2421,7 +2623,9 @@ class MatchEngine:
         start); the caller's ``seq`` in it tags their trace
         annotations.  A ``dev``
         window also leaves ``host_rows`` there: the rows a kernel
-        flagged, which `_overlay` matched on the host."""
+        flagged, which `_overlay` matched on the host; and
+        ``residual``: the filters the residual held at the window's
+        snapshot, which every row of it matched on the host trie."""
         if pending[0] != "dev":
             if info is not None:
                 info["path"] = pending[0]
@@ -2457,6 +2661,7 @@ class MatchEngine:
         tp("match_overlay")
         with self._mlock:
             tm.lap("overlay_lock_wait", then="overlay")
+            tp("overlay_locked")
             out, n_host = self._overlay(
                 topics, words, rows, gpos, ovf, snap, dflat, tm
             )
@@ -2464,6 +2669,7 @@ class MatchEngine:
         if info is not None:
             info["timings"] = tm.timings()
             info["host_rows"] = n_host
+            info["residual"] = snap[8]
         if self.use_device is None and len(words) >= 64:
             cpu_us = (
                 (cpu0 + time.thread_time() - c1) / len(words) * 1e6

@@ -343,6 +343,7 @@ class WindowRecord(Laps):
         "loop_cpu", "decide_rows", "decide_rows_padded", "sender", "reader",
         "rules_firings", "rules_firings_run", "n_clients_plain",
         "n_host_rows", "n_shared", "n_shared_vector", "gc",
+        "residual_filters",
     )
 
     def __init__(self, seq: int, n_msgs: int, source: str) -> None:
@@ -358,6 +359,10 @@ class WindowRecord(Laps):
         # rows of a ``dev`` window that a kernel flagged (frontier or
         # match cap passed) and the host trie matched instead
         self.n_host_rows = 0
+        # the engine's residual filters (the delta since its last fold,
+        # matched on the host trie) at a ``dev`` window's snapshot;
+        # None where no snapshot was taken
+        self.residual_filters: Optional[int] = None
         # shared-subscription rows the window picked a member for, and
         # those of them one window operation served (the rest went
         # through the scalar redispatch path)
@@ -465,6 +470,7 @@ class WindowRecord(Laps):
             "n_clients_plain": self.n_clients_plain,
             "n_clips": self.n_clips,
             "n_host_rows": self.n_host_rows,
+            "residual_filters": self.residual_filters,
             "n_shared": self.n_shared,
             "n_shared_vector": self.n_shared_vector,
             "decide_rows": self.decide_rows,

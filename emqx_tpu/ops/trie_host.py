@@ -82,6 +82,11 @@ class HostTrie:
         of NativeTrie.insert_batch); returns per-item seq tags."""
         return [self.insert(flt, fid, ws=ws) for flt, fid, ws in items]
 
+    def delete_batch(self, fids) -> int:
+        """Batch delete (interface twin of NativeTrie.delete_batch);
+        returns how many of the fids were present."""
+        return sum(self.delete_id(fid) for fid in fids)
+
     def delete_id(self, fid: Hashable) -> bool:
         ws = self._filters.pop(fid, None)
         if ws is None:

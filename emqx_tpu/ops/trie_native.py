@@ -52,6 +52,8 @@ def _bind(lib) -> None:
     ]
     lib.ht_delete.restype = ctypes.c_int32
     lib.ht_delete.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    lib.ht_delete_batch.restype = ctypes.c_int64
+    lib.ht_delete_batch.argtypes = [ctypes.c_void_p, _i64p, ctypes.c_int64]
     lib.ht_match.restype = ctypes.c_int64
     lib.ht_match.argtypes = [
         ctypes.c_void_p,
@@ -173,6 +175,29 @@ class NativeTrie:
         self._free.append(iid)
         self._filters.pop(fid, None)
         return True
+
+    def delete_batch(self, fids) -> int:
+        """`delete_id` of every fid in ONE GIL-released call (the
+        twin of `insert_batch`); returns how many were present."""
+        flt_map, ids = self._filters, self._ids
+        handles = []
+        for fid in fids:
+            if flt_map.pop(fid, None) is None:
+                continue
+            if type(fid) is int and fid >= 0:
+                handles.append(fid << 1)
+                continue
+            iid = ids.pop(fid)
+            self._rev[iid] = None
+            self._free.append(iid)
+            handles.append((iid << 1) | 1)
+        if handles:
+            arr = np.asarray(handles, np.int64)
+            self._lib.ht_delete_batch(
+                self._h, arr.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+                len(arr),
+            )
+        return len(handles)
 
     def match(self, name: str) -> set:
         raw = name.encode()

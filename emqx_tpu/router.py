@@ -19,18 +19,41 @@ the rule sink and the shared-pick path.
 Shared subscriptions route through the same engine entry for the real
 filter; group membership and a window's picks live in
 `SharedSubManager`.
+
+Route changes come in batches, the `emqx_router_syncer` shape with the
+event loop's turn as the batch (emqx_router_syncer.erl:58, :115-121:
+callers push their route ops, then wait for the batch before the
+SUBACK).  Inside a scope (`begin_routes` / `end_routes`: the turn's
+reads) a subscribe or unsubscribe stages its engine route op and
+changes its own state at once; when the scope closes the staged ops go
+to the engine as one batch in their net order
+(`MatchEngine.apply_routes`: one lock acquisition, one native call each
+way), so the loop waits for the matcher's lock once a turn, not once a
+filter.  What waits for the batch (`after_routes`: a SUBACK or UNSUBACK)
+runs once it is applied, in order.  Outside a scope a route op applies
+at once, as every caller off the read path expects.
+
+Why the turn waits for the lock rather than carrying its batch to a
+later turn where a match holds it: the holder is a window's `overlay`,
+Python on an executor thread, and a loop that goes on running while it
+waits takes the GIL from it.  On the chip (TPU v5e, the
+`fleet-1m-consoles.churn-paced` cell) a carrying loop measured the median
+delay 29-54 % above a loop that waits once a filter, and the one wait a
+turn 19 % below it (`PERF.md`, section 5).
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+import time
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from . import topic as T
 from .broker.session import SubOpts
 from .broker.shared import SharedSubManager
-from .engine import MatchEngine
+from .engine import MatchEngine, _validate_filter
+from .tp import tp
 
 _EMPTY_I64 = np.empty(0, dtype=np.int64)
 
@@ -136,6 +159,18 @@ class Router:
         self._oa_rap = np.zeros(_OPTS_CAP0, dtype=bool)
         self._oa_subid = np.zeros(_OPTS_CAP0, dtype=bool)
         self.opts_rev = 0
+        # (client, filter) subscription pairs, kept by subscribe and
+        # unsubscribe: the 'subscriptions.count' stat
+        self._n_subs = 0
+        # --- route batches -------------------------------------------
+        # the filters whose route the engine holds or is staged to
+        # hold: a delete is staged only for one of them
+        self._routed: Set[str] = set()
+        self._scope = 0  # open `begin_routes` scopes
+        # staged (is_add, filter) ops and what waits for them: (fn,
+        # perf_counter at the packet, filters), both in order
+        self._ops: List[Tuple[bool, str]] = []
+        self._acks: List[Tuple[Callable[[], None], float, int]] = []
 
     # ---------------------------------------------------- interning
 
@@ -219,13 +254,16 @@ class Router:
         `$share/...` filter).  Mirrors emqx_broker:subscribe/3 +
         route-add (emqx_broker.erl:151-190, 691-721)."""
         shared = T.parse_share(flt)
+        # (a filter the engine would refuse is refused before any state
+        # changes, as the engine's own insert does)
+        _validate_filter(shared.topic if shared is not None else flt)
         if shared is not None:
             real = shared.topic
             opts.share_group = shared.group
             row = self._intern(clientid)  # picks resolve to rows
-            self._shared_opts.setdefault(real, {})[
-                (shared.group, clientid)
-            ] = opts
+            members = self._shared_opts.setdefault(real, {})
+            self._n_subs += (shared.group, clientid) not in members
+            members[(shared.group, clientid)] = opts
             skey = (real, shared.group, clientid)
             sslot = self._shared_slot.get(skey)
             if sslot is None:
@@ -237,18 +275,15 @@ class Router:
                 shared.group, real, clientid, row, sslot
             )
             if need_route and real not in self._subs:
-                self.engine.insert(real, real)
-                if self.on_route_added is not None:
-                    self.on_route_added(real)
+                self._route(True, real)
         else:
             real = flt
             subs = self._subs.get(real)
             if subs is None:
                 subs = self._subs[real] = {}
                 if real not in self._shared_opts or not self._shared_opts[real]:
-                    self.engine.insert(real, real)
-                    if self.on_route_added is not None:
-                        self.on_route_added(real)
+                    self._route(True, real)
+            self._n_subs += clientid not in subs
             subs[clientid] = opts
             row = self._intern(clientid)
             bucket = self._csr.get(real)
@@ -269,7 +304,8 @@ class Router:
             emptied = self.shared.leave(shared.group, real, clientid)
             opts_map = self._shared_opts.get(real)
             if opts_map is not None:
-                opts_map.pop((shared.group, clientid), None)
+                if opts_map.pop((shared.group, clientid), None) is not None:
+                    self._n_subs -= 1
                 if not opts_map:
                     del self._shared_opts[real]
             sslot = self._shared_slot.pop(
@@ -285,6 +321,7 @@ class Router:
                 removed = False
             else:
                 del subs[clientid]
+                self._n_subs -= 1
                 if not subs:
                     del self._subs[real]
                 bucket = self._csr.get(real)
@@ -307,8 +344,78 @@ class Router:
 
     def _maybe_drop_route(self, real: str) -> None:
         if real not in self._subs and real not in self._shared_opts:
-            if self.engine.delete(real) and self.on_route_removed is not None:
-                self.on_route_removed(real)
+            self._route(False, real)
+
+    # ------------------------------------------------ route batches
+
+    def _route(self, add: bool, real: str) -> None:
+        """Stage one route op, and apply it at once outside a scope.
+        An op is staged only where it changes `_routed` (a second
+        group's join, or a delete of a filter never routed, moves
+        nothing), so a filter's first op in a batch says whether the
+        engine held it before."""
+        if add == (real in self._routed):
+            return
+        if add:
+            self._routed.add(real)
+        else:
+            self._routed.discard(real)
+        self._ops.append((add, real))
+        if not self._scope:
+            self._flush()
+
+    def begin_routes(self) -> None:
+        """Open a route scope (they nest): its ops wait for the end of
+        the outermost one."""
+        self._scope += 1
+
+    def end_routes(self) -> None:
+        """Close a scope; the outermost applies what is staged."""
+        self._scope -= 1
+        if not self._scope and (self._ops or self._acks):
+            self._flush()
+
+    def after_routes(self, fn: Callable[[], None], t0: float,
+                     filters: int) -> None:
+        """Run ``fn`` (a SUBACK or UNSUBACK and what follows it) once
+        every route op staged so far is applied: at once where none
+        waits, else in order after the batch.  ``t0`` (perf_counter)
+        is when its packet came, ``filters`` how many it names: the
+        engine counts the wait (`MatchEngine.note_route_acks`)."""
+        if self._ops or self._acks:
+            self._acks.append((fn, t0, filters))
+            return
+        fn()
+        self.engine.note_route_acks(time.perf_counter() - t0, filters)
+
+    def _flush(self) -> None:
+        ops, self._ops = self._ops, []
+        if ops:
+            # a filter's first op says what the engine held before the
+            # batch (a delete: the route), its last what it holds after;
+            # where the two agree (a delete then an insert) nothing moves
+            first: Dict[str, bool] = {}
+            last: Dict[str, bool] = {}
+            for add, real in ops:
+                first.setdefault(real, add)
+                last[real] = add
+            pairs = [(r, r) for r, add in last.items()
+                     if add and first[r]]
+            dels = [r for r, add in last.items()
+                    if not add and not first[r]]
+            if pairs or dels:
+                self.engine.apply_routes(pairs, dels)
+            tp("routes_applied", ops=len(ops))
+            added, removed = self.on_route_added, self.on_route_removed
+            for add, real in ops:
+                hook = added if add else removed
+                if hook is not None:
+                    hook(real)
+        acks, self._acks = self._acks, []
+        note = self.engine.note_route_acks
+        for fn, t0, filters in acks:
+            fn()
+            note(time.perf_counter() - t0, filters)
 
     def cleanup_client(self, clientid: str) -> None:
         """Drop every subscription of a dead client (the
@@ -327,9 +434,7 @@ class Router:
     def subscription_count(self) -> int:
         """Total (client, filter) subscription pairs — the
         'subscriptions.count' stat (rule fids excluded)."""
-        return sum(len(v) for v in self._subs.values()) + sum(
-            len(v) for v in self._shared_opts.values()
-        )
+        return self._n_subs
 
     # --------------------------------------------------------- match
 

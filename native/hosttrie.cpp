@@ -203,6 +203,14 @@ int32_t ht_delete(void* h, int64_t fid) {
     return 1;
 }
 
+// Batch delete, the twin of ht_insert_batch: one GIL-released call for
+// a batch of route deletes; returns how many of the fids were present.
+int64_t ht_delete_batch(void* h, const int64_t* fids, int64_t n) {
+    int64_t found = 0;
+    for (int64_t i = 0; i < n; i++) found += ht_delete(h, fids[i]);
+    return found;
+}
+
 // Match a concrete topic.  Fills `out` (capacity `cap`) with matching
 // fids and returns the TOTAL match count (callers grow the buffer and
 // retry when the return exceeds cap).
